@@ -109,9 +109,10 @@ _SHAPE_RE = re.compile(r"\b([a-z]+\d*)\[([0-9,]*)\]")
 # pair is therefore counted at its "-done" and the "-start" skipped.
 # the result-type class must admit TPU layout/memory-space annotations
 # — tiled layouts "{1,0:T(8,128)}" and space markers "S(1)" carry
-# UPPERCASE letters the CPU dump never shows
+# UPPERCASE letters the CPU dump never shows, and a tuple of more than
+# five buffers carries "/*index=5*/" markers
 _COLL_RE = re.compile(
-    r"=\s+(\(?[a-zA-Z0-9\[\]{},:\s/()]*?\)?)\s+"
+    r"=\s+(\(?[a-zA-Z0-9\[\]{},:\s/()*=]*?\)?)\s+"
     r"(all-reduce|all-gather|reduce-scatter|all-to-all|"
     r"collective-permute)(-start|-done)?\(")
 

@@ -29,6 +29,7 @@ Model assumptions (documented; see README "Performance analysis"):
 
 from __future__ import annotations
 
+from ..observability.xla_cost import CHIP_PEAKS as _CHIP_PEAKS
 from . import opgraph
 
 __all__ = [
@@ -61,9 +62,12 @@ class ChipSpec:
     link bytes/s (the host-embedding exchange axis,
     `fluid.host_embedding`).
 
-    Defaults resolve through `observability.xla_cost` (env overrides >
-    live-platform table) and fall back to the v5e constants of record so
-    static analysis works on machines with no accelerator attached."""
+    `detect` resolves every axis through `observability.xla_cost`
+    (explicit arg > env override > `CHIP_PEAKS[device_kind]`).  This is
+    a static model, so on a host with no accelerator it prices the chip
+    the repo targets (`V5E`) and says so in the spec's name; an
+    accelerator `CHIP_PEAKS` does not list is an error, never priced as
+    a v5e."""
 
     def __init__(self, name, peak_flops, hbm_bw, ici_bw=None,
                  host_bw=None):
@@ -74,21 +78,21 @@ class ChipSpec:
         self.host_bw = float(host_bw) if host_bw else None
 
     @classmethod
-    def detect(cls, peak_flops=None, hbm_bw=None, platform=None,
+    def detect(cls, peak_flops=None, hbm_bw=None, device_kind=None,
                ici_bw=None, host_bw=None):
         from ..observability import xla_cost
 
-        peak = xla_cost.peak_flops(explicit=peak_flops, platform=platform)
-        bw = xla_cost.hbm_bandwidth(explicit=hbm_bw, platform=platform)
-        ici = xla_cost.ici_bandwidth(explicit=ici_bw, platform=platform)
-        host = xla_cost.host_bandwidth(explicit=host_bw, platform=platform)
-        if peak and bw:
-            return cls(platform or "detected", peak, bw,
-                       ici or V5E.ici_bw, host or V5E.host_bw)
+        row = xla_cost.chip_peaks(device_kind)
+        if row is None:                 # host CPU: price the target chip
+            device_kind, name = V5E_DEVICE_KIND, V5E.name + " (target)"
+        else:
+            name = row["name"]
         return cls(
-            V5E.name if (peak is None and bw is None) else "partial",
-            peak or V5E.peak_flops, bw or V5E.hbm_bw, ici or V5E.ici_bw,
-            host or V5E.host_bw)
+            name,
+            xla_cost.peak_flops(peak_flops, device_kind),
+            xla_cost.hbm_bandwidth(hbm_bw, device_kind),
+            xla_cost.ici_bandwidth(ici_bw, device_kind),
+            xla_cost.host_bandwidth(host_bw, device_kind))
 
     def to_dict(self):
         return {"name": self.name, "peak_flops": self.peak_flops,
@@ -102,10 +106,9 @@ class ChipSpec:
             "%.0f GB/s" % (self.host_bw / 1e9) if self.host_bw else "n/a")
 
 
-# one v5e chip: 197 bf16 TFLOP/s (the constant bench.py always used),
-# 819 GB/s HBM, 45 GB/s one-way ICI per link (public specs), 16 GB/s
-# PCIe-class host link
-V5E = ChipSpec("tpu-v5e", 197e12, 819e9, 4.5e10, 1.6e10)
+# the chip the repo targets; its figures live in `xla_cost.CHIP_PEAKS`
+V5E_DEVICE_KIND = "TPU v5 lite"
+V5E = ChipSpec(**_CHIP_PEAKS[V5E_DEVICE_KIND])
 
 
 # ---------------------------------------------------------------------------

@@ -31,6 +31,7 @@ import sys
 import time
 
 from ..monitor import LOST, UNINITED, HeartBeatMonitor, _atomic_json_dump
+from ...fluid.core.place import check_children_can_take_chip
 from ...incubate.checkpoint.checkpoint_saver import StaleGenerationError
 
 __all__ = [
@@ -248,6 +249,7 @@ class ElasticController:
                 WORKSPACE_ENV: self._ws,
             })
             argv = self._worker_argv(rank, self._world, generation)
+            check_children_can_take_chip("elastic worker gang", env)
             if self._log_dir:
                 f = open(os.path.join(
                     self._log_dir, "worker_g%d_r%d.log"

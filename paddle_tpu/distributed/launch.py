@@ -117,6 +117,9 @@ def launch(args=None):
         node_ips, args.started_port, args.nproc_per_node
     )
     node_idx = node_ips.index(args.node_ip)
+    from ..fluid.core.place import check_children_can_take_chip
+
+    check_children_can_take_chip("distributed.launch workers")
     procs = []
     log_files = []
     if args.log_dir:

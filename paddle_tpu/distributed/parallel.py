@@ -114,6 +114,9 @@ def spawn(func, args=(), nprocs=None, started_port=None):
     endpoints = ",".join(
         get_cluster_endpoints(["127.0.0.1"], started_port, nprocs))
 
+    from ..fluid.core.place import check_children_can_take_chip
+
+    check_children_can_take_chip("distributed.spawn ranks")
     ctx = mp.get_context("spawn")
     procs = [ctx.Process(target=_spawn_main,
                          args=(func, rank, args, nprocs, endpoints,

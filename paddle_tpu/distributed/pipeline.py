@@ -100,11 +100,11 @@ def gpipe(stage_fn, n_stages, n_micro, axis_name="pp",
             outs = jnp.where(take, outs.at[out_idx].set(out), outs)
             return (recv_next, outs), None
 
-        from ..fluid.core.jax_compat import pvary
-
-        outs0 = jnp.zeros((n_micro,) + out_s.shape, out_s.dtype)
-        outs0 = pvary(outs0, axis_name)
-        recv0 = pvary(jnp.zeros(out_s.shape, out_s.dtype), axis_name)
+        outs0 = jax.lax.pcast(
+            jnp.zeros((n_micro,) + out_s.shape, out_s.dtype), axis_name,
+            to="varying")
+        recv0 = jax.lax.pcast(
+            jnp.zeros(out_s.shape, out_s.dtype), axis_name, to="varying")
         (_, outs), _ = jax.lax.scan(
             tick, (recv0, outs0), jnp.arange(n_ticks)
         )

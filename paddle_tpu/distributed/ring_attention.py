@@ -60,9 +60,7 @@ def ring_attention(q, k, v, axis_name="sp", scale=None, causal=False):
     """
     if scale is None:
         scale = float(q.shape[-1]) ** -0.5
-    from ..fluid.core.jax_compat import axis_size
-
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     s_loc = q.shape[2]
 
@@ -70,9 +68,7 @@ def ring_attention(q, k, v, axis_name="sp", scale=None, causal=False):
     # mark the accumulators as device-varying on the ring axis (shard_map
     # tracks varying-vs-replicated; a constant init would type-clash with
     # the per-shard scan carry)
-    from ..fluid.core.jax_compat import pvary
-
-    _vary = lambda x: pvary(x, axis_name)
+    _vary = lambda x: jax.lax.pcast(x, axis_name, to="varying")
     acc = _vary(jnp.zeros((b, h, s_loc, d), jnp.float32))
     m = _vary(jnp.full((b, h, s_loc), NEG_INF / 2, jnp.float32))
     l = _vary(jnp.zeros((b, h, s_loc), jnp.float32))
@@ -120,8 +116,6 @@ def ring_attention_sharded(q, k, v, mesh, axis_name="sp", scale=None,
     fn = functools.partial(
         ring_attention, axis_name=axis_name, scale=scale, causal=causal
     )
-    from ..fluid.core.jax_compat import shard_map as _shard_map
-
-    return _shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec
     )(q, k, v)

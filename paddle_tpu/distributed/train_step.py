@@ -200,9 +200,9 @@ class ShardedTrainStep:
       them just-in-time at forward entry (per-bucket, overlap-ready)
       and the updated shards never re-replicate.
 
-    Stages 2/3 run the dp axis in manual-collective mode (`shard_map`
-    through the `jax_compat` shim) and therefore require a pure-dp mesh
-    (tp/sp/ep composition stays on the GSPMD path for now).
+    Stages 2/3 run the dp axis in manual-collective mode
+    (`jax.shard_map`) and therefore require a pure-dp mesh (tp/sp/ep
+    composition stays on the GSPMD path for now).
 
     ``accumulate_steps=k`` splits the batch into k microbatches via a
     ``lax.scan`` that accumulates grads locally in f32 — at stage >= 2
@@ -552,7 +552,6 @@ class ShardedTrainStep:
         """
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        from ..fluid.core import jax_compat
         from . import zero as zero_mod
 
         mesh = self.mesh
@@ -713,9 +712,9 @@ class ShardedTrainStep:
         out_specs = (out_p_specs, out_m_specs,
                      [P("dp") for _ in reasm_buckets], P())
 
-        mapped = jax_compat.shard_map(
-            body, mesh.mesh, in_specs=in_specs, out_specs=out_specs,
-            check=False)
+        mapped = jax.shard_map(
+            body, mesh=mesh.mesh, in_specs=in_specs, out_specs=out_specs,
+            check_vma=False)
 
         repl = NamedSharding(mesh.mesh, P())
 

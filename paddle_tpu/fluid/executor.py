@@ -293,9 +293,7 @@ class _LoweredBlock:
                 new_state = {n: env[n] for n in self.state_out}
                 return fetches, new_state
 
-            from .core.jax_compat import shard_map as _shard_map
-
-            sharded = _shard_map(
+            sharded = jax.shard_map(
                 run_block_sharded,
                 mesh=jmesh,
                 in_specs=(
@@ -305,7 +303,7 @@ class _LoweredBlock:
                     P(),
                 ),
                 out_specs=([P(rank_axis)] * len(fetch_names), P()),
-                check=False,
+                check_vma=False,
             )
             self._jitted = jax.jit(sharded, donate_argnums=(1,))
 

@@ -247,16 +247,26 @@ def _matmul_bias_act(ctx, ins, attrs):
         w2 = jnp.swapaxes(w, -1, -2) if (ty and w.ndim > 1) else w
         out_shape = None                    # jnp.matmul shape as-is
 
+    from ...ops import dispatch
     from ...ops.pallas.matmul import matmul_bias_act, naive_matmul_bias_act
 
-    use_pallas = (
-        _jax.default_backend() == "tpu"
-        and x2.ndim == 2 and w2.ndim == 2
-        and not tx and not ty and alpha == 1.0
-        and x2.shape[0] % 128 == 0 and x2.shape[1] % 128 == 0
-        and w2.shape[1] % 128 == 0
-    )
-    if use_pallas:
+    if _jax.default_backend() != "tpu":
+        reason = "backend is not a TPU"
+    elif not (x2.ndim == 2 and w2.ndim == 2 and not tx and not ty
+              and alpha == 1.0):
+        reason = "not a plain 2-D x @ w"
+    elif x2.shape[0] % 128 or x2.shape[1] % 128 or w2.shape[1] % 128:
+        reason = "M, K, N %s are not all multiples of 128" % (
+            x2.shape + w2.shape[1:],)
+    elif act == "gelu" and not approx:
+        # the kernel's epilogue would need erf in VMEM; refuse here so a
+        # user's step never meets the compiler's error
+        reason = "exact gelu: the Pallas TPU lowering has no erf"
+    else:
+        reason = None
+    dispatch.record("matmul_bias_act", "xla composition" if reason
+                    else "pallas", reason or "fused GEMM shape rules met")
+    if reason is None:
         out = matmul_bias_act(x2, w2, bias, activation=act,
                               approximate=approx)
     else:

@@ -367,12 +367,6 @@ def _print(ctx, ins, attrs):
     flat = x.reshape(-1)
     head = flat[: summarize if summarize > 0 else flat.shape[0]]
 
-    from ..core.block_eval import _warn_no_callbacks, host_callbacks_supported
-
-    if not host_callbacks_supported():
-        _warn_no_callbacks("layers.Print")
-        return {"Out": [x]}
-
     # host callback, NOT jax.debug.print: the user message is arbitrary
     # text (its braces must not reach a format-string parser)
     def _emit(v):

@@ -159,6 +159,14 @@ def _stateful_forward_vars(stage_ops, block, scope):
     return out
 
 
+def _varying(x, axis):
+    """Type ``x`` as varying over the manual ``axis`` (a value that
+    already is stays as it is: the cast accepts only invariant input)."""
+    if axis in jax.typeof(x).vma:
+        return x
+    return jax.lax.pcast(x, axis, to="varying")
+
+
 def build_pipeline_jit(program, block, ops, feed_names, feed_shapes,
                        fetch_names, state_in, state_out, state_donate,
                        state_ro, scope, mesh, n_micro, loss_name, is_test):
@@ -262,14 +270,18 @@ def build_pipeline_jit(program, block, ops, feed_names, feed_shapes,
     # an in-body psum per shard and psum'ing grads again double-counts
     # by the pp size).
     def pp_forward(train_params, const_params, mb_feeds, rng_key):
-        from .core.jax_compat import pvary
-
         s = jax.lax.axis_index("pp")
-        env_base = dict(const_params)
-        env_base.update(train_params)
+        # params enter replicated (invariant over pp) and each stage
+        # branch of the switch below reads its own.  Type them varying
+        # HERE, so the psum that makes their gradient invariant again
+        # sits outside the switch: jax 0.9.0 transposes a switch on a
+        # varying index over invariant operands through one branch only
+        # (every shard's cotangent came from stage 0's branch)
+        params_in = {**const_params, **train_params}
+        env_base = {n: _varying(v, "pp") for n, v in params_in.items()}
         # persistable vars written by forward stages (BN running stats)
         # ride the scan carry: microbatch-SEQUENTIAL, like SectionWorker
-        stats0 = {n: env_base[n] for n in stat_names}
+        stats0 = {n: params_in[n] for n in stat_names}
 
         def tick(carry, t):
             bnd, acc, stats = carry
@@ -298,17 +310,17 @@ def build_pipeline_jit(program, block, ops, feed_names, feed_shapes,
                     # every switch branch must produce the same
                     # replication type: mark all branch outputs varying
                     # on pp (they are — each shard ran its own stage)
-                    out = {n: pvary(env.get(n, bnd_in[n]), "pp")
+                    out = {n: _varying(env.get(n, bnd_in[n]), "pp")
                            for n in boundary}
                     lv = (env[loss_name].astype(jnp.float32)
                           if si == loss_stage else jnp.float32(0))
                     new_stats = {
-                        n: pvary(jax.lax.stop_gradient(
+                        n: _varying(jax.lax.stop_gradient(
                             env.get(n, stats_in[n])), "pp")
                         for n in stat_names
                     }
                     return (out,
-                            pvary(jnp.asarray(lv, jnp.float32).reshape(()),
+                            _varying(jnp.asarray(lv, jnp.float32).reshape(()),
                                   "pp"),
                             new_stats)
                 return f
@@ -324,10 +336,15 @@ def build_pipeline_jit(program, block, ops, feed_names, feed_shapes,
             acc = acc + jnp.where(valid, lv, 0.0)
             return (new_bnd, acc, new_stats), None
 
+        # every carry leaves a tick varying on pp (each shard ran its
+        # own stage), so it has to enter the scan typed that way too
         bnd0 = jax.tree.map(
-            lambda sd: jnp.zeros(sd.shape, sd.dtype), dict(bnd_structs))
+            lambda sd: _varying(jnp.zeros(sd.shape, sd.dtype), "pp"),
+            dict(bnd_structs))
         (_, acc, stats_end), _ = jax.lax.scan(
-            tick, (bnd0, jnp.float32(0), stats0),
+            tick,
+            (bnd0, _varying(jnp.float32(0), "pp"),
+             jax.tree.map(lambda a: _varying(a, "pp"), stats0)),
             jnp.arange(n_micro + n_stages - 1))
         # only the last stage accumulated; the psum broadcasts the total.
         # mean losses average over microbatches (== full-batch mean);
@@ -342,14 +359,11 @@ def build_pipeline_jit(program, block, ops, feed_names, feed_shapes,
         loss = total / n_micro if loss_reduction == "mean" else total
         return loss, stats_final
 
-    from .core.jax_compat import shard_map as _shard_map
-
-    sharded_loss = _shard_map(
+    sharded_loss = jax.shard_map(
         pp_forward,
         mesh=jmesh,
         in_specs=(P(), P(), P(), P()),
         out_specs=(P(), {n: P() for n in stat_names}),
-        check=False,
     )
 
     def step(feed_vals, donate_state, ro_state, rng_key):

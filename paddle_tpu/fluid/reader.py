@@ -359,8 +359,11 @@ class DataLoader:
             )
             for _ in range(self.num_workers)
         ]
-        for p in procs:
-            p.start()
+        from .core.place import cpu_only_children
+
+        with cpu_only_children():    # workers collate on the host only
+            for p in procs:
+                p.start()
         self._pool = (procs, task_q, res_q)
         return self._pool
 

@@ -1755,6 +1755,25 @@ class GenerationEngine:
         except Exception:
             return -1
 
+    def decode_hlo(self):
+        """Optimized HLO of the ACTUAL decode executable, lowered with
+        the engine's live operands — what the TP comm drills pin
+        `decode_comm_estimate` against and `chip_smoke.py` searches for
+        the decode kernel's custom call."""
+        with self._lock, _TRACE_LOCK:      # lowering retraces the model
+            if self.paged:
+                lowered = self._decode_step_fn.lower(
+                    self._params, *self.cache.arrays(), self._lengths,
+                    self._last_tokens, self._keys, self._steps,
+                    self._temp, self._top_k, self._top_p,
+                    self._decode_tables())
+            else:
+                lowered = self._decode_step_fn.lower(
+                    self._params, self.cache.k, self.cache.v,
+                    self._lengths, self._last_tokens, self._keys,
+                    self._steps, self._temp, self._top_k, self._top_p)
+        return lowered.compile().as_text()
+
     def _decode_cache_size(self):
         """Jit-cache entries of the decode step — the compile-once pin."""
         return self._jit_cache_size(self._decode_step_fn)

@@ -48,8 +48,9 @@ class AnalysisConfig:
         from this config enables on-disk caching for EVERY compile in
         the process (with the size/compile-time thresholds zeroed).
         Intended for dedicated serving processes."""
-        self._compile_cache_dir = cache_dir or os.path.join(
-            os.path.expanduser("~"), ".cache", "paddle_tpu_xla_cache")
+        from ..fluid.core.compile_cache import compile_cache_dir
+
+        self._compile_cache_dir = cache_dir or compile_cache_dir()
 
     def enable_int8(self):
         """Weight-only int8 on load (cf. reference
@@ -71,27 +72,14 @@ class Predictor:
 
         self._config = config
         if config._compile_cache_dir:
-            os.makedirs(config._compile_cache_dir, exist_ok=True)
-            jax.config.update(
-                "jax_compilation_cache_dir", config._compile_cache_dir)
-            try:
-                # the cache latches its enabled/dir decision at the first
-                # compile; reset so enabling works even after earlier
-                # uncached compiles in this process
-                from jax.experimental.compilation_cache import (
-                    compilation_cache as _cc,
-                )
+            from ..fluid.core.compile_cache import enable_compile_cache
 
-                _cc.reset_cache()
-            except Exception:
-                pass
-            for knob, val in (
-                    ("jax_persistent_cache_min_compile_time_secs", 0.0),
-                    ("jax_persistent_cache_min_entry_size_bytes", 0)):
-                try:
-                    jax.config.update(knob, val)
-                except Exception:
-                    pass  # older jax without the knob: cache still works
+            enable_compile_cache(config._compile_cache_dir)
+            # a serving ladder is many small executables: cache them all
+            jax.config.update(
+                "jax_persistent_cache_min_compile_time_secs", 0.0)
+            jax.config.update(
+                "jax_persistent_cache_min_entry_size_bytes", 0)
         from ..fluid.executor import Executor
         from ..fluid.core.scope import Scope
 

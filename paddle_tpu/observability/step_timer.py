@@ -87,30 +87,41 @@ def remove_step_failure_hook(fn):
 
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _COMPILE_EVENT_PREFIX = "/jax/core/compile/"
+# persistent compilation cache: a hit loads an executable from disk, a
+# miss compiles and writes one (compiles under the cache's size/time
+# thresholds are neither)
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "xla_compile_cache_hits_total",
+    "/jax/compilation_cache/cache_misses": "xla_compile_cache_misses_total",
+}
 
 _hooks_lock = threading.Lock()
 _hooks_installed = False
 
 
 def install_jax_compile_hooks():
-    """Register the process-wide jax.monitoring listener (idempotent;
-    graceful no-op when this jax build lacks the monitoring API).
-    Returns True when the hooks are (already) live."""
+    """Register the process-wide jax.monitoring listeners (idempotent).
+    Returns True once the hooks are live."""
     global _hooks_installed
     if _hooks_installed:             # hot-path fast exit (benign race:
         return True                  # the flag only ever goes False->True)
     with _hooks_lock:
         if _hooks_installed:
             return True
-        try:
-            import jax.monitoring as jmon
+        import jax.monitoring as jmon
 
-            register = jmon.register_event_duration_secs_listener
-        except (ImportError, AttributeError):
-            return False
-        register(_on_jax_duration_event)
+        jmon.register_event_duration_secs_listener(_on_jax_duration_event)
+        jmon.register_event_listener(_on_jax_event)
         _hooks_installed = True
         return True
+
+
+def _on_jax_event(event, **kw):
+    name = _CACHE_EVENTS.get(event)
+    if name is not None:
+        default_registry().counter(
+            name, "Persistent compilation cache events "
+            "(jax.monitoring %s)" % event).inc()
 
 
 def _on_jax_duration_event(event, duration, **kw):

@@ -10,16 +10,16 @@ utilization per executable:
 
 * `cost_of_jitted(fn, *args)` — lower+compile a jitted callable for one
   argument signature (hits jax's compilation caches when the signature
-  was already built, e.g. after warmup) and normalize `cost_analysis()`
-  across jax versions (dict vs [dict]);
+  was already built, e.g. after warmup) and normalize
+  `cost_analysis()`'s keys;
 * `record_executable_cost(name, cost)` — gauges
   `xla_executable_flops{executable=}` /
   `xla_executable_bytes_accessed{executable=}`;
 * `record_mfu(name, flops, seconds)` — the headline `mfu{executable=}`
-  gauge: flops / seconds / peak.  Peak FLOP/s comes from
-  `$PADDLE_TPU_PEAK_FLOPS`, an explicit argument, or the built-in
-  per-platform table (one v5e chip: 197 bf16 TFLOP/s — the same
-  constant bench.py always used).
+  gauge: flops / seconds / peak.  Peak FLOP/s comes from an explicit
+  argument, `$PADDLE_TPU_PEAK_FLOPS`, or `CHIP_PEAKS` — the one table
+  of per-chip rates, keyed by `device_kind`, each figure with its
+  source; a device it does not list is an error.
 
 Sampling is warmup/once-per-signature work — nothing here runs on the
 step path.
@@ -32,6 +32,9 @@ import os
 from .metrics import default_registry
 
 __all__ = [
+    "CHIP_PEAKS",
+    "UnknownDeviceError",
+    "chip_peaks",
     "cost_analysis_of",
     "cost_of_jitted",
     "feed_signature",
@@ -57,82 +60,91 @@ HBM_BW_ENV = "PADDLE_TPU_HBM_BW"
 ICI_BW_ENV = "PADDLE_TPU_ICI_BW"
 HOST_BW_ENV = "PADDLE_TPU_HOST_BW"
 
-# bf16 peak per chip for platforms we know; MFU needs a denominator and
-# an unknown platform yields None (callers then skip the gauge)
-_PLATFORM_PEAK = {
-    "tpu": 197e12,   # v5e public spec (bench.py's constant of record)
+# THE table of per-chip rates, keyed by jax's `device_kind` — every MFU
+# denominator and roofline axis in the repo (`record_mfu`,
+# `analysis.perf.ChipSpec`) reads it.  A device that has no row is an
+# error where a rate is asked for, never another chip's figures: an
+# unknown TPU generation priced as a v5e reports a wrong utilization
+# under a right-looking name.
+CHIP_PEAKS = {
+    "TPU v5 lite": {                    # one v5e chip
+        "name": "tpu-v5e",
+        # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+        # 16 GB HBM2e at 819 GB/s
+        "peak_flops": 197e12,
+        "hbm_bw": 819e9,
+        # one ICI link, one direction: the scaling-book figure.  The
+        # same Google Cloud page gives 1,600 Gbit/s per chip in total;
+        # the two are not yet reconciled against a measured all-reduce
+        # (ROADMAP S5)
+        "ici_bw": 4.5e10,
+        # host<->device link: a PCIe-gen3-x16-class assumption, not a
+        # published figure (prices `fluid.host_embedding` exchanges)
+        "host_bw": 1.6e10,
+    },
+    # the host CPU is a known device with no peak: utilization is not
+    # defined there, and callers get None, not an error
+    "cpu": None,
 }
 
-# HBM bytes/s per chip — the other roofline axis (analysis.perf's time
-# estimates divide bytes moved by this)
-_PLATFORM_HBM_BW = {
-    "tpu": 819e9,    # v5e public spec
-}
 
-# ICI bytes/s per chip, one link one direction — the ring-collective
-# bound the comm model divides wire bytes by (v5e: 4 links x 400 Gbps
-# bidirectional => 45 GB/s usable one-way per ring direction, the
-# scaling-book figure).  The third roofline axis (analysis.comm).
-_PLATFORM_ICI_BW = {
-    "tpu": 4.5e10,   # v5e, one-way per link
-}
-
-# host<->device link bytes/s — the fourth roofline axis: host-RAM
-# embedding pull/push traffic (fluid.host_embedding) rides this, not
-# HBM or ICI.  PCIe-gen3-x16-class figure for the v5e host attach.
-_PLATFORM_HOST_BW = {
-    "tpu": 1.6e10,
-}
+class UnknownDeviceError(LookupError):
+    """A chip rate was asked for a device `CHIP_PEAKS` has no row for."""
 
 
-def _resolve_rate(explicit, env_name, table, platform):
+def chip_peaks(device_kind=None):
+    """The `CHIP_PEAKS` row for ``device_kind`` (default: the live
+    ``jax.devices()[0].device_kind``); None for the host CPU; raises
+    `UnknownDeviceError` for anything else the table does not list."""
+    if device_kind is None:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise UnknownDeviceError(
+            "no published peaks for device_kind %r: add a row with its "
+            "source to observability.xla_cost.CHIP_PEAKS (known: %s)"
+            % (device_kind, sorted(CHIP_PEAKS))) from None
+
+
+def _resolve_rate(explicit, env_name, axis, device_kind):
     """The shared resolution ladder for every chip-rate axis: explicit
-    arg > env var > platform table (platform defaults to the live jax
-    backend).  None when unknown."""
+    arg > env var > the `CHIP_PEAKS` row of ``device_kind`` (default:
+    the live device).  None only for the host CPU."""
     if explicit:
         return float(explicit)
     env = os.getenv(env_name)
     if env:
-        try:
-            return float(env)
-        except ValueError:
-            pass
-    if platform is None:
-        try:
-            import jax
-
-            platform = jax.default_backend()
-        except Exception:
-            return None
-    return table.get(platform)
+        return float(env)
+    row = chip_peaks(device_kind)
+    return None if row is None else row[axis]
 
 
-def peak_flops(explicit=None, platform=None):
+def peak_flops(explicit=None, device_kind=None):
     """Resolve the MFU denominator: explicit arg > $PADDLE_TPU_PEAK_FLOPS
-    > platform table."""
-    return _resolve_rate(explicit, PEAK_FLOPS_ENV, _PLATFORM_PEAK,
-                         platform)
+    > `CHIP_PEAKS`."""
+    return _resolve_rate(explicit, PEAK_FLOPS_ENV, "peak_flops",
+                         device_kind)
 
 
-def hbm_bandwidth(explicit=None, platform=None):
+def hbm_bandwidth(explicit=None, device_kind=None):
     """Resolve HBM bytes/s: explicit arg > $PADDLE_TPU_HBM_BW >
-    platform table."""
-    return _resolve_rate(explicit, HBM_BW_ENV, _PLATFORM_HBM_BW,
-                         platform)
+    `CHIP_PEAKS`."""
+    return _resolve_rate(explicit, HBM_BW_ENV, "hbm_bw", device_kind)
 
 
-def ici_bandwidth(explicit=None, platform=None):
+def ici_bandwidth(explicit=None, device_kind=None):
     """Resolve ICI bytes/s (one link, one direction): explicit arg >
-    $PADDLE_TPU_ICI_BW > platform table."""
-    return _resolve_rate(explicit, ICI_BW_ENV, _PLATFORM_ICI_BW,
-                         platform)
+    $PADDLE_TPU_ICI_BW > `CHIP_PEAKS`."""
+    return _resolve_rate(explicit, ICI_BW_ENV, "ici_bw", device_kind)
 
 
-def host_bandwidth(explicit=None, platform=None):
+def host_bandwidth(explicit=None, device_kind=None):
     """Resolve host-link bytes/s (host-embedding exchange pricing):
-    explicit arg > $PADDLE_TPU_HOST_BW > platform table."""
-    return _resolve_rate(explicit, HOST_BW_ENV, _PLATFORM_HOST_BW,
-                         platform)
+    explicit arg > $PADDLE_TPU_HOST_BW > `CHIP_PEAKS`."""
+    return _resolve_rate(explicit, HOST_BW_ENV, "host_bw", device_kind)
 
 
 def cost_analysis_of(compiled):
@@ -143,8 +155,6 @@ def cost_analysis_of(compiled):
         ca = compiled.cost_analysis()
     except Exception:
         return None
-    if isinstance(ca, (list, tuple)):      # older jax: one dict per device
-        ca = ca[0] if ca else None
     if not isinstance(ca, dict) or not ca:
         return None
     out = {}
@@ -189,12 +199,13 @@ def record_executable_cost(name, cost, registry=None):
 
 
 def record_mfu(name, flops, seconds, peak=None, registry=None,
-               platform=None):
+               device_kind=None):
     """Set `mfu{executable=name}` = flops/seconds/peak; returns the MFU
-    (None when peak is unknown or inputs are degenerate)."""
+    (None on the host CPU, which has no peak, or when inputs are
+    degenerate)."""
     if not flops or not seconds or seconds <= 0:
         return None
-    peak = peak_flops(explicit=peak, platform=platform)
+    peak = peak_flops(explicit=peak, device_kind=device_kind)
     if not peak:
         return None
     mfu = float(flops) / float(seconds) / peak

@@ -17,6 +17,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from . import dispatch
+
 NEG_INF = -1e30
 
 
@@ -66,9 +68,11 @@ def naive_attention_with_layout(q, k, v, bias, scale, causal,
     return _naive_attention(q, k, v, bias, scale, causal)
 
 
-def _use_pallas(q, k, bias, layout="BHSD"):
+def _naive_reason(q, k, bias, layout="BHSD"):
+    """The rule that sends this call to the naive composition, or None
+    when the flash kernel takes it."""
     if jax.default_backend() != "tpu":
-        return False
+        return "backend is not a TPU"
     # the head dim is never split (its block equals the full dim), so any
     # 64-multiple works — 64 is BERT/GPT's head size and is MXU-packable;
     # the in-kernel bias path only handles row-broadcast (padding-mask)
@@ -79,8 +83,12 @@ def _use_pallas(q, k, bias, layout="BHSD"):
     sq, dim = q.shape[s_ax], q.shape[-1]
     sk = k.shape[s_ax]
     if bias is not None and bias.shape[-2] != 1:
-        return False
-    return dim % 64 == 0 and sq >= 192 and sk >= 192
+        return "bias is not a row-broadcast (padding-mask) bias"
+    if dim % 64:
+        return "head_dim %d is not a multiple of 64" % dim
+    if sq < 192 or sk < 192:
+        return "sequence (q %d, k %d) shorter than 192" % (sq, sk)
+    return None
 
 
 def scaled_dot_product_attention(q, k, v, bias=None, segment_ids=None,
@@ -92,7 +100,10 @@ def scaled_dot_product_attention(q, k, v, bias=None, segment_ids=None,
     attention stays within equal segment ids (packing)."""
     if scale is None:
         scale = float(q.shape[-1]) ** -0.5
-    if _use_pallas(q, k, bias, layout):
+    reason = _naive_reason(q, k, bias, layout)
+    dispatch.record("attention", "naive" if reason else "pallas",
+                    reason or "flash kernel shape rules met")
+    if reason is None:
         from .pallas.attention import flash_attention
 
         return flash_attention(q, k, v, bias=bias, segment_ids=segment_ids,

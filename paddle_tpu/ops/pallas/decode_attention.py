@@ -36,6 +36,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import dispatch
+
 NEG_INF = -1e30
 
 __all__ = ["decode_attention", "decode_attention_reference"]
@@ -63,19 +65,43 @@ def decode_attention_reference(q, k_cache, v_cache, lengths, scale=None):
     return jnp.where(dead, 0.0, out).astype(q.dtype)
 
 
-def _pick_block_k(t):
+# K and V blocks are double-buffered in VMEM, whose scoped limit is
+# 16 MiB on a v5e; the rest is left to the kernel's own temporaries
+_KV_VMEM_BUDGET = 12 << 20
+
+
+def kv_block_vmem_bytes(rows, h, d, dtype):
+    """VMEM held by the pipelined K and V blocks of ``rows`` cache rows:
+    two arrays, two buffers each, with the [H, D] minor dims padded to
+    the dtype's (sublane, 128) tile."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sublane = 8 * (4 // itemsize)
+    h_pad = -(-h // sublane) * sublane
+    d_pad = -(-d // 128) * 128
+    return 4 * rows * h_pad * d_pad * itemsize
+
+
+def _pick_block_k(t, h, d, dtype):
+    """Largest standard block that divides ``t`` and fits the budget."""
     for b in (512, 256, 128):
-        if t % b == 0:
+        if t % b == 0 and kv_block_vmem_bytes(b, h, d, dtype) \
+                <= _KV_VMEM_BUDGET:
             return b
     return None
 
 
-def _kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, m_ref, l_ref, acc_ref,
-            *, scale, bk, nk):
-    """Grid (N, nk): per slot, stream cache blocks with running
-    (m, l, acc) statistics — the flash forward's online softmax with
-    the head axis as the score tile's sublane dimension."""
-    j = pl.program_id(1)
+def _online_softmax_block(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
+                          *, length, j, nblocks, scale, bk):
+    """One cache block of a slot's decode attention: fold rows
+    ``j*bk .. (j+1)*bk`` (those below ``length``) into the running
+    (m, l, acc) online-softmax statistics, and emit on the last block.
+
+    Shared by the dense and the paged kernel.  The score and value
+    products are broadcast-multiply-reduce on the VPU over the cache's
+    native [bk, H, D] block: a one-token query has no non-contracting
+    dimension to give the MXU (Mosaic refuses that ``dot_general``), and
+    keeping the block's layout needs no in-kernel transpose.  Scores
+    stay [bk, H, 1] so the heads never leave the sublane axis."""
 
     @pl.when(j == 0)
     def _init():
@@ -86,37 +112,46 @@ def _kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, m_ref, l_ref, acc_ref,
     q = q_ref[0].astype(jnp.float32)                   # [H, D]
     k = k_ref[0].astype(jnp.float32)                   # [bk, H, D]
     v = v_ref[0].astype(jnp.float32)                   # [bk, H, D]
-    # batched per-head dot: [H, D] x [H, bk, D] -> [H, bk]
-    s = jax.lax.dot_general(
-        q, k.transpose(1, 0, 2), (((1,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    ) * scale
-    s = s + bias_ref[0, 0, :].astype(jnp.float32)[None, :]
+    s = jnp.sum(k * q[None], axis=-1, keepdims=True) * scale  # [bk, H, 1]
+    pos = jax.lax.broadcasted_iota(jnp.int32, (bk, 1, 1), 0) + j * bk
+    live = pos < length
+    s = jnp.where(live, s, NEG_INF)
 
-    m_prev = m_ref[:, 0]                               # [H]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    p = jnp.exp(s - m_new[:, None])                    # [H, bk]
-    # a block the mask fully killed still has p = exp(s - m); with m
-    # stuck at NEG_INF the subtraction is 0 -> p = 1 garbage.  Kill it.
-    p = jnp.where(s <= NEG_INF / 2, 0.0, p)
+    m_prev = m_ref[:, :1]                              # [H, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+    # a fully masked block leaves m at NEG_INF, where exp(s - m) = 1:
+    # dead rows are zeroed by the mask, not by the exponent
+    p = jnp.where(live, jnp.exp(s - m_new[None]), 0.0)  # [bk, H, 1]
     corr = jnp.exp(m_prev - m_new)
-    l_new = l_ref[:, 0] * corr + jnp.sum(p, axis=1)
-    # [H, bk] x [H, bk, D] -> [H, D]
-    pv = jax.lax.dot_general(
-        p, v.transpose(1, 0, 2), (((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )
-    acc_ref[...] = acc_ref[...] * corr[:, None] + pv
-    m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
+    l_new = l_ref[:, :1] * corr + jnp.sum(p, axis=0)
+    acc_ref[...] = acc_ref[...] * corr + jnp.sum(p * v, axis=0)
+    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    @pl.when(j == nk - 1)
+    @pl.when(j == nblocks - 1)
     def _finalize():
-        l = l_ref[:, 0]
-        safe_l = jnp.where(l == 0.0, 1.0, l)
-        out = acc_ref[...] / safe_l[:, None]
-        dead = m_ref[:, 0] <= NEG_INF / 2              # empty slot
-        o_ref[0] = jnp.where(dead[:, None], 0.0, out).astype(o_ref.dtype)
+        l = l_ref[:, :1]
+        out = acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
+        dead = m_ref[:, :1] <= NEG_INF / 2             # empty slot
+        o_ref[0] = jnp.where(dead, 0.0, out).astype(o_ref.dtype)
+
+
+def _kernel(lengths_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
+            *, scale, bk, nk):
+    """Grid (N, nk): per slot, stream cache blocks through
+    `_online_softmax_block`."""
+    _online_softmax_block(
+        q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
+        length=lengths_ref[pl.program_id(0)], j=pl.program_id(1),
+        nblocks=nk, scale=scale, bk=bk)
+
+
+def _stat_scratch(h, d):
+    return [
+        pltpu.VMEM((h, 128), jnp.float32),   # running row max
+        pltpu.VMEM((h, 128), jnp.float32),   # running row sum
+        pltpu.VMEM((h, d), jnp.float32),     # output accumulator
+    ]
 
 
 def _pallas_decode(q, k_cache, v_cache, lengths, scale, interpret,
@@ -125,43 +160,46 @@ def _pallas_decode(q, k_cache, v_cache, lengths, scale, interpret,
     # no standard divisor: run the whole cache as one block.  Fine in
     # interpret mode (tests at any max_len); on real TPU the auto
     # dispatch only takes this path when a 128-multiple block divides T
-    # (_use_pallas), so an explicit caller owns the tiling constraint.
-    bk = block_k or _pick_block_k(t) or t
+    # (_reference_reason), so an explicit caller owns the tiling
+    # constraint.
+    bk = block_k or _pick_block_k(t, h, d, k_cache.dtype) or t
     if t % bk:
         raise ValueError(
             "block_k=%d does not divide cache length %d" % (bk, t))
     nk = t // bk
-    # length mask as an additive [N, 1, T] bias (one f32 row per slot:
-    # O(T) HBM, vs the O(H*T) score tensor the kernel never emits)
-    pos = jnp.arange(t, dtype=jnp.int32)
-    bias = jnp.where(pos[None, :] < lengths[:, None], 0.0,
-                     NEG_INF).astype(jnp.float32)[:, None, :]
     kernel = functools.partial(_kernel, scale=scale, bk=bk, nk=nk)
-    return pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,          # lengths: the mask is built in-kernel
         grid=(n, nk),
         in_specs=[
-            pl.BlockSpec((1, h, d), lambda g, j: (g, 0, 0)),
-            pl.BlockSpec((1, bk, h, d), lambda g, j: (g, j, 0, 0)),
-            pl.BlockSpec((1, bk, h, d), lambda g, j: (g, j, 0, 0)),
-            pl.BlockSpec((1, 1, bk), lambda g, j: (g, 0, j)),
+            pl.BlockSpec((1, h, d), lambda g, j, ln: (g, 0, 0)),
+            pl.BlockSpec((1, bk, h, d), lambda g, j, ln: (g, j, 0, 0)),
+            pl.BlockSpec((1, bk, h, d), lambda g, j, ln: (g, j, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, h, d), lambda g, j: (g, 0, 0)),
+        out_specs=pl.BlockSpec((1, h, d), lambda g, j, ln: (g, 0, 0)),
+        scratch_shapes=_stat_scratch(h, d),
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, h, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((h, 128), jnp.float32),   # running row max
-            pltpu.VMEM((h, 128), jnp.float32),   # running row sum
-            pltpu.VMEM((h, d), jnp.float32),     # output accumulator
-        ],
         interpret=interpret,
-    )(q, k_cache, v_cache, bias)
+    )(lengths, q, k_cache, v_cache)
 
 
-def _use_pallas(k_cache):
+def _reference_reason(k_cache):
+    """The rule that sends this call to the jnp reference, or None when
+    the kernel takes it."""
     if jax.default_backend() != "tpu":
-        return False
-    t, d = k_cache.shape[1], k_cache.shape[-1]
-    return d % 64 == 0 and _pick_block_k(t) is not None
+        return "backend is not a TPU"
+    _, t, h, d = k_cache.shape
+    if d % 64:
+        return "head_dim %d is not a multiple of 64" % d
+    if _pick_block_k(t, h, d, k_cache.dtype) is None:
+        return ("no 128-multiple block divides cache length %d and fits "
+                "%d MiB of VMEM at H=%d, D=%d"
+                % (t, _KV_VMEM_BUDGET >> 20, h, d))
+    return None
 
 
 def decode_attention(q, k_cache, v_cache, lengths, scale=None,
@@ -176,8 +214,13 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
     if scale is None:
         scale = float(q.shape[-1]) ** -0.5
     lengths = lengths.astype(jnp.int32)
-    if interpret is None and not _use_pallas(k_cache):
-        return decode_attention_reference(q, k_cache, v_cache, lengths,
-                                          scale)
+    if interpret is None:
+        reason = _reference_reason(k_cache)
+        dispatch.record("decode_attention",
+                        "reference" if reason else "pallas",
+                        reason or "dense decode kernel shape rules met")
+        if reason:
+            return decode_attention_reference(q, k_cache, v_cache,
+                                              lengths, scale)
     return _pallas_decode(q, k_cache, v_cache, lengths, scale,
                           bool(interpret), block_k=block_k)

@@ -192,6 +192,17 @@ def _residual_kind(act):
     return None
 
 
+def _mxu_dot(a, b, dims):
+    """float32-accumulating dot.  Only float32 operands take the
+    process's `jax_default_matmul_precision`: narrower ones multiply
+    exactly on the MXU, and "highest" would reach Mosaic as an fp32
+    contract on bf16 operands, which it refuses ("Bad lhs type")."""
+    f32 = a.dtype == jnp.float32 and b.dtype == jnp.float32
+    return jax.lax.dot_general(
+        a, b, dims, precision=None if f32 else jax.lax.Precision.DEFAULT,
+        preferred_element_type=jnp.float32)
+
+
 # ---------------------------------------------------------------------------
 # forward kernel
 # ---------------------------------------------------------------------------
@@ -215,10 +226,8 @@ def _fwd_kernel(*refs, act, approx, nk, has_bias, emit_z):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jax.lax.dot_general(
-        x_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    acc_ref[...] += _mxu_dot(x_ref[...], w_ref[...],
+                             (((1,), (0,)), ((), ())))
 
     @pl.when(kblk == nk - 1)
     def _epilogue():
@@ -283,10 +292,7 @@ def _bwd_dx_kernel(g_ref, res_ref, w_ref, dx_ref, acc_ref, *, act, approx,
     dz = (_dact_from_residual(g, res_ref[...].astype(jnp.float32), act,
                               approx)
           if res_ref is not None else g)
-    acc_ref[...] += jax.lax.dot_general(
-        dz, w_ref[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    acc_ref[...] += _mxu_dot(dz, w_ref[...], (((1,), (1,)), ((), ())))
 
     @pl.when(j == nn - 1)
     def _finalize():
@@ -310,10 +316,7 @@ def _bwd_dw_kernel(x_ref, g_ref, res_ref, dw_ref, db_ref, dw_acc, db_acc,
     dz = (_dact_from_residual(g, res_ref[...].astype(jnp.float32), act,
                               approx)
           if res_ref is not None else g)
-    dw_acc[...] += jax.lax.dot_general(
-        x_ref[...], dz, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    dw_acc[...] += _mxu_dot(x_ref[...], dz, (((0,), (0,)), ((), ())))
 
     @pl.when(mm == nm - 1)
     def _write_dw():

@@ -43,7 +43,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .decode_attention import decode_attention_reference
+from .. import dispatch
+from .decode_attention import (
+    _KV_VMEM_BUDGET,
+    _online_softmax_block,
+    _stat_scratch,
+    decode_attention_reference,
+    kv_block_vmem_bytes,
+)
 
 NEG_INF = -1e30
 
@@ -152,53 +159,17 @@ def chunked_attention_reference(q, k_cache, v_cache, start, n_real=None,
 
 def _paged_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref,
                   o_ref, m_ref, l_ref, acc_ref, *, scale, bs, nb):
-    """Grid (N, nb): per slot, stream TABLE-MAPPED pool blocks with
-    running (m, l, acc) online-softmax statistics.  The index maps
+    """Grid (N, nb): per slot, stream TABLE-MAPPED pool blocks through
+    the dense kernel's online-softmax block update.  The index maps
     already routed k_ref/v_ref to pool block ``tables[n, j]``; in here
     only the length mask remains — positions ``j*bs + o >= lengths[n]``
     are killed, so blocks wholly past the length contribute nothing
     (their p rows are exactly zero)."""
-    n = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0].astype(jnp.float32)                   # [H, D]
-    k = k_ref[0].astype(jnp.float32)                   # [bs, H, D]
-    v = v_ref[0].astype(jnp.float32)                   # [bs, H, D]
-    s = jax.lax.dot_general(
-        q, k.transpose(1, 0, 2), (((1,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    ) * scale                                          # [H, bs]
-    length = lengths_ref[n]
-    off = jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1) + j * bs
-    s = jnp.where(off < length, s, NEG_INF)
-
-    m_prev = m_ref[:, 0]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    p = jnp.exp(s - m_new[:, None])
-    p = jnp.where(s <= NEG_INF / 2, 0.0, p)
-    corr = jnp.exp(m_prev - m_new)
-    l_new = l_ref[:, 0] * corr + jnp.sum(p, axis=1)
-    pv = jax.lax.dot_general(
-        p, v.transpose(1, 0, 2), (((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )
-    acc_ref[...] = acc_ref[...] * corr[:, None] + pv
-    m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
-
-    @pl.when(j == nb - 1)
-    def _finalize():
-        l = l_ref[:, 0]
-        safe_l = jnp.where(l == 0.0, 1.0, l)
-        out = acc_ref[...] / safe_l[:, None]
-        dead = m_ref[:, 0] <= NEG_INF / 2              # empty slot
-        o_ref[0] = jnp.where(dead[:, None], 0.0, out).astype(o_ref.dtype)
+    del tables_ref                      # consumed by the index maps
+    _online_softmax_block(
+        q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
+        length=lengths_ref[pl.program_id(0)], j=pl.program_id(1),
+        nblocks=nb, scale=scale, bk=bs)
 
 
 def _pallas_paged(q, k_pool, v_pool, tables, lengths, scale, interpret):
@@ -220,11 +191,7 @@ def _pallas_paged(q, k_pool, v_pool, tables, lengths, scale, interpret):
                          lambda g, j, tab, ln: (tab[g, j], 0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, h, d), lambda g, j, tab, ln: (g, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((h, 128), jnp.float32),   # running row max
-            pltpu.VMEM((h, 128), jnp.float32),   # running row sum
-            pltpu.VMEM((h, d), jnp.float32),     # output accumulator
-        ],
+        scratch_shapes=_stat_scratch(h, d),
     )
     return pl.pallas_call(
         kernel,
@@ -235,11 +202,24 @@ def _pallas_paged(q, k_pool, v_pool, tables, lengths, scale, interpret):
       q, k_pool, v_pool)
 
 
-def _use_pallas(k_pool):
+def _reference_reason(k_pool, quantized):
+    """The rule that sends this call to the gather reference, or None
+    when the kernel takes it."""
+    if quantized:
+        return "int8 pool: the kernel reads float blocks only"
     if jax.default_backend() != "tpu":
-        return False
-    bs, d = int(k_pool.shape[1]), int(k_pool.shape[-1])
-    return d % 64 == 0 and bs % 128 == 0
+        return "backend is not a TPU"
+    _, bs, h, d = (int(x) for x in k_pool.shape)
+    if d % 64:
+        return "head_dim %d is not a multiple of 64" % d
+    if bs % 128:
+        return "block_size %d is not a multiple of 128" % bs
+    need = kv_block_vmem_bytes(bs, h, d, k_pool.dtype)
+    if need > _KV_VMEM_BUDGET:
+        return ("blocks of %d rows at H=%d, D=%d need %d MiB of VMEM, "
+                "over the %d MiB budget"
+                % (bs, h, d, need >> 20, _KV_VMEM_BUDGET >> 20))
+    return None
 
 
 def paged_decode_attention(q, k_pool, v_pool, tables, lengths,
@@ -256,12 +236,14 @@ def paged_decode_attention(q, k_pool, v_pool, tables, lengths,
         scale = float(q.shape[-1]) ** -0.5
     lengths = jnp.asarray(lengths).astype(jnp.int32)
     tables = jnp.asarray(tables).astype(jnp.int32)
-    if k_scale is not None:
-        return paged_decode_attention_reference(
-            q, k_pool, v_pool, tables, lengths, scale,
-            k_scale=k_scale, v_scale=v_scale)
-    if interpret is None and not _use_pallas(k_pool):
-        return paged_decode_attention_reference(
-            q, k_pool, v_pool, tables, lengths, scale)
+    if interpret is None or k_scale is not None:
+        reason = _reference_reason(k_pool, k_scale is not None)
+        dispatch.record("paged_decode_attention",
+                        "gather reference" if reason else "pallas",
+                        reason or "paged decode kernel shape rules met")
+        if reason:
+            return paged_decode_attention_reference(
+                q, k_pool, v_pool, tables, lengths, scale,
+                k_scale=k_scale, v_scale=v_scale)
     return _pallas_paged(q, k_pool, v_pool, tables, lengths, scale,
                          bool(interpret))

@@ -36,6 +36,7 @@ import threading
 
 import numpy as np
 
+from ..fluid.core.place import check_children_can_take_chip
 from ..observability import locks as _locks
 
 __all__ = [
@@ -275,6 +276,8 @@ class ProcessReplica(Replica):
         repo_root = os.path.dirname(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))))
         worker_env.setdefault("PYTHONPATH", repo_root)
+        check_children_can_take_chip(
+            "process replica %s" % self.replica_id, worker_env)
         self._proc = subprocess.Popen(
             [sys.executable, "-m", "paddle_tpu.serving.worker", model_dir],
             env=worker_env, pass_fds=(c2w_r, w2c_w), close_fds=True)

@@ -5,7 +5,7 @@ tensor-parallel programs over a one-axis ``Mesh(("tp",))``.
 Everything host-side is INHERITED unchanged — scheduling, block
 accounting, prefix cache, chunked prefill, speculative decoding,
 admission, metrics, hot-swap: the subclass only overrides the
-``_make_*_fn`` factories to return `fluid.core.jax_compat.shard_map`
+``_make_*_fn`` factories to return `jax.shard_map`
 wrappings of the shard-local functional forward (`tp_serving.model`)
 with IDENTICAL positional signatures, so every call site, the
 compile-count pin, and the one-executable-per-config invariant carry
@@ -33,7 +33,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from ..fluid.core import jax_compat
 from ..generation.engine import GenerationEngine
 from ..generation.sampling import sample_tokens, token_logprobs
 from . import model as tp_model
@@ -119,8 +118,8 @@ class TPGenerationEngine(GenerationEngine):
                     + (P(),) * n_host)
         out_specs = cache_specs + (P(),) * (
             2 if self.return_logprobs else 1)
-        return jax_compat.shard_map(body, self._mesh, in_specs,
-                                    out_specs, check=False)
+        return jax.shard_map(body, mesh=self._mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     # -- traced-function factories (same signatures as the base) ----------
     def _make_decode_fn(self):
@@ -336,24 +335,6 @@ class TPGenerationEngine(GenerationEngine):
             "count_match": len(layer) == est["all_reduce_count"],
             "wire_match": wire == est["comm_bytes_per_step"],
         }
-
-    def decode_hlo(self):
-        """Optimized HLO of the ACTUAL decode executable, lowered with
-        the engine's live operands — what the comm drills pin
-        `decode_comm_estimate` against."""
-        with self._lock:
-            if self.paged:
-                lowered = self._decode_step_fn.lower(
-                    self._params, *self.cache.arrays(), self._lengths,
-                    self._last_tokens, self._keys, self._steps,
-                    self._temp, self._top_k, self._top_p,
-                    self._decode_tables())
-            else:
-                lowered = self._decode_step_fn.lower(
-                    self._params, self.cache.k, self.cache.v,
-                    self._lengths, self._last_tokens, self._keys,
-                    self._steps, self._temp, self._top_k, self._top_p)
-        return lowered.compile().as_text()
 
     # -- hot-swap boundary (canonical layout outside, shard-major in) -----
     def snapshot_params(self):

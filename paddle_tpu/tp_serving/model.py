@@ -1,6 +1,6 @@
 """Shard-local functional TransformerLM forward for tensor-parallel
-serving — the math each chip runs inside `fluid.core.jax_compat
-.shard_map` over the ``("tp",)`` mesh.
+serving — the math each chip runs inside `jax.shard_map` over the
+``("tp",)`` mesh.
 
 This mirrors the single-chip lowering op for op (`models.transformer_lm`
 through `fluid/ops`): f32 LayerNorm (eps 1e-5), erf gelu, the flattened

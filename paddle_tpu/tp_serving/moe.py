@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..fluid.core import jax_compat
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["build_ep_moe", "ep_moe_comm_bytes", "moe_params",
@@ -160,9 +159,9 @@ def build_ep_moe(mesh, num_experts, *, capacity_factor=1.25, top_k=1,
     }
     out_specs = (P("tp", None), P("tp", None)) if expert_stats \
         else P("tp", None)
-    mapped = jax_compat.shard_map(
-        body, mesh, in_specs=(param_specs, P("tp", None)),
-        out_specs=out_specs, check=False)
+    mapped = jax.shard_map(
+        body, mesh=mesh, in_specs=(param_specs, P("tp", None)),
+        out_specs=out_specs, check_vma=False)
     return jax.jit(mapped)
 
 

@@ -50,25 +50,13 @@ _SUBDIR = "paddle_tpu_tune"
 
 
 def default_cache_dir():
-    """Resolution order: $PADDLE_TPU_TUNE_CACHE > the live jax
-    persistent-compilation-cache dir (set by PR-2's
-    ``enable_compilation_cache``) > the PR-2 default cache path.  The
-    tuning cache is a subdirectory, so it never collides with jax's own
-    entries."""
-    env = os.getenv(CACHE_DIR_ENV)
-    if env:
-        return os.path.join(env, _SUBDIR)
-    jax_dir = None
-    try:
-        import jax
+    """$PADDLE_TPU_TUNE_CACHE, else a subdirectory of the compile
+    cache's directory (`fluid.core.compile_cache`), so tuned configs and
+    compiled executables travel together and never collide."""
+    from ..fluid.core.compile_cache import compile_cache_dir
 
-        jax_dir = jax.config.jax_compilation_cache_dir
-    except Exception:
-        pass
-    if jax_dir:
-        return os.path.join(jax_dir, _SUBDIR)
-    return os.path.join(os.path.expanduser("~"), ".cache",
-                        "paddle_tpu_xla_cache", _SUBDIR)
+    return os.path.join(os.getenv(CACHE_DIR_ENV) or compile_cache_dir(),
+                        _SUBDIR)
 
 
 def _mesh_desc(mesh):

@@ -28,7 +28,6 @@ def test_mesh_construction():
 
 
 def test_collectives_under_shard_map():
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = dist.auto_mesh(8)
@@ -41,7 +40,7 @@ def test_collectives_under_shard_map():
         g = dist.all_gather(x, axis="dp")
         return s, mx, g
 
-    s, mx, g = shard_map(
+    s, mx, g = jax.shard_map(
         body, mesh=mesh.mesh,
         in_specs=(P("dp", None),),
         out_specs=(P("dp", None), P("dp", None), P("dp", None)),
@@ -64,7 +63,6 @@ def test_collective_program_ops_single_rank_identity():
 
 
 def test_send_recv_ring_shift():
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = dist.auto_mesh(8)
@@ -74,8 +72,8 @@ def test_send_recv_ring_shift():
     def body(x):
         return dist.send_recv(x, perm, axis="dp")
 
-    out = shard_map(body, mesh=mesh.mesh, in_specs=(P("dp", None),),
-                    out_specs=P("dp", None))(x)
+    out = jax.shard_map(body, mesh=mesh.mesh, in_specs=(P("dp", None),),
+                        out_specs=P("dp", None))(x)
     np.testing.assert_allclose(
         np.asarray(out)[:, 0], [7, 0, 1, 2, 3, 4, 5, 6]
     )

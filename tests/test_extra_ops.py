@@ -368,7 +368,6 @@ def test_sync_batch_norm_syncs_across_mesh_ranks():
 
     from paddle_tpu.fluid.core.registry import get_op_def, LowerContext
     from paddle_tpu import distributed as dist
-    from paddle_tpu.fluid.core.jax_compat import shard_map
 
     mesh = dist.auto_mesh(8)
     x = _r(16, 3, 2, 2)
@@ -387,9 +386,9 @@ def test_sync_batch_norm_syncs_across_mesh_ranks():
         )
         return out["Y"][0]
 
-    y = jax.jit(shard_map(
+    y = jax.jit(jax.shard_map(
         body, mesh=mesh.mesh,
-        in_specs=(P("dp"),), out_specs=P("dp"), check=False,
+        in_specs=(P("dp"),), out_specs=P("dp"), check_vma=False,
     ))(x)
     mean = x.mean(axis=(0, 2, 3))
     var = x.var(axis=(0, 2, 3))

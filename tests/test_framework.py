@@ -155,3 +155,58 @@ def test_clone_for_test_strips_optimizer():
     assert "sgd" not in types
     drop_ops = [op for op in test_prog.global_block.ops if op.type == "dropout"]
     assert all(op.attrs["is_test"] for op in drop_ops)
+
+
+# ---------------------------------------------------------------------------
+# Place resolution and one-process-per-chip (PR 22)
+# ---------------------------------------------------------------------------
+
+
+def test_place_names_a_real_device_or_raises():
+    """A place never resolves to another device than it names: a
+    `TPUPlace` that ran on the CPU would report CPU results under the
+    chip's name."""
+    import jax
+
+    assert fluid.CPUPlace(0).get_device() == jax.devices("cpu")[0]
+    assert fluid.CPUPlace(7).get_device() == jax.devices("cpu")[7]
+    with pytest.raises(RuntimeError, match="names no device"):
+        fluid.TPUPlace(0).get_device()          # no TPU in this process
+    with pytest.raises(RuntimeError, match="names no device"):
+        fluid.CPUPlace(99).get_device()         # past the last device
+    with pytest.raises(RuntimeError, match="names no device"):
+        fluid.Executor(fluid.TPUPlace(0)).run(fluid.Program())
+
+
+def test_children_that_need_the_chip_are_refused_by_a_parent_that_holds_it(
+        monkeypatch):
+    import jax
+
+    from paddle_tpu.fluid.core.place import check_children_can_take_chip
+
+    jax.devices()                               # a backend is initialized
+    # the CPU is not exclusive: a CPU parent may start anything
+    check_children_can_take_chip("workers", {"JAX_PLATFORMS": "tpu"})
+    # a parent that holds an accelerator may start only CPU children
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    check_children_can_take_chip("workers", {"JAX_PLATFORMS": "cpu"})
+    with pytest.raises(RuntimeError, match="one process at a time"):
+        check_children_can_take_chip("workers", {})
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    with pytest.raises(RuntimeError, match="cannot start ranks"):
+        check_children_can_take_chip("ranks")   # child env defaults to ours
+
+
+def test_cpu_only_children_restores_the_environment(monkeypatch):
+    import os
+
+    from paddle_tpu.fluid.core.place import cpu_only_children
+
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    with cpu_only_children():
+        assert os.environ["JAX_PLATFORMS"] == "cpu"
+    assert "JAX_PLATFORMS" not in os.environ
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    with cpu_only_children():
+        assert os.environ["JAX_PLATFORMS"] == "cpu"
+    assert os.environ["JAX_PLATFORMS"] == "tpu,cpu"

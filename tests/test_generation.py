@@ -260,8 +260,13 @@ class TestTransformerLM:
                 dygraph.to_variable(np.full((B, 1), S - 1, np.int64)),
                 caches=(jnp.asarray(k_stack), jnp.asarray(v_stack)),
                 cache_positions=jnp.asarray([S - 1] * B))
-        # bit-identical: the cached path IS the full math at the last row
-        np.testing.assert_array_equal(logits.numpy()[:, 0], full[:, -1])
+        # the cached path IS the full math at the last row, but not its
+        # summation order: the decode step contracts a [1, D] query row
+        # where the full forward contracts [S, D], and the CPU backend
+        # blocks the two matmuls differently — observed 6e-8 apart, so the
+        # pin is a float32 rounding bound, not bit equality
+        np.testing.assert_allclose(logits.numpy()[:, 0], full[:, -1],
+                                   rtol=0, atol=1e-6)
         # and the step wrote this token's K/V at position S-1
         assert np.any(np.asarray(k2)[0, :, S - 1] != 0)
 

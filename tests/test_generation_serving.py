@@ -221,31 +221,47 @@ class TestHttpFront:
         conn.close()
         assert out["tokens"] == streamed
 
-    def test_shed_answers_503_with_retry_after(self, front):
-        fleet, port = front
-        # saturate: 2 slots busy on long generations + queue of 2
+    def test_shed_answers_503_with_retry_after(self, lm):
+        """Saturation is built, not raced: the scheduler loop is not
+        started, so the test itself decides when slots fill and when
+        the queue does (a load thread against a running tiny model
+        drains faster than it can fill)."""
+        fleet = make_fleet(lm, replicas=1, max_queue=2)   # no .start()
+        engine = fleet.replicas[0].engine
+        port = free_port()
+        httpd = serving.serve_generation_http(fleet, port=port,
+                                              block=False)
+        long_req = {"prompt": [1, 2, 3], "max_new_tokens": 40,
+                    "stream": True, "timeout": 30}
         conns = []
-        for _ in range(4):
-            conns.append(self._post(
-                port, {"prompt": [1, 2, 3], "max_new_tokens": 40,
-                       "stream": True})[0])
-        deadline = time.monotonic() + 30
-        status, retry = None, None
-        while time.monotonic() < deadline:
+        try:
+            # 2 slots busy on long generations ...
+            conns += [self._post(port, long_req) for _ in range(2)]
+            assert engine.step()
+            occ = engine.occupancy()
+            assert (occ["active"], occ["pending"]) == (2, 0)
+            # ... + a full queue of 2
+            conns += [self._post(port, long_req) for _ in range(2)]
+            assert engine.occupancy()["pending"] == 2
             conn, resp = self._post(
                 port, {"prompt": [1, 2], "max_new_tokens": 2,
                        "stream": False})
-            status = resp.status
             retry = resp.getheader("Retry-After")
-            body = resp.read()
+            body = json.loads(resp.read())
             conn.close()
-            if status == 503:
-                assert json.loads(body)["reason"] == "slots_full"
-                break
-        assert status == 503, "fleet never saturated"
-        assert retry is not None and int(retry) >= 1
-        for c in conns:
-            c.close()
+            assert resp.status == 503
+            assert body["shed"] and body["reason"] == "slots_full"
+            assert retry is not None and int(retry) >= 1
+            # nothing was dropped: the four admitted requests finish
+            fleet.start()
+            for _, stream in conns:
+                done = json.loads(stream.read().splitlines()[-1])
+                assert done["done"] and done["n_tokens"] == 40
+        finally:
+            for c, _ in conns:
+                c.close()
+            httpd.shutdown()
+            fleet.stop()
 
     def test_bad_request_400(self, front):
         _, port = front

@@ -209,6 +209,34 @@ def test_dataloader_worker_error_propagates():
         list(dl)
 
 
+class _EnvDataset(_SlowDataset):
+    """Each item reports whether its worker is pinned to the CPU."""
+
+    def __getitem__(self, i):
+        import os
+
+        pinned = os.environ.get("JAX_PLATFORMS") == "cpu"
+        return np.full((1,), float(pinned), np.float32), np.int64(0)
+
+
+def test_dataloader_workers_are_pinned_to_the_cpu(monkeypatch):
+    """A chip belongs to one process at a time: workers only collate on
+    the host, so they must never initialize the parent's accelerator
+    backend — whatever the parent's own JAX_PLATFORMS says."""
+    import os
+
+    from paddle_tpu.fluid.reader import DataLoader
+
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    dl = DataLoader(_EnvDataset(8), batch_size=4, num_workers=2)
+    try:
+        for bx, _ in dl:
+            assert bx.min() == 1.0, "a worker saw the parent's platforms"
+    finally:
+        dl.close()
+    assert os.environ["JAX_PLATFORMS"] == "tpu,cpu"    # parent untouched
+
+
 def test_distributed_batch_sampler_partitions_and_pads():
     from paddle_tpu.fluid.reader import DistributedBatchSampler, TensorDataset
 

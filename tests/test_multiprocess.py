@@ -58,7 +58,6 @@ def _run_multi(tmp_path, nproc=2):
     return results
 
 
-@pytest.mark.needs_xla_multiprocess
 def test_two_process_loss_parity(tmp_path):
     single = _run_single(tmp_path)
     multi = _run_multi(tmp_path, nproc=2)

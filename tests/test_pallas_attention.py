@@ -416,3 +416,72 @@ def test_partial_explicit_block_keeps_env_for_other_side(monkeypatch):
     monkeypatch.setenv("PADDLE_TPU_FLASH_BLOCKS", "nope")
     with pytest.raises(ValueError, match="two ints"):
         _block_sizes(512, 512, 128, None)
+
+
+def test_dispatch_choice_is_counted_with_the_rule_that_decided():
+    """No dispatch between a kernel and its composition is silent: each
+    trace lands in `kernel_dispatch_total{op, impl, rule}`."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import dispatch
+    from paddle_tpu.ops.attention import scaled_dot_product_attention
+    from paddle_tpu.ops.pallas.decode_attention import decode_attention
+    from paddle_tpu.ops.pallas.paged_attention import paged_decode_attention
+
+    before = dispatch.choices()
+
+    def delta(key):
+        return dispatch.choices().get(key, 0) - before.get(key, 0)
+
+    q = jnp.ones((1, 2, 8, 16), jnp.float32)
+    scaled_dot_product_attention(q, q, q)
+    assert delta(("attention", "naive", "backend is not a TPU")) == 1
+
+    dq = jnp.ones((2, 2, 64), jnp.float32)
+    cache = jnp.ones((2, 128, 2, 64), jnp.float32)
+    lens = jnp.asarray([3, 5], jnp.int32)
+    decode_attention(dq, cache, cache, lens)
+    assert delta(("decode_attention", "reference",
+                  "backend is not a TPU")) == 1
+
+    pool = jnp.ones((3, 16, 2, 64), jnp.int8)
+    scale = jnp.ones((3, 16, 2), jnp.float32)
+    tables = jnp.asarray([[1], [2]], jnp.int32)
+    paged_decode_attention(dq, pool, pool, tables, lens, k_scale=scale,
+                           v_scale=scale)
+    assert delta(("paged_decode_attention", "gather reference",
+                  "int8 pool: the kernel reads float blocks only")) == 1
+    # an explicit interpret= request is the caller's choice, not a dispatch
+    decode_attention(dq, cache, cache, lens, interpret=True)
+    assert delta(("decode_attention", "reference",
+                  "backend is not a TPU")) == 1
+
+
+def test_dispatch_names_the_shape_rule_on_a_tpu(monkeypatch):
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.attention import _naive_reason
+    from paddle_tpu.ops.pallas import decode_attention as da
+    from paddle_tpu.ops.pallas import paged_attention as pa
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    q = jax.ShapeDtypeStruct((48, 512, 12, 64), jnp.bfloat16)
+    assert _naive_reason(q, q, None, "BSHD") is None
+    short = jax.ShapeDtypeStruct((1, 8, 12, 64), jnp.float32)
+    assert "shorter than 192" in _naive_reason(short, short, None, "BSHD")
+
+    pool16 = jax.ShapeDtypeStruct((9, 16, 12, 64), jnp.float32)
+    assert pa._reference_reason(pool16, False) == \
+        "block_size 16 is not a multiple of 128"
+    pool128 = jax.ShapeDtypeStruct((9, 128, 12, 64), jnp.float32)
+    assert pa._reference_reason(pool128, False) is None
+    pool1024 = jax.ShapeDtypeStruct((9, 1024, 12, 64), jnp.float32)
+    assert "MiB of VMEM" in pa._reference_reason(pool1024, False)
+
+    assert da._reference_reason(
+        jax.ShapeDtypeStruct((4, 1024, 12, 64), jnp.float32)) is None
+    assert "not a multiple of 64" in da._reference_reason(
+        jax.ShapeDtypeStruct((4, 1024, 12, 48), jnp.float32))
+    assert "cache length 1000" in da._reference_reason(
+        jax.ShapeDtypeStruct((4, 1000, 12, 64), jnp.float32))

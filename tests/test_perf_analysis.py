@@ -786,3 +786,25 @@ def test_rank_pass_pipelines_prefers_matmul_bias_act_fuse():
         main, [[], ["matmul_bias_act_fuse"]], chip=CHIP)
     assert ranked[0].pipeline == ("matmul_bias_act_fuse",)
     assert ranked[0].time_s < ranked[1].time_s
+
+
+def test_chip_spec_detect_reads_the_one_table_and_refuses_unknown_chips():
+    """`ChipSpec.detect` prices from `xla_cost.CHIP_PEAKS`, keyed by
+    device_kind: the v5e row for a v5e, the TARGET chip (named as such)
+    on a host with no accelerator, and an error — never v5e figures —
+    for an accelerator the table does not list."""
+    from paddle_tpu.observability import xla_cost
+
+    row = xla_cost.CHIP_PEAKS["TPU v5 lite"]
+    v5e = perf.ChipSpec.detect(device_kind="TPU v5 lite")
+    assert (v5e.name, v5e.peak_flops, v5e.hbm_bw, v5e.ici_bw, v5e.host_bw) \
+        == ("tpu-v5e", row["peak_flops"], row["hbm_bw"], row["ici_bw"],
+            row["host_bw"])
+    assert perf.V5E.to_dict() == v5e.to_dict()
+    here = perf.ChipSpec.detect()                # the CPU backend
+    assert here.name == "tpu-v5e (target)"
+    assert here.peak_flops == v5e.peak_flops
+    with pytest.raises(xla_cost.UnknownDeviceError, match="TPU v9"):
+        perf.ChipSpec.detect(device_kind="TPU v9")
+    # explicit figures still win over the table, axis by axis
+    assert perf.ChipSpec.detect(peak_flops=1e12).peak_flops == 1e12

@@ -92,18 +92,25 @@ def test_custom_op_registration_from_user_code():
     its gradient; layers drive it through a Program."""
     import jax.numpy as jnp
 
-    from paddle_tpu.fluid.core.registry import get_op_def, register_op
+    from paddle_tpu.fluid.core import registry
+    from paddle_tpu.fluid.core.registry import register_op
 
-    if not hasattr(get_op_def, "_test_relu3_registered"):
-        @register_op("user_relu3", inputs=["X"], outputs=["Out"])
-        def _user_relu3(ctx, ins, attrs):
-            """User op: relu(x)^3, scaled by an attr."""
-            x = ins["X"][0]
-            s = float(attrs.get("scale", 1.0))
-            return {"Out": [jnp.maximum(x, 0.0) ** 3 * s]}
+    @register_op("user_relu3", inputs=["X"], outputs=["Out"])
+    def _user_relu3(ctx, ins, attrs):
+        """User op: relu(x)^3, scaled by an attr."""
+        x = ins["X"][0]
+        s = float(attrs.get("scale", 1.0))
+        return {"Out": [jnp.maximum(x, 0.0) ** 3 * s]}
 
-        get_op_def._test_relu3_registered = True
+    try:
+        _run_user_relu3()
+    finally:
+        # the op table is process-wide: a later test in this worker
+        # (test_api_spec) must not see this test's op in the surface
+        registry._OP_REGISTRY.pop("user_relu3")
 
+
+def _run_user_relu3():
     from paddle_tpu.fluid.layers.common import append_simple_op
 
     main, startup = fluid.Program(), fluid.Program()

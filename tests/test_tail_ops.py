@@ -571,3 +571,28 @@ def test_nce_noise_correction():
     # every sampled logit is log(k q) -> adjusted 0 -> each term log 2
     np.testing.assert_allclose(outs["Cost"][0, 0], (1 + k) * np.log(2),
                                rtol=1e-4)
+
+
+def test_assert_op_passes_and_raises_from_inside_the_compiled_program():
+    """`Assert` is a host callback in the compiled step.  It has no
+    warn-and-continue branch any more (PR 22): on a backend with host
+    callbacks it works, elsewhere the step fails loudly."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid import layers
+
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = layers.data("x", shape=[-1, 3], append_batch_size=False)
+        total = layers.reduce_sum(x)
+        layers.control_flow.Assert(
+            layers.less_than(total, layers.fill_constant([1], "float32", 10.0)),
+            data=[total], message="total too large")
+        out = total * 2.0
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup)
+    got, = exe.run(main, feed={"x": np.ones((2, 3), np.float32)},
+                   fetch_list=[out])
+    assert float(np.asarray(got).reshape(-1)[0]) == 12.0
+    with pytest.raises(Exception, match="Assert failed: total too large"):
+        exe.run(main, feed={"x": np.full((2, 3), 5.0, np.float32)},
+                fetch_list=[out])

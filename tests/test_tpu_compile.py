@@ -1,0 +1,191 @@
+"""The main path's kernels, compiled at real widths for a DESCRIBED TPU.
+
+The chip's compiler is installed here and compiles for a `v5e:2x2` chip
+that is described, not attached: what Mosaic or XLA:TPU refuses on the
+chip it refuses here too (a `dot_general` with no non-contracting
+dimension, a primitive without a TPU lowering, a block over the VMEM
+limit), at no chip time.  Interpret-mode tests cannot see any of that —
+both decode kernels passed every one of them and compiled for no chip
+until PR 22.  Nothing runs: a compile that passes says nothing about
+results or times.
+
+Rules this file keeps (see the on-chip-measurement guide): the topology
+is described inside a module-scoped fixture, never at import time, in a
+`skipif` or in `parametrize` arguments — only one process may load the
+TPU's library, and under xdist every worker imports every test file;
+shapes and shardings are built in fixtures and tests; every compile
+happens in this process; the persistent compilation cache is off around
+them (an entry written for a described chip cannot be read back without
+one).  All of these tests live in this one file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+CUSTOM_CALL = 'custom_call_target="tpu_custom_call"'
+
+# BERT-base attention shapes (bench.py: B=48, S=512) and the
+# GPT-2-small decode shapes of benchmarks/generation_bench.py
+B, S, H, D = 48, 512, 12, 64
+SLOTS, T, BS = 8, 1024, 128
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else logs in /tmp
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache
+
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", old)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def compile_for_chip(one_chip, no_persistent_cache):
+    """compile_for_chip(fn, (shape, dtype), ...) -> optimized HLO text of
+    ``fn`` compiled for one described v5e chip."""
+    def compile_(fn, *specs):
+        args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+                for shape, dtype in specs]
+        return jax.jit(fn).lower(*args).compile().as_text()
+
+    return compile_
+
+
+def test_flash_attention_forward_and_backward_bert_base(compile_for_chip):
+    from paddle_tpu.ops.pallas.attention import flash_attention
+
+    def loss(q, k, v, bias):
+        out = flash_attention(q, k, v, bias=bias, layout="BSHD",
+                              interpret=False)
+        return (out.astype(jnp.float32) ** 2).sum()
+
+    qkv = ((B, S, H, D), jnp.bfloat16)
+    hlo = compile_for_chip(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                           qkv, qkv, qkv, ((B, 1, 1, S), jnp.float32))
+    # the forward and at least one backward kernel
+    assert hlo.count(CUSTOM_CALL) >= 2
+    assert "transpose(jvp" in hlo
+
+
+@pytest.mark.parametrize("seq", [512, 500], ids=["S512", "S500-unaligned"])
+def test_flash_attention_causal_prefill(compile_for_chip, seq):
+    from paddle_tpu.ops.pallas.attention import flash_attention
+
+    qkv = ((1, seq, H, D), jnp.float32)
+    hlo = compile_for_chip(
+        lambda q, k, v: flash_attention(q, k, v, causal=True, layout="BSHD",
+                                        interpret=False), qkv, qkv, qkv)
+    assert CUSTOM_CALL in hlo
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_paged_decode_kernel(compile_for_chip, dtype):
+    from paddle_tpu.ops.pallas.paged_attention import paged_decode_attention
+
+    nb = T // BS
+    pool = ((SLOTS * nb + 1, BS, H, D), dtype)
+    hlo = compile_for_chip(
+        lambda q, kp, vp, tab, ln: paged_decode_attention(
+            q, kp, vp, tab, ln, interpret=False),
+        ((SLOTS, H, D), dtype), pool, pool,
+        ((SLOTS, nb), jnp.int32), ((SLOTS,), jnp.int32))
+    assert CUSTOM_CALL in hlo
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_dense_decode_kernel(compile_for_chip, dtype):
+    from paddle_tpu.ops.pallas.decode_attention import decode_attention
+
+    cache = ((SLOTS, T, H, D), dtype)
+    hlo = compile_for_chip(
+        lambda q, k, v, ln: decode_attention(q, k, v, ln, interpret=False),
+        ((SLOTS, H, D), dtype), cache, cache, ((SLOTS,), jnp.int32))
+    assert CUSTOM_CALL in hlo
+
+
+def test_decode_block_choice_fits_vmem():
+    """The dense kernel's 512-row float32 block compiled alone and ran
+    out of scoped VMEM inside the whole GPT-2-small decode step (16.02 of
+    16.00 MiB): the block is now chosen against a VMEM budget."""
+    from paddle_tpu.ops.pallas.decode_attention import (
+        _KV_VMEM_BUDGET,
+        _pick_block_k,
+        kv_block_vmem_bytes,
+    )
+
+    assert kv_block_vmem_bytes(512, H, D, jnp.float32) > _KV_VMEM_BUDGET
+    assert _pick_block_k(T, H, D, jnp.float32) == 256
+    assert _pick_block_k(T, H, D, jnp.bfloat16) == 512
+    assert _pick_block_k(T + 64, H, D, jnp.float32) is None
+
+
+# the BERT-base FFN GEMM: [B*S, 768] x [768, 3072]
+M, K, N = B * S, 768, 3072
+GEMM = (((M, K), jnp.bfloat16), ((K, N), jnp.bfloat16), ((N,), jnp.bfloat16))
+
+
+@pytest.mark.parametrize("act,approx", [("gelu", True), ("relu", False)],
+                         ids=["gelu-tanh-form", "relu"])
+def test_matmul_bias_act_kernel(compile_for_chip, act, approx):
+    from paddle_tpu.ops.pallas.matmul import matmul_bias_act
+
+    def loss(x, w, b):
+        out = matmul_bias_act(x, w, b, activation=act, approximate=approx,
+                              interpret=False)
+        return (out.astype(jnp.float32) ** 2).sum()
+
+    hlo = compile_for_chip(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                           *GEMM)
+    assert hlo.count(CUSTOM_CALL) >= 2
+
+
+def test_exact_gelu_is_routed_to_xla_not_to_a_compile_error(
+        compile_for_chip, monkeypatch):
+    """Mosaic has no `erf`: the op's dispatch refuses exact gelu with the
+    reason counted, and the step compiles as the XLA composition."""
+    from paddle_tpu.fluid.core.registry import LowerContext, get_op_def
+    from paddle_tpu.ops import dispatch
+
+    # the dispatch asks which backend is live; here that is the CPU, so
+    # the test answers for the described chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    lower = get_op_def("matmul_bias_act").lower
+    key = ("matmul_bias_act", "xla composition",
+           "exact gelu: the Pallas TPU lowering has no erf")
+    before = dispatch.choices().get(key, 0)
+
+    def loss(x, w, b):
+        with pytest.warns(UserWarning, match="no erf"):
+            out = lower(LowerContext(), {"X": [x], "Y": [w], "Bias": [b]},
+                        {"act_type": "gelu", "approximate": False,
+                         "x_num_col_dims": 1})["Out"][0]
+        return (out.astype(jnp.float32) ** 2).sum()
+
+    hlo = compile_for_chip(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                           *GEMM)
+    assert CUSTOM_CALL not in hlo
+    assert dispatch.choices()[key] > before

@@ -832,9 +832,6 @@ def test_cost_analysis_normalization():
         {"flops": 10.0, "bytes accessed": 5.0,
          "bytes accessed0{}": 3.0, "not_a_number": "x"})) == \
         {"flops": 10.0, "bytes_accessed": 5.0}
-    # older jax: list of per-device dicts
-    assert XC.cost_analysis_of(
-        FakeCompiled([{"flops": 7.0}]))["flops"] == 7.0
     assert XC.cost_analysis_of(FakeCompiled(None)) is None
     assert XC.cost_analysis_of(FakeCompiled(RuntimeError("no"))) is None
 
@@ -846,10 +843,15 @@ def test_record_mfu_math_and_peak_resolution(monkeypatch):
     assert XC.peak_flops(explicit=5e12) == 5e12
     monkeypatch.setenv(XC.PEAK_FLOPS_ENV, "2e12")
     assert XC.peak_flops() == 2e12
-    assert XC.peak_flops(platform="tpu") == 2e12   # env beats table
+    assert XC.peak_flops(device_kind="TPU v5 lite") == 2e12  # env beats table
     monkeypatch.delenv(XC.PEAK_FLOPS_ENV)
-    assert XC.peak_flops(platform="tpu") == 197e12
-    assert XC.peak_flops(platform="quantum") is None
+    assert XC.peak_flops(device_kind="TPU v5 lite") == 197e12
+    # the host CPU is a known device without a peak; anything the table
+    # does not list is an error, never another chip's figure
+    assert XC.peak_flops(device_kind="cpu") is None
+    assert XC.peak_flops() is None
+    with pytest.raises(XC.UnknownDeviceError, match="TPU v9"):
+        XC.peak_flops(device_kind="TPU v9")
 
     reg = MetricsRegistry()
     mfu = XC.record_mfu("exe", flops=1e12, seconds=0.01, peak=500e12,
@@ -861,8 +863,10 @@ def test_record_mfu_math_and_peak_resolution(monkeypatch):
     # degenerate inputs and unknown peak report nothing
     assert XC.record_mfu("e", 0, 1.0, peak=1e12, registry=reg) is None
     assert XC.record_mfu("e", 1e9, 0.0, peak=1e12, registry=reg) is None
-    assert XC.record_mfu("e", 1e9, 1.0, peak=None, platform="quantum",
+    assert XC.record_mfu("e", 1e9, 1.0, peak=None, device_kind="cpu",
                          registry=reg) is None
+    with pytest.raises(XC.UnknownDeviceError):
+        XC.record_mfu("e", 1e9, 1.0, device_kind="TPU v9", registry=reg)
 
 
 def test_cost_of_jitted_real_executable():

@@ -375,6 +375,22 @@ ENTRY %main (p: f32[64]) -> f32[8] {
     assert ar["result_bytes"] == 8 * 128 * 4
 
 
+def test_hlo_collectives_parse_tuples_longer_than_five_buffers():
+    """XLA:TPU combines gradient buffers into one tuple all-reduce and
+    marks every fifth element "/*index=5*/": the v5e ZeRO-2 BERT-base
+    step has six such all-reduces and the extractor saw two (PR 22)."""
+    hlo = """\
+HloModule tpu
+
+ENTRY %main (p: f32[64]) -> f32[8] {
+  %all-reduce.85 = (f32[16]{0:T(1024)}, f32[16]{0:T(1024)}, f32[16]{0:T(1024)}, f32[16]{0:T(1024)}, f32[16]{0:T(1024)}, /*index=5*/f32[16]{0:T(1024)}, f32[8]{0:T(1024)S(1)}) all-reduce(%a, %b, %c, %d, %e, /*index=5*/%f, %g), channel_id=3
+}
+"""
+    rows = comm_mod.hlo_collectives(hlo)
+    assert [r["kind"] for r in rows] == ["all-reduce"]
+    assert rows[0]["result_bytes"] == (6 * 16 + 8) * 4
+
+
 def test_hlo_collectives_bill_async_pairs_at_the_done():
     """TPU HLO emits async start/done pairs whose -start result is a
     TUPLE of operand + result buffers — billing it would overcount;
