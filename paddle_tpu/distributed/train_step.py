@@ -26,12 +26,17 @@ extracts the compiled HLO's actual collectives so tests (and
 
 from __future__ import annotations
 
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..fluid import framework
 from ..fluid.core.registry import LowerContext, get_op_def
+from ..observability import trace as _trace
+from ..observability.metrics import default_registry
+from ..observability.xla_cost import feed_signature
 from .sharding import ShardingRule, megatron_rule, replicated_rule, zero_shard_state
 from .topology import DeviceMesh
 
@@ -128,24 +133,27 @@ class FunctionalOptimizer:
         return state
 
     def apply(self, params, grads, state, lr):
-        """(params, grads, state, scalar lr) -> (new_params, new_state)."""
+        """(params, grads, state, scalar lr) -> (new_params, new_state).
+        Traced under the scope ``optimizer_update``, by which a device
+        trace finds the update's operations."""
         ctx = LowerContext(base_key=None, is_test=False)
         new_params, new_state = {}, {}
-        for name, p in params.items():
-            g = grads[name]
-            ins = {
-                "Param": [p],
-                "Grad": [g],
-                "LearningRate": [jnp.asarray(lr, jnp.float32)],
-            }
-            for slot, _ in _STATE_SLOTS[self.op_type]:
-                ins[slot] = [state[name][slot]]
-            outs = self._opdef.lower(ctx, ins, self.attrs)
-            new_params[name] = outs["ParamOut"][0]
-            new_state[name] = {
-                slot: outs[_OUT_SLOT[slot]][0]
-                for slot, _ in _STATE_SLOTS[self.op_type]
-            }
+        with jax.named_scope("optimizer_update"):
+            for name, p in params.items():
+                g = grads[name]
+                ins = {
+                    "Param": [p],
+                    "Grad": [g],
+                    "LearningRate": [jnp.asarray(lr, jnp.float32)],
+                }
+                for slot, _ in _STATE_SLOTS[self.op_type]:
+                    ins[slot] = [state[name][slot]]
+                outs = self._opdef.lower(ctx, ins, self.attrs)
+                new_params[name] = outs["ParamOut"][0]
+                new_state[name] = {
+                    slot: outs[_OUT_SLOT[slot]][0]
+                    for slot, _ in _STATE_SLOTS[self.op_type]
+                }
         return new_params, new_state
 
     @property
@@ -281,6 +289,10 @@ class ShardedTrainStep:
         self._step_fns = {}
         self._hlo_texts = {}   # compiled_hlo memo (one AOT compile each)
         self._shardings = None
+        self._m_dispatch_ms = default_registry().histogram(
+            "train_step_dispatch_ms",
+            "ShardedTrainStep call: batch placement and step dispatch, "
+            "until the call returns (ms)")
 
     # -- state ----------------------------------------------------------
     def init(self):
@@ -481,9 +493,10 @@ class ShardedTrainStep:
         return lsum / acc, jax.tree.map(lambda g: g / acc, gsum)
 
     def _losses_and_grads(self, grad_fn, params, batch, key):
-        if self.accumulate_steps > 1:
-            return self._accumulate(grad_fn, params, batch, key)
-        return grad_fn(params, batch, key)
+        with jax.named_scope("loss_and_grad"):
+            if self.accumulate_steps > 1:
+                return self._accumulate(grad_fn, params, batch, key)
+            return grad_fn(params, batch, key)
 
     def _build(self, batch):
         """The GSPMD step (zero_stage <= 1): one jit, XLA inserts the
@@ -494,7 +507,7 @@ class ShardedTrainStep:
         prng_impl = self.prng_impl
         me = self
 
-        def step(train_state, batch):
+        def train_step(train_state, batch):
             params = train_state["params"]
             key = jax.random.fold_in(
                 jax.random.key(0, impl=prng_impl), train_state["step"]
@@ -523,7 +536,7 @@ class ShardedTrainStep:
         batch_sh = self._batch_sharding(batch)
         loss_sh = NamedSharding(self.mesh.mesh, PartitionSpec())
         return jax.jit(
-            step,
+            train_step,
             in_shardings=(state_sh, batch_sh),
             out_shardings=(state_sh, loss_sh),
             donate_argnums=(0,),
@@ -718,7 +731,7 @@ class ShardedTrainStep:
 
         repl = NamedSharding(mesh.mesh, P())
 
-        def step(train_state, batch):
+        def train_step(train_state, batch):
             out_params, out_moments, reasm_out, loss = mapped(
                 train_state, batch)
             # per-bucket all-gathers: one resharding constraint per
@@ -754,7 +767,7 @@ class ShardedTrainStep:
         }
         batch_sh = self._batch_sharding(batch)
         return jax.jit(
-            step,
+            train_step,
             in_shardings=(state_sh, batch_sh),
             out_shardings=(state_sh, repl),
             donate_argnums=(0,),
@@ -769,14 +782,10 @@ class ShardedTrainStep:
         sh = self._batch_sharding(batch)
         return {k: jax.device_put(v, sh[k]) for k, v in batch.items()}
 
-    @staticmethod
-    def _batch_sig(batch):
-        """The executable-cache key for one batch signature — writer
-        (__call__) and reader (cost_analysis) share the one canonical
-        builder in observability.xla_cost."""
-        from ..observability.xla_cost import feed_signature
-
-        return feed_signature(batch)
+    # the executable-cache key for one batch signature — writer
+    # (__call__) and reader (cost_analysis) share the one canonical
+    # builder in observability.xla_cost
+    _batch_sig = staticmethod(feed_signature)
 
     def cost_analysis(self, train_state, batch):
         """XLA `cost_analysis()` of the compiled step executable for this
@@ -862,13 +871,19 @@ class ShardedTrainStep:
             state_slots_per_param=len(self.fopt.moment_slots))
 
     def __call__(self, train_state, batch):
-        batch = {k: jnp.asarray(v) for k, v in batch.items()}
-        sig = self._batch_sig(batch)
-        step_fn = self._step_fns.get(sig)
-        if step_fn is None:
-            if self._shardings is None:
-                raise RuntimeError("call init() before the first step")
-            step_fn = self._step_fns[sig] = self._build_step(batch)
-        batch_sh = self._batch_sharding(batch)
-        batch = {k: jax.device_put(v, batch_sh[k]) for k, v in batch.items()}
-        return step_fn(train_state, batch)
+        t0 = time.perf_counter()
+        with _trace.span("train.step_dispatch", cat="train"):
+            batch = {k: jnp.asarray(v) for k, v in batch.items()}
+            sig = self._batch_sig(batch)
+            step_fn = self._step_fns.get(sig)
+            if step_fn is None:
+                if self._shardings is None:
+                    raise RuntimeError("call init() before the first step")
+                step_fn = self._step_fns[sig] = self._build_step(batch)
+            with _trace.span("train.batch_put", cat="train"):
+                batch_sh = self._batch_sharding(batch)
+                batch = {k: jax.device_put(v, batch_sh[k])
+                         for k, v in batch.items()}
+            out = step_fn(train_state, batch)
+        self._m_dispatch_ms.observe((time.perf_counter() - t0) * 1e3)
+        return out

@@ -213,16 +213,20 @@ class RecordEvent:
     RecordEvent RAII; dygraph/profiler record_event)."""
 
     def __init__(self, name):
-        import jax
-
-        self._ann = jax.profiler.TraceAnnotation(name)
+        self._name = name
+        self._span = None
 
     def __enter__(self):
-        self._ann.__enter__()
+        # the program's one span call site: a TraceAnnotation while a
+        # profiler session runs, a ring event while tracing is enabled
+        from ..observability import trace as _trace
+
+        self._span = _trace.span(self._name, cat="user")
+        self._span.__enter__()
         return self
 
     def __exit__(self, *exc):
-        return self._ann.__exit__(*exc)
+        return self._span.__exit__(*exc)
 
 
 record_event = RecordEvent

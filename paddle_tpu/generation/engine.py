@@ -113,6 +113,36 @@ class EngineDeadError(RuntimeError):
 _TRACE_LOCK = _locks.named_lock("generation.trace")
 
 
+def _named(fn, name):
+    """``fn`` under a stable name: `jax.jit` names the compiled module
+    after it, so a device trace tells the step functions apart on its
+    ``XLA Modules`` line whatever the closures are called."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
+class _DeviceCall:
+    """A span around a device call (its dispatch, or the fetch that
+    waits for it), whose duration also counts as the step's device
+    share: `generation_sched_host_ms` is what a step took beyond these."""
+
+    __slots__ = ("_engine", "_span", "_t0")
+
+    def __init__(self, engine, name, args=None, trace_id=None):
+        self._engine = engine
+        self._span = _trace.span(name, cat="generation", args=args,
+                                 trace_id=trace_id)
+
+    def __enter__(self):
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self._engine._device_s += time.perf_counter() - self._t0
+        return self._span.__exit__(*exc)
+
+
 def _shed_error(reason, retry_after_s, detail):
     from ..serving.admission import ShedError
 
@@ -167,6 +197,10 @@ class RequestHandle:
     def __init__(self, request, trace=None):
         self.request = request
         self._q = queue.Queue()
+        # perf_counter of each put, beside the queue: the event tuples
+        # keep their shape (the ndjson stream is built from them)
+        self._put_times = deque()
+        self.t_event = None            # put time of the event last read
         self._done = threading.Event()
         self._tokens = []
         self._logprobs = []            # filled only on logprob engines
@@ -174,6 +208,7 @@ class RequestHandle:
         self.error = None
         self.requeued = False          # fleet's requeue-once latch
         self.t_submit = time.perf_counter()
+        self.t_queued = None           # entry of the submit that queued it
         self.t_first_token = None
         # the cross-process trace context: ONE per request, created at
         # first submission and carried by the handle thereafter — the
@@ -183,6 +218,10 @@ class RequestHandle:
         self._sink = None              # engine's per-request record sink
 
     # -- engine side ------------------------------------------------------
+    def _put(self, event):
+        self._put_times.append(time.perf_counter())
+        self._q.put(event)
+
     def _emit(self, index, token, logprob=None):
         if index == 0:
             self.t_first_token = time.perf_counter()
@@ -194,10 +233,10 @@ class RequestHandle:
         if logprob is None:
             # logprobs disabled: the event tuple (and hence the ndjson
             # stream upstream) is byte-identical to a pre-logprob engine
-            self._q.put(("token", index, int(token)))
+            self._put(("token", index, int(token)))
         else:
             self._logprobs.append(float(logprob))
-            self._q.put(("token", index, int(token), float(logprob)))
+            self._put(("token", index, int(token), float(logprob)))
 
     def _restart(self):
         self._tokens = []
@@ -206,7 +245,7 @@ class RequestHandle:
         if tr.enabled:
             tr.async_instant("restart", self.trace.trace_id,
                              cat="generation")
-        self._q.put(("restart", None, None))
+        self._put(("restart", None, None))
 
     def _finish(self, reason):
         self.finish_reason = reason
@@ -215,7 +254,7 @@ class RequestHandle:
         if tr.enabled:
             tr.async_end("request", self.trace.trace_id,
                          cat="generation", args={"reason": reason})
-        self._q.put(("done", reason, None))
+        self._put(("done", reason, None))
         self._done.set()
 
     def _fail(self, error):
@@ -225,7 +264,7 @@ class RequestHandle:
         if tr.enabled:
             tr.async_end("request", self.trace.trace_id,
                          cat="generation", args={"error": str(error)})
-        self._q.put(("error", str(error), None))
+        self._put(("error", str(error), None))
         self._done.set()
 
     def _record(self, outcome, **extra):
@@ -261,7 +300,9 @@ class RequestHandle:
         terminal ("done", reason) / ("error", msg) which is yielded
         last.  ``timeout`` bounds the wait for EACH event; exceeding it
         raises TimeoutError (never a bare queue.Empty — the HTTP front
-        turns it into a terminal error record, see handle_generate)."""
+        turns it into a terminal error record, see handle_generate).
+        ``t_event`` is the perf_counter at which the engine put the
+        event just yielded (the front's stream lag counts from it)."""
         while True:
             try:
                 ev = self._q.get(timeout=timeout)
@@ -269,6 +310,7 @@ class RequestHandle:
                 raise TimeoutError(
                     "request %s produced no event within %.1fs"
                     % (self.request.request_id, timeout)) from None
+            self.t_event = self._put_times.popleft()
             yield ev
             if ev[0] in ("done", "error"):
                 return
@@ -449,10 +491,13 @@ class GenerationEngine:
         self._donate = bool(donate)
         donate_kv = tuple(range(1, 1 + self._nc)) if donate else ()
         self._donate_kv = donate_kv
-        self._decode_step_fn = jax.jit(self._make_decode_fn(),
-                                       donate_argnums=donate_kv)
+        self._decode_step_fn = jax.jit(
+            _named(self._make_decode_fn(), "generation_decode"),
+            donate_argnums=donate_kv)
         self._prefill_fns = {
-            b: jax.jit(self._make_prefill_fn(b), donate_argnums=donate_kv)
+            b: jax.jit(_named(self._make_prefill_fn(b),
+                              "generation_prefill_%d" % b),
+                       donate_argnums=donate_kv)
             for b in self.prefill_buckets
         }
         self._chunk_fns = {}           # chunk width -> jitted fn (lazy)
@@ -471,14 +516,18 @@ class GenerationEngine:
                 dcfg.head_dim)
             ddonate = (1, 2) if donate else ()
             self._draft_decode_fn = jax.jit(
-                self._make_draft_decode_fn(), donate_argnums=ddonate)
+                _named(self._make_draft_decode_fn(),
+                       "generation_draft_decode"),
+                donate_argnums=ddonate)
             self._draft_prefill_fns = {
-                b: jax.jit(self._make_draft_prefill_fn(b),
+                b: jax.jit(_named(self._make_draft_prefill_fn(b),
+                                  "generation_draft_prefill_%d" % b),
                            donate_argnums=ddonate)
                 for b in self.prefill_buckets
             }
-            self._verify_fn = jax.jit(self._make_verify_fn(),
-                                      donate_argnums=donate_kv)
+            self._verify_fn = jax.jit(
+                _named(self._make_verify_fn(), "generation_verify"),
+                donate_argnums=donate_kv)
         else:
             self._draft_cache = None
             self._verify_fn = None
@@ -502,11 +551,20 @@ class GenerationEngine:
             "generation_ttft_ms", "Submit -> first token (ms)",
             labelnames=lbl).labels(self._engine)
         self._m_itl = reg.histogram(
-            "generation_itl_ms", "Inter-token latency per decode step (ms)",
+            "generation_itl_ms", "Decode step wall time, one a step (ms)",
             labelnames=lbl).labels(self._engine)
         self._m_prefill_ms = reg.histogram(
             "generation_prefill_ms", "Prefill call wall time (ms)",
             labelnames=lbl).labels(self._engine)
+        self._m_queue_wait = reg.histogram(
+            "generation_queue_wait_ms",
+            "Entry of submit -> the pop that admits the request (ms)",
+            labelnames=lbl).labels(self._engine)
+        self._m_sched_host = reg.histogram(
+            "generation_sched_host_ms",
+            "A step() that decoded, less its time in device calls (ms)",
+            labelnames=lbl).labels(self._engine)
+        self._device_s = 0.0           # this step's time in device calls
         self._m_occupancy = reg.gauge(
             "generation_slot_occupancy", "Occupied-slot fraction",
             labelnames=lbl).labels(self._engine)
@@ -895,6 +953,7 @@ class GenerationEngine:
         self._release_blocks(slot)
         self._free.append(slot)
         st.handle._restart()
+        st.handle.t_queued = time.perf_counter()   # a new wait starts
         self._pending.insert(0, (st.request, st.handle))
         self._m_queue.set(len(self._pending))
         self._m_preempt.inc()
@@ -947,6 +1006,7 @@ class GenerationEngine:
         steps.  ``_handle`` re-attaches an existing handle (the fleet's
         requeue-after-death path: the stream restarts, the handle
         doesn't change hands)."""
+        t_enter = time.perf_counter()  # queue wait counts the lock too
         if not isinstance(request, GenerationRequest):
             request = GenerationRequest(request)
         if len(request.prompt_ids) > self.prefill_buckets[-1]:
@@ -982,6 +1042,7 @@ class GenerationEngine:
             handle = _handle if _handle is not None \
                 else RequestHandle(request)
             handle._sink = self._record_request
+            handle.t_queued = t_enter
             tr = _trace.default_tracer()
             if tr.enabled:
                 tid = handle.trace.trace_id
@@ -1043,53 +1104,81 @@ class GenerationEngine:
         prefill by ONE chunk, refill free slots (prefill), then one
         decode step over the active batch.  Returns True when any work
         happened."""
-        with self._lock:
-            if self._dead:
-                raise EngineDeadError("engine %s is dead" % self._engine)
-            progressed = False
-            for slot in range(self.slots):
-                if self._chunking[slot] is not None:
-                    self._chunk_step(slot)
-                    progressed = True
-            while self._free and self._pending:
-                entry, handle = self._pending.pop(0)
-                slot = self._free.pop(0)
-                self._m_queue.set(len(self._pending))
-                tr = _trace.default_tracer()
-                if tr.enabled:
-                    tr.async_end("queue", handle.trace.trace_id,
-                                 cat="generation")
-                # an entry is either a raw GenerationRequest (prefill
-                # here) or a KVHandoff from a prefill worker (adopt the
-                # finished pages — decode-only workers never prefill)
-                admit = (self._prefill_into
-                         if isinstance(entry, GenerationRequest)
-                         else self._inject_into)
-                if not admit(slot, entry, handle):
-                    # pool dry at admission: requeue and wait for a
-                    # running request to free blocks — unless nothing
-                    # is running, in which case it never will
-                    self._free.insert(0, slot)
-                    if self._active.any() or any(
-                            c is not None for c in self._chunking):
-                        self._pending.insert(0, (entry, handle))
-                        self._m_queue.set(len(self._pending))
-                        if tr.enabled:
-                            tr.async_begin("queue", handle.trace.trace_id,
-                                           cat="generation")
-                    else:
-                        handle._fail(
-                            "kv pool exhausted: request %s needs more "
-                            "blocks than the pool can ever free"
-                            % _entry_request(entry).request_id)
-                    break
+        t_step = time.perf_counter()
+        with _trace.span("generation.step", cat="generation"):
+            with _trace.span("generation.lock_wait", cat="generation"):
+                self._lock.acquire()
+            try:
+                return self._step_locked(t_step)
+            finally:
+                self._lock.release()
+
+    def _step_locked(self, t_step):
+        if self._dead:
+            raise EngineDeadError("engine %s is dead" % self._engine)
+        self._device_s = 0.0
+        decoded = self._decode_steps
+        progressed = False
+        for slot in range(self.slots):
+            if self._chunking[slot] is not None:
+                self._chunk_step(slot)
                 progressed = True
-            if self._active.any():
-                self._decode_once()
-                progressed = True
-            self._m_occupancy.set(
-                float(self._active.sum()) / max(self.slots, 1))
-            return progressed
+        while self._free and self._pending:
+            entry, handle = self._pending[0]
+            with _trace.span(
+                    "generation.admit", cat="generation",
+                    args={"request_id": _entry_request(entry).request_id},
+                    trace_id=handle.trace.trace_id):
+                admitted = self._admit_next()
+            if not admitted:
+                break
+            progressed = True
+        if self._active.any():
+            self._decode_once()
+            progressed = True
+        self._m_occupancy.set(
+            float(self._active.sum()) / max(self.slots, 1))
+        if self._decode_steps != decoded:
+            self._m_sched_host.observe(
+                (time.perf_counter() - t_step - self._device_s) * 1e3)
+        return progressed
+
+    def _admit_next(self):
+        """Move the head of the pending queue into a free slot.  False
+        when the pool was dry: the request is back at the head (or
+        failed, if nothing is running that could ever free blocks)."""
+        t_pop = time.perf_counter()
+        entry, handle = self._pending.pop(0)
+        slot = self._free.pop(0)
+        self._m_queue.set(len(self._pending))
+        tr = _trace.default_tracer()
+        if tr.enabled:
+            tr.async_end("queue", handle.trace.trace_id, cat="generation")
+        # an entry is either a raw GenerationRequest (prefill here) or a
+        # KVHandoff from a prefill worker (adopt the finished pages —
+        # decode-only workers never prefill)
+        admit = (self._prefill_into if isinstance(entry, GenerationRequest)
+                 else self._inject_into)
+        if admit(slot, entry, handle):
+            self._m_queue_wait.observe((t_pop - handle.t_queued) * 1e3)
+            return True
+        # pool dry at admission: requeue (the wait keeps its first
+        # stamp) until a running request frees blocks — unless nothing
+        # is running, in which case it never will
+        self._free.insert(0, slot)
+        if self._active.any() or any(
+                c is not None for c in self._chunking):
+            self._pending.insert(0, (entry, handle))
+            self._m_queue.set(len(self._pending))
+            if tr.enabled:
+                tr.async_begin("queue", handle.trace.trace_id,
+                               cat="generation")
+        else:
+            handle._fail(
+                "kv pool exhausted: request %s needs more "
+                "blocks than the pool can ever free"
+                % _entry_request(entry).request_id)
+        return False
 
     def run_until_idle(self, max_steps=100000):
         """Drive `step()` until no pending and no active work is left."""
@@ -1153,7 +1242,9 @@ class GenerationEngine:
         if tr.enabled:
             tr.async_begin("prefill", handle.trace.trace_id,
                            cat="generation", args={"bucket": bucket})
-        with _trace.span("generation.prefill", cat="generation",
+        # the span holds the fetch of the sampled token too: the host
+        # waits there for the device to finish the prefill
+        with _DeviceCall(self, "generation.prefill",
                          args={"bucket": bucket, "slot": int(slot),
                                "request_id": request.request_id},
                          trace_id=handle.trace.trace_id):
@@ -1163,9 +1254,10 @@ class GenerationEngine:
                     np.int32(n_prompt), table, key,
                     np.float32(sp.temperature), np.int32(sp.top_k),
                     np.float32(sp.top_p))
+            tok0 = int(out[self._nc])
+            lp0 = (float(out[self._nc + 1]) if self.return_logprobs
+                   else None)
         self.cache.update(*out[:self._nc])
-        tok0 = int(out[self._nc])
-        lp0 = float(out[self._nc + 1]) if self.return_logprobs else None
         self._m_prefill_ms.observe((time.perf_counter() - t0) * 1e3)
         if tr.enabled:
             tr.async_end("prefill", handle.trace.trace_id,
@@ -1184,7 +1276,7 @@ class GenerationEngine:
         if tr.enabled:
             tr.async_begin("prefill", handle.trace.trace_id,
                            cat="generation", args={"bucket": bucket})
-        with _trace.span("generation.prefill", cat="generation",
+        with _DeviceCall(self, "generation.prefill",
                          args={"bucket": bucket, "slot": int(slot),
                                "request_id": request.request_id},
                          trace_id=handle.trace.trace_id):
@@ -1194,14 +1286,14 @@ class GenerationEngine:
                     np.int32(n_prompt), np.int32(slot), key,
                     np.float32(sp.temperature), np.int32(sp.top_k),
                     np.float32(sp.top_p))
-        k2, v2, tok0 = out[:3]
-        lp0 = float(out[3]) if self.return_logprobs else None
-        self.cache.update(k2, v2)
+            tok0 = int(out[2])
+            lp0 = float(out[3]) if self.return_logprobs else None
+        self.cache.update(out[0], out[1])
         self._m_prefill_ms.observe((time.perf_counter() - t0) * 1e3)
         if tr.enabled:
             tr.async_end("prefill", handle.trace.trace_id,
                          cat="generation")
-        self._activate(slot, request, handle, int(tok0), lp0, key)
+        self._activate(slot, request, handle, tok0, lp0, key)
 
     def _chunk_step(self, slot):
         """Advance one chunked prefill by one chunk (one executable
@@ -1225,27 +1317,32 @@ class GenerationEngine:
             return
         if width not in self._chunk_fns:
             self._chunk_fns[width] = jax.jit(
-                self._make_chunk_fn(width),
+                _named(self._make_chunk_fn(width),
+                       "generation_prefill_chunk_%d" % width),
                 donate_argnums=self._donate_kv)
         tokens = np.zeros((1, width), np.int32)
         tokens[0, :c_real] = request.prompt_ids[cs.pos:cs.pos + c_real]
         table = self.cache.table_row(slot)[None].astype(np.int32)
         last = cs.pos + c_real >= n_prompt
-        with _trace.span("generation.prefill_chunk", cat="generation",
-                         args={"width": width, "slot": int(slot), "pos": cs.pos,
-                               "request_id": request.request_id}):
+        tok0 = lp0 = None
+        with _DeviceCall(self, "generation.prefill_chunk",
+                         args={"width": width, "slot": int(slot),
+                               "pos": cs.pos,
+                               "request_id": request.request_id},
+                         trace_id=handle.trace.trace_id):
             with _TRACE_LOCK:
                 out = self._chunk_fns[width](
                     self._params, *self.cache.arrays(), tokens,
                     np.int32(cs.pos), table, np.int32(c_real - 1),
                     cs.key, np.float32(sp.temperature),
                     np.int32(sp.top_k), np.float32(sp.top_p))
+            if last:                   # the host waits for the sample
+                tok0 = int(out[self._nc])
+                if self.return_logprobs:
+                    lp0 = float(out[self._nc + 1])
         self.cache.update(*out[:self._nc])
         cs.pos += c_real
         if last:
-            tok0 = int(out[self._nc])
-            lp0 = (float(out[self._nc + 1]) if self.return_logprobs
-                   else None)
             self._chunking[slot] = None
             self._m_prefill_ms.observe(
                 (time.perf_counter() - cs.t0) * 1e3)
@@ -1295,52 +1392,56 @@ class GenerationEngine:
                 self._die("injected death at decode step %d"
                           % self._decode_steps)
                 raise
-        if self.draft_model is not None and self._spec_viable():
-            if self._spec_once():
+        if self.draft_model is not None:
+            with _trace.span("generation.grow", cat="generation"):
+                viable = self._spec_viable()
+            if viable and self._spec_once():
                 return
         # plain step: make room for ONE new row per active slot
         if self.paged:
-            for slot in list(np.nonzero(self._active)[0]):
-                if not self._active[slot]:
-                    continue           # preempted as an earlier victim
-                if not self._grow_or_preempt(
-                        slot, int(self._lengths[slot]) + 1):
-                    self._fail_slot(
-                        slot, "kv pool exhausted: no preemptable slot "
-                        "left to make room")
-            if not self._active.any():
-                return
+            with _trace.span("generation.grow", cat="generation"):
+                for slot in list(np.nonzero(self._active)[0]):
+                    if not self._active[slot]:
+                        continue       # preempted as an earlier victim
+                    if not self._grow_or_preempt(
+                            slot, int(self._lengths[slot]) + 1):
+                        self._fail_slot(
+                            slot, "kv pool exhausted: no preemptable "
+                            "slot left to make room")
+                if not self._active.any():
+                    return
+                operands = (*self.cache.arrays(), self._lengths,
+                            self._last_tokens, self._keys, self._steps,
+                            self._temp, self._top_k, self._top_p,
+                            self._decode_tables())
+        else:
+            operands = (self.cache.k, self.cache.v, self._lengths,
+                        self._last_tokens, self._keys, self._steps,
+                        self._temp, self._top_k, self._top_p)
         t0 = time.perf_counter()
-        with _TRACE_LOCK:
-            if self.paged:
-                out = self._decode_step_fn(
-                    self._params, *self.cache.arrays(), self._lengths,
-                    self._last_tokens, self._keys, self._steps,
-                    self._temp, self._top_k, self._top_p,
-                    self._decode_tables())
-            else:
-                out = self._decode_step_fn(
-                    self._params, self.cache.k, self.cache.v,
-                    self._lengths, self._last_tokens, self._keys,
-                    self._steps, self._temp, self._top_k, self._top_p)
+        with _DeviceCall(self, "generation.decode_dispatch"):
+            with _TRACE_LOCK:
+                out = self._decode_step_fn(self._params, *operands)
+        # the host waits here while the device works
+        with _DeviceCall(self, "generation.decode_fetch"):
+            nxt = np.asarray(out[self._nc])
+            lps = (np.asarray(out[self._nc + 1]) if self.return_logprobs
+                   else None)
         self.cache.update(*out[:self._nc])
-        nxt = np.asarray(out[self._nc])
-        lps = (np.asarray(out[self._nc + 1]) if self.return_logprobs
-               else None)
         self._decode_steps += 1
-        dt_ms = (time.perf_counter() - t0) * 1e3
+        self._m_itl.observe((time.perf_counter() - t0) * 1e3)
         # the cache write in the step put every ACTIVE slot's new token
         # at lengths; advance those counters (inactive rows computed
         # garbage nobody reads — their writes went to the garbage block)
-        for slot in np.nonzero(self._active)[0]:
-            self._lengths[slot] += 1
-            self._steps[slot] += 1
-            st = self._slot_state[slot]
-            st_tok = int(nxt[slot])
-            self._last_tokens[slot] = st_tok
-            self._emit(slot, st, st_tok,
-                       float(lps[slot]) if lps is not None else None)
-            self._m_itl.observe(dt_ms)
+        with _trace.span("generation.emit", cat="generation"):
+            for slot in np.nonzero(self._active)[0]:
+                self._lengths[slot] += 1
+                self._steps[slot] += 1
+                st = self._slot_state[slot]
+                st_tok = int(nxt[slot])
+                self._last_tokens[slot] = st_tok
+                self._emit(slot, st, st_tok,
+                           float(lps[slot]) if lps is not None else None)
 
     # -- speculative decoding ----------------------------------------------
     def _spec_viable(self):
@@ -1373,47 +1474,52 @@ class GenerationEngine:
         cur = self._last_tokens.copy()
         kd, vd = self._draft_cache.arrays()
         t0 = time.perf_counter()
-        with _TRACE_LOCK:
-            for i in range(k):
-                kd, vd, nxt = self._draft_decode_fn(
-                    self._draft_params, kd, vd,
-                    self._lengths + np.int32(i), cur)
+        for i in range(k):
+            with _DeviceCall(self, "generation.decode_dispatch"):
+                with _TRACE_LOCK:
+                    kd, vd, nxt = self._draft_decode_fn(
+                        self._draft_params, kd, vd,
+                        self._lengths + np.int32(i), cur)
+            with _DeviceCall(self, "generation.decode_fetch"):
                 cur = np.asarray(nxt)
-                drafts[:, i] = cur
+            drafts[:, i] = cur
         self._draft_cache.update(kd, vd)
         tok_in = np.concatenate(
             [self._last_tokens[:, None], drafts], axis=1).astype(np.int32)
-        with _TRACE_LOCK:
-            out = self._verify_fn(
-                self._params, *self.cache.arrays(), self._lengths,
-                tok_in, self._keys, self._steps, self._temp,
-                self._top_k, self._top_p, self._decode_tables())
+        tables = self._decode_tables()
+        with _DeviceCall(self, "generation.decode_dispatch"):
+            with _TRACE_LOCK:
+                out = self._verify_fn(
+                    self._params, *self.cache.arrays(), self._lengths,
+                    tok_in, self._keys, self._steps, self._temp,
+                    self._top_k, self._top_p, tables)
+        with _DeviceCall(self, "generation.decode_fetch"):
+            toks = np.asarray(out[self._nc])           # [N, S]
+            lps = (np.asarray(out[self._nc + 1]) if self.return_logprobs
+                   else None)
         self.cache.update(*out[:self._nc])
-        toks = np.asarray(out[self._nc])               # [N, S]
-        lps = (np.asarray(out[self._nc + 1]) if self.return_logprobs
-               else None)
         self._decode_steps += 1
-        dt_ms = (time.perf_counter() - t0) * 1e3
-        for slot in np.nonzero(self._active)[0]:
-            greedy = self._temp[slot] <= 0.0
-            j = 0
-            if greedy:
-                while j < k and drafts[slot, j] == toks[slot, j]:
-                    j += 1
-                self._m_spec_proposed.inc(k)
-                self._m_spec_accepted.inc(j)
-            st = self._slot_state[slot]
-            for i in range(j + 1):
-                self._lengths[slot] += 1
-                self._steps[slot] += 1
-                t = int(toks[slot, i])
-                self._last_tokens[slot] = t
-                self._emit(slot, st, t,
-                           float(lps[slot, i]) if lps is not None
-                           else None)
-                if not self._active[slot]:
-                    break              # stop token / limits mid-accept
-            self._m_itl.observe(dt_ms)
+        self._m_itl.observe((time.perf_counter() - t0) * 1e3)
+        with _trace.span("generation.emit", cat="generation"):
+            for slot in np.nonzero(self._active)[0]:
+                greedy = self._temp[slot] <= 0.0
+                j = 0
+                if greedy:
+                    while j < k and drafts[slot, j] == toks[slot, j]:
+                        j += 1
+                    self._m_spec_proposed.inc(k)
+                    self._m_spec_accepted.inc(j)
+                st = self._slot_state[slot]
+                for i in range(j + 1):
+                    self._lengths[slot] += 1
+                    self._steps[slot] += 1
+                    t = int(toks[slot, i])
+                    self._last_tokens[slot] = t
+                    self._emit(slot, st, t,
+                               float(lps[slot, i]) if lps is not None
+                               else None)
+                    if not self._active[slot]:
+                        break          # stop token / limits mid-accept
         return True
 
     # -- token delivery ----------------------------------------------------
@@ -1515,7 +1621,10 @@ class GenerationEngine:
                 busy = (bool(self._pending) or bool(self._active.any())
                         or any(c is not None for c in self._chunking))
                 if not busy:
-                    self._work.wait(0.05)
+                    # no work: an idle device here is the traffic's doing
+                    with _trace.span("generation.idle_wait",
+                                     cat="generation"):
+                        self._work.wait(0.05)
                     continue
             try:
                 self.step()
@@ -1630,6 +1739,7 @@ class GenerationEngine:
         in the same pending queue when slots are busy and shed at
         ``max_queue``.  ``_handle`` re-attaches an existing handle on
         the fleet requeue path."""
+        t_enter = time.perf_counter()
         if not self.paged:
             raise ValueError("inject_prefilled requires paged=True")
         if handoff.block_size != self.block_size:
@@ -1666,6 +1776,7 @@ class GenerationEngine:
                     trace=_trace.TraceContext.from_wire(
                         getattr(handoff, "trace", None)))
             handle._sink = self._record_request
+            handle.t_queued = t_enter
             tr = _trace.default_tracer()
             if tr.enabled:
                 tid = handle.trace.trace_id

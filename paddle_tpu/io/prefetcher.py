@@ -28,6 +28,7 @@ import time
 
 import numpy as np
 
+from ..observability import trace as _trace
 from .stats import PipelineStats
 
 __all__ = ["DevicePrefetcher"]
@@ -219,7 +220,8 @@ class DevicePrefetcher:
                         "by a newer iteration (one live iterator at a "
                         "time)")
                 t0 = time.perf_counter()
-                item = q.get()
+                with _trace.span("io.next_batch", cat="io"):
+                    item = q.get()
                 self.stats.step_wait_ms.observe(
                     (time.perf_counter() - t0) * 1e3)
                 if item is _SENTINEL:

@@ -31,6 +31,15 @@ question; this module restores the timeline one, TPU-first:
 Enable via `enable_tracing()` or `PADDLE_TPU_TRACE=1`; the
 `FlightRecorder` (flight_recorder.py) arms a bounded always-on ring and
 dumps it on crash/SIGTERM/first failed step.
+
+**Two sinks, one call site.**  `span()` also opens a
+`jax.profiler.TraceAnnotation` of the same name while a `jax.profiler`
+session runs, whether or not the ring is on: the span then lies in the
+session's ``.xplane.pb`` on the clock of the device's ``XLA Ops`` line,
+with its args, ``trace_id`` and ``request_id`` as the event's stats.
+Nothing has to tell the program that a session is on: the annotation's
+own activity check (`TraceAnnotation.is_enabled`) decides, and with the
+ring off and no session every `span()` returns the shared no-op.
 """
 
 from __future__ import annotations
@@ -41,9 +50,12 @@ import json
 import os
 import threading
 
-from . import locks
 import time
 from collections import deque
+
+from jax.profiler import TraceAnnotation as _Annotation
+
+from . import locks
 
 __all__ = [
     "Tracer",
@@ -96,16 +108,24 @@ _NULL_CTX = _NullCtx()
 
 
 class _SpanCtx:
-    __slots__ = ("_tr", "_name", "_cat", "_args", "_trace_id", "_t0",
-                 "_abandoned")
+    """One open span, feeding the ring (``ring``), the profiler session
+    (``profiled``: a `TraceAnnotation` held open for the span's life), or
+    both — whichever was live when the span was opened."""
 
-    def __init__(self, tracer, name, cat, args, trace_id):
+    __slots__ = ("_tr", "_name", "_cat", "_args", "_trace_id", "_t0",
+                 "_abandoned", "_ring", "_ann")
+
+    def __init__(self, tracer, name, cat, args, trace_id, ring, profiled):
         self._tr = tracer
         self._name = name
         self._cat = cat
         self._args = args
         self._trace_id = trace_id
         self._abandoned = False
+        self._ring = ring
+        # True: to be opened in the session; then the open annotation;
+        # None: not in a session, or closed
+        self._ann = profiled or None
 
     def __enter__(self):
         stack = getattr(_tls, "spans", None)
@@ -116,40 +136,60 @@ class _SpanCtx:
             self._trace_id = stack[-1]._trace_id if stack \
                 else getattr(_tls, "trace_id", None)
         stack.append(self)
+        if self._ann:
+            kw = dict(self._args) if self._args else {}
+            if self._trace_id is not None:
+                kw.setdefault("trace_id", self._trace_id)
+            self._ann = _Annotation(self._name, **kw)
+            self._ann.__enter__()
         self._t0 = _now()
         return self
 
     def add_args(self, **kw):
         """Attach metadata discovered while the span is open (e.g. the
-        compile/compute split known only at close)."""
+        compile/compute split known only at close).  A ``trace_id``
+        given here becomes the span's own — the id of a request that was
+        only known once the span was open — and spans nested on this
+        thread from then on inherit it."""
         if self._args is None:
             self._args = {}
         self._args.update(kw)
+        if "trace_id" in kw:
+            self._trace_id = kw["trace_id"]
+        if self._ann:
+            self._ann.set_metadata(**kw)
         return self
+
+    def _leave(self, exc_type=None, exc=None, tb=None):
+        stack = getattr(_tls, "spans", None)
+        if stack and stack[-1] is self:
+            stack.pop()
+        if self._ann:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
 
     def abandon(self):
         """Close WITHOUT emitting — the operation this span was timing
         was cancelled (e.g. the step whose data fetch hit
         StopIteration), so no event should pretend it happened.  Also
-        honored when the span is left via its with-block."""
-        self._abandoned = True
-        stack = getattr(_tls, "spans", None)
-        if stack and stack[-1] is self:
-            stack.pop()
+        honored when the span is left via its with-block.  (A profiler
+        session keeps what it saw: its annotation ends here.)"""
+        if not self._abandoned:
+            self._abandoned = True
+            self._leave()
 
     def __exit__(self, exc_type, exc, tb):
         if self._abandoned:
             return False
         t1 = _now()
-        stack = getattr(_tls, "spans", None)
-        if stack and stack[-1] is self:
-            stack.pop()
-        args = self._args
-        if exc_type is not None:
-            args = dict(args or {})
-            args["error"] = exc_type.__name__
-        self._tr.complete(self._name, self._t0, t1, cat=self._cat,
-                          args=args, trace_id=self._trace_id)
+        self._leave(exc_type, exc, tb)
+        if self._ring:
+            args = self._args
+            if exc_type is not None:
+                args = dict(args or {})
+                args["error"] = exc_type.__name__
+            self._tr.complete(self._name, self._t0, t1, cat=self._cat,
+                              args=args, trace_id=self._trace_id)
         return False
 
 
@@ -224,16 +264,21 @@ class Tracer:
         return int(t * 1e6)
 
     def span(self, name, cat="", args=None, trace_id=None):
-        """Context manager timing a region on this thread (ph:"X").
-        No-op (shared null object) when disabled."""
-        if not self._enabled:
+        """Context manager timing a region on this thread: a ph:"X"
+        event in the ring when tracing is enabled, a `TraceAnnotation`
+        in the running `jax.profiler` session when there is one.  The
+        shared null object when neither is."""
+        ring, profiled = self._enabled, _Annotation.is_enabled()
+        if not (ring or profiled):
             return _NULL_CTX
-        return _SpanCtx(self, name, cat, args, trace_id)
+        return _SpanCtx(self, name, cat, args, trace_id, ring, profiled)
 
     def complete(self, name, t0, t1, cat="", args=None, trace_id=None,
                  tid=None):
         """Explicit-interval span: t0/t1 are `Tracer` clock seconds
-        (time.perf_counter) captured by the caller."""
+        (time.perf_counter) captured by the caller.  Feeds the ring
+        only: an interval that is already over cannot be opened as an
+        annotation on the profiler's clock."""
         if not self._enabled:
             return
         if trace_id is not None:

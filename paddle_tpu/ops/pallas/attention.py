@@ -310,28 +310,30 @@ def _fwd(q, k, v, bias, qseg, kseg, n_head, scale, causal, interpret,
         has_bias=has_bias, has_seg=has_seg, coff=coff, emit_lse=emit_lse,
     )
     lse_rows = bq if emit_lse else 8
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(bh, nq, nk),
-        in_specs=in_specs,
-        out_specs=[
-            _row_spec(bq, d, layout, h, 1),
-            pl.BlockSpec((1, lse_rows, 128),
-                         (lambda b, i, j: (b, i, 0)) if emit_lse
-                         else (lambda b, i, j: (b, 0, 0))),
-        ],
-        out_shape=[
-            out_sds,
-            jax.ShapeDtypeStruct(
-                (bh, sq if emit_lse else 8, 128), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, 128), jnp.float32),  # running row max
-            pltpu.VMEM((bq, 128), jnp.float32),  # running row sum
-            pltpu.VMEM((bq, d), _acc_dtype()),  # output accumulator
-        ],
-        interpret=interpret,
-    )(*args)
+    with jax.named_scope("flash_attention"):
+        out, lse = pl.pallas_call(
+            kernel,
+            grid=(bh, nq, nk),
+            in_specs=in_specs,
+            out_specs=[
+                _row_spec(bq, d, layout, h, 1),
+                pl.BlockSpec((1, lse_rows, 128),
+                             (lambda b, i, j: (b, i, 0)) if emit_lse
+                             else (lambda b, i, j: (b, 0, 0))),
+            ],
+            out_shape=[
+                out_sds,
+                jax.ShapeDtypeStruct(
+                    (bh, sq if emit_lse else 8, 128), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bq, 128), jnp.float32),  # running row max
+                pltpu.VMEM((bq, 128), jnp.float32),  # running row sum
+                pltpu.VMEM((bq, d), _acc_dtype()),  # output accumulator
+            ],
+            interpret=interpret,
+            name="flash_attention_fwd",
+        )(*args)
     return out, lse
 
 
@@ -577,17 +579,19 @@ def _bwd_fused(q, k, v, bias, qseg, kseg, out, g, h, scale, causal,
     if has_bias:
         out_specs.append(pl.BlockSpec((1, 1, bk), lambda g_: (g_, 0, 0)))
         out_shape.append(jax.ShapeDtypeStruct(bias.shape, bias.dtype))
-    res = pl.pallas_call(
-        functools.partial(
-            _bwd_fused_kernel, scale=scale, causal=causal, bq=bq, bk=bk,
-            has_bias=has_bias, has_seg=has_seg, coff=coff,
-        ),
-        grid=(bh,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(*args)
+    with jax.named_scope("flash_attention"):
+        res = pl.pallas_call(
+            functools.partial(
+                _bwd_fused_kernel, scale=scale, causal=causal, bq=bq,
+                bk=bk, has_bias=has_bias, has_seg=has_seg, coff=coff,
+            ),
+            grid=(bh,),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            out_shape=out_shape,
+            interpret=interpret,
+            name="flash_attention_bwd_fused",
+        )(*args)
     if has_bias:
         dq, dk, dv, dbias = res
     else:
@@ -814,19 +818,21 @@ def _flash_core_bwd(n_head, scale, causal, interpret, coff, layout,
         _lse_spec("ij"),  # lse rows (token buffer on the fast path)
     ]
     args += [out, g, lse2d]
-    dq = pl.pallas_call(
-        functools.partial(
-            _bwd_dq_kernel, scale=scale, causal=causal, bq=bq, bk=bk, nk=nk,
-            has_bias=has_bias, has_seg=has_seg, coff=coff,
-            recompute_lse=fast,
-        ),
-        grid=(bh, nq, nk),
-        in_specs=dq_specs,
-        out_specs=_row_spec(bq, d, layout, h, 1),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), _acc_dtype())],
-        interpret=interpret,
-    )(*args)
+    with jax.named_scope("flash_attention"):
+        dq = pl.pallas_call(
+            functools.partial(
+                _bwd_dq_kernel, scale=scale, causal=causal, bq=bq, bk=bk,
+                nk=nk, has_bias=has_bias, has_seg=has_seg, coff=coff,
+                recompute_lse=fast,
+            ),
+            grid=(bh, nq, nk),
+            in_specs=dq_specs,
+            out_specs=_row_spec(bq, d, layout, h, 1),
+            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+            scratch_shapes=[pltpu.VMEM((bq, d), _acc_dtype())],
+            interpret=interpret,
+            name="flash_attention_bwd_dq",
+        )(*args)
 
     # column-parallel pass: lse/o/do blocks follow the INNER grid dim (i)
     kv_specs = [
@@ -864,19 +870,21 @@ def _flash_core_bwd(n_head, scale, causal, interpret, coff, layout,
         out_specs.append(pl.BlockSpec((1, 1, bk), lambda b, j, i: (b, 0, j)))
         out_shape.append(jax.ShapeDtypeStruct((bh, 1, sk), bias.dtype))
         scratch.append(pltpu.VMEM((8, bk), jnp.float32))
-    res = pl.pallas_call(
-        functools.partial(
-            _bwd_dkv_kernel, scale=scale, causal=causal, bq=bq, bk=bk, nq=nq,
-            has_bias=has_bias, has_seg=has_seg, coff=coff,
-            recompute_lse=fast,
-        ),
-        grid=(bh, nk, nq),
-        in_specs=kv_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-        interpret=interpret,
-    )(*args)
+    with jax.named_scope("flash_attention"):
+        res = pl.pallas_call(
+            functools.partial(
+                _bwd_dkv_kernel, scale=scale, causal=causal, bq=bq, bk=bk,
+                nq=nq, has_bias=has_bias, has_seg=has_seg, coff=coff,
+                recompute_lse=fast,
+            ),
+            grid=(bh, nk, nq),
+            in_specs=kv_specs,
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=scratch,
+            interpret=interpret,
+            name="flash_attention_bwd_dkv",
+        )(*args)
     if has_bias:
         dk, dv, dbias = res
     else:

@@ -340,30 +340,68 @@ def parse_generation_request(msg):
         request_id=msg.get("request_id"))
 
 
-def handle_generate(handler, fleet, msg):
-    """Answer one /generate on an open BaseHTTPRequestHandler.  With
-    ``"stream": true`` the response is chunked ndjson — one record per
-    event as it happens; otherwise one JSON object after completion."""
+def _front_histograms(fleet):
+    """The front's two always-on histograms, in the fleet's registry."""
+    reg = getattr(fleet, "metrics_registry", None) or default_registry()
+    return (reg.histogram(
+                "generation_front_admit_ms",
+                "Parsed body -> fleet.submit returned, the wait for the "
+                "engine's lock included (ms)"),
+            reg.histogram(
+                "generation_stream_lag_ms",
+                "Engine put a token on the request's queue -> its chunk "
+                "written to the socket (ms)"))
+
+
+def _admit(handler, fleet, msg):
+    """Parse the body and submit it.  Returns ``(request, handle,
+    stream, timeout)``, or None after answering the refusal itself."""
     try:
         request = parse_generation_request(msg)
         stream = bool(msg.get("stream", True))
         timeout = float(msg.get("timeout", 60.0))
     except Exception as e:
         handler._send(400, {"error": "%s: %s" % (type(e).__name__, e)})
-        return
+        return None
     try:
         h = fleet.submit(request)
     except ShedError as e:
         handler._send(503, {"error": str(e), "shed": True,
                             "reason": e.reason},
                       headers=(("Retry-After", str(e.retry_after_s)),))
-        return
+        return None
     except ValueError as e:
         handler._send(400, {"error": "%s: %s" % (type(e).__name__, e)})
-        return
+        return None
     except Exception as e:
         handler._send(500, {"error": "%s: %s" % (type(e).__name__, e)})
-        return
+        return None
+    return request, h, stream, timeout
+
+
+def handle_generate(handler, fleet, msg):
+    """Answer one /generate on an open BaseHTTPRequestHandler.  With
+    ``"stream": true`` the response is chunked ndjson — one record per
+    event as it happens; otherwise one JSON object after completion."""
+    admit_ms, stream_lag_ms = _front_histograms(fleet)
+    with _trace.span("http.generate", cat="http") as whole:
+        t0 = time.perf_counter()
+        with _trace.span("http.admit", cat="http") as admit:
+            admitted = _admit(handler, fleet, msg)
+            if admitted is not None:
+                # the request's id is known only now: both spans take
+                # it, so the handler's and the scheduler's spans of one
+                # request share it across their threads
+                ids = {"trace_id": admitted[1].trace.trace_id,
+                       "request_id": admitted[0].request_id}
+                admit.add_args(**ids)
+                whole.add_args(**ids)
+        admit_ms.observe((time.perf_counter() - t0) * 1e3)
+        if admitted is not None:
+            _respond(handler, *admitted, stream_lag_ms)
+
+
+def _respond(handler, request, h, stream, timeout, stream_lag_ms):
     if not stream:
         try:
             tokens = h.result(timeout=timeout)
@@ -394,6 +432,8 @@ def handle_generate(handler, fleet, msg):
                     if len(ev) > 3:    # logprob engines append a field;
                         rec["logprob"] = ev[3]   # off => byte-identical
                     chunk(rec)
+                    stream_lag_ms.observe(
+                        (time.perf_counter() - h.t_event) * 1e3)
                 elif kind == "restart":
                     chunk({"event": "restart"})
                 elif kind == "done":

@@ -538,67 +538,95 @@ def test_flight_recorder_one_dump_per_unwind(tmp_path):
 # ---------------------------------------------------------------------------
 
 
-def test_disabled_tracing_is_shared_noop_and_within_budget():
-    """Disabled tracing must cost ~nothing on the step path: span()
-    returns one shared null object (no allocation), and the per-step
-    instrumentation cost — ~4 span/complete calls — stays far inside
-    the repo's <2% telemetry budget against a real (small) train step."""
+def test_disabled_tracing_is_shared_noop_and_within_budget(monkeypatch):
+    """With the ring off and no profiler session, instrumentation costs
+    the hot paths calls, not objects: span() returns one shared null
+    object and nothing it allocates outlives it, a steady decode step
+    opens six spans (five on a dense cache) and a train step two.  The
+    budget is in counts (a timing ratio fails under load); the
+    nanoseconds per call are measured on the chip machine (PERF.md)."""
+    import gc
+
+    import jax
+
+    from paddle_tpu import distributed as dist
+    from paddle_tpu import generation as gen
+    from paddle_tpu import models
+    from paddle_tpu.fluid import dygraph
+    from paddle_tpu.fluid.optimizer import SGDOptimizer
+
     T.disable_tracing()
     tr = T.default_tracer()
     assert tr.span("a") is tr.span("b")          # shared no-op object
 
-    # a real step to budget against: the telemetry-bench fc program
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup):
-        x = layers.data("x", shape=[-1, 64], append_batch_size=False)
-        y = layers.data("y", shape=[-1, 1], append_batch_size=False)
-        h = layers.fc(layers.fc(x, 128, act="relu"), 128, act="relu")
-        loss = layers.reduce_mean(layers.square(layers.fc(h, 1) - y))
-        fluid.optimizer.SGDOptimizer(0.01).minimize(loss)
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup)
-    rng = np.random.RandomState(0)
-    feed = {"x": rng.randn(64, 64).astype(np.float32),
-            "y": rng.randn(64, 1).astype(np.float32)}
-    for _ in range(3):                            # compile + warm
-        exe.run(main, feed=feed, fetch_list=[loss])
-    t0 = time.perf_counter()
-    n_steps = 30
-    for _ in range(n_steps):
-        exe.run(main, feed=feed, fetch_list=[loss])
-    step_s = (time.perf_counter() - t0) / n_steps
-
-    def per_call(fn, n=20000):
-        t0 = time.perf_counter()
+    def open_spans(n):
         for _ in range(n):
-            fn()
-        return (time.perf_counter() - t0) / n
+            with tr.span("s", cat="train", args=None) as sp:
+                sp.add_args(k=1)
 
-    def disabled_span():
-        with tr.span("s", cat="train", args=None):
-            pass
-
-    cost_disabled = per_call(disabled_span)
-    T.enable_tracing()
+    open_spans(100)
+    gc.collect()
+    gc.disable()
     try:
-        tr = T.default_tracer()
-
-        def enabled_span():
-            with tr.span("s", cat="train", args={"step": 1}):
-                pass
-
-        cost_enabled = per_call(enabled_span)
+        before = sys.getallocatedblocks()
+        open_spans(10000)
+        kept = sys.getallocatedblocks() - before
     finally:
-        T.disable_tracing()
-        T.default_tracer().clear()
-    spans_per_step = 4     # step + data_wait + executor.run + slack
-    budget = 0.02 * step_s
-    assert spans_per_step * cost_disabled < 0.1 * budget, (
-        "disabled tracing costs %.1f%% of a %.2fms step"
-        % (100 * spans_per_step * cost_disabled / step_s, step_s * 1e3))
-    assert spans_per_step * cost_enabled < budget, (
-        "enabled tracing costs %.1f%% of a %.2fms step"
-        % (100 * spans_per_step * cost_enabled / step_s, step_s * 1e3))
+        gc.enable()
+    assert kept <= 16, "%d blocks outlive 10000 disabled spans" % kept
+
+    opened = []
+    real = tr.span
+
+    def counting(name, **kw):
+        ctx = real(name, **kw)
+        opened.append((name, ctx))
+        return ctx
+
+    monkeypatch.setattr(tr, "span", counting)
+    with dygraph.guard():
+        np.random.seed(0)
+        lm = models.TransformerLM(models.TransformerLMConfig.tiny())
+        per_step = {}
+        for paged in (True, False):
+            engine = gen.GenerationEngine(
+                lm, slots=2, max_len=64, prefill_buckets=[8],
+                paged=paged, metrics_registry=MetricsRegistry())
+            for _ in range(2):
+                engine.submit(gen.GenerationRequest([1, 2, 3],
+                                                    max_new_tokens=8))
+            engine.step()                # admits both, then decodes
+            del opened[:]
+            engine.step()                # a steady decode step
+            per_step[paged] = [name for name, _ in opened]
+        assert per_step[True] == [
+            "generation.step", "generation.lock_wait", "generation.grow",
+            "generation.decode_dispatch", "generation.decode_fetch",
+            "generation.emit"]
+        assert per_step[False] == [n for n in per_step[True]
+                                   if n != "generation.grow"]
+
+        class Net(dygraph.Layer):
+            def __init__(self):
+                super().__init__()
+                self.fc = dygraph.Linear(4, 1)
+
+            def forward(self, x):
+                return self.fc(x)
+
+        step = dist.ShardedTrainStep(
+            Net(), SGDOptimizer(0.1),
+            lambda m, b: layers.reduce_mean(layers.square(m(b["x"]))),
+            dist.auto_mesh(1, devices=jax.devices()[:1]), zero_stage=0)
+        state = step.init()
+        batch = {"x": np.ones((2, 4), np.float32)}
+        state, _ = step(state, batch)    # compiles
+        del opened[:]
+        state, loss = step(state, batch)
+        float(loss)
+    assert [name for name, _ in opened] == ["train.step_dispatch",
+                                            "train.batch_put"]
+    assert all(ctx is T._NULL_CTX for _, ctx in opened)
 
 
 # ---------------------------------------------------------------------------
