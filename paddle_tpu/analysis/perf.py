@@ -1013,7 +1013,7 @@ def rank_pass_pipelines(program, candidates, chip=None,
 
 class DecodeStepCost:
     """The decode step's roofline: one token per slot against a
-    ``[L, slots, cache_len, H, D]`` KV cache.
+    KV cache of L layers x ``[slots, cache_len, H*D]`` for K and for V.
 
     At batch 1-per-slot the MXU sees [slots, hidden] x [hidden, ...]
     matmuls — every weight byte and every cache byte is read for O(1)

@@ -2,8 +2,9 @@
 engine (SURVEY §1 row 9's inference tier, grown from one-shot forward
 passes to token streams).
 
-* `PagedKVCache` — the KV store is a block pool
-  ``[L, num_blocks, block_size, H, D]`` plus a host per-slot block
+* `PagedKVCache` — the KV store is a block pool, one
+  ``[num_blocks, block_size, H*D]`` array per layer for K and for V
+  that every step writes in place, plus a host per-slot block
   table (`BlockPool` refcounted allocation, PagedAttention layout);
   the pool is provisioned to the MEAN sequence length instead of
   ``slots * max_len``, and the decode step gathers K/V through the

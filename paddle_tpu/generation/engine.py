@@ -12,13 +12,18 @@ The execution model, and why the executable set stays enumerable:
   logits.  The first token is emitted immediately — the TTFT path.
 * **decode** — every scheduler iteration runs ONE jitted step over ALL
   slots: one token per slot in, attention through the block table
-  (`ops.pallas.paged_attention`), one sampled token per slot out.
+  (`ops.pallas.paged_attention.cached_attention`), one sampled token
+  per slot out.
   Pool arrays are donated, the table is passed as DATA, shapes never
   change — the step compiles once per engine config and
   `_decode_cache_size()` plus the PR-4 compile accumulator pin it.
-* **paged KV** (the PR-17 rebuild) — the store is a block pool
-  ``[L, num_blocks, block_size, H, D]`` plus a host per-slot block
-  table (`kv_cache.PagedKVCache`).  Slots allocate blocks as they
+* **paged KV** (the PR-17 rebuild) — the store is a block pool, one
+  ``[num_blocks, block_size, H*D]`` array per layer for K and for V
+  (`kv_cache` says why that shape), plus a host per-slot block table
+  (`kv_cache.PagedKVCache`).  Every step function takes the
+  ``2 * num_layers`` arrays as donated operands and writes its new
+  rows into them in place (`ops.pallas.paged_attention.kv_write`):
+  no step slices, stacks or copies a pool.  Slots allocate blocks as they
   grow instead of reserving ``max_len`` rows up front, so the pool is
   provisioned to the MEAN sequence length; when it runs dry the engine
   evicts cached prefixes, then preempts the least-progressed slot
@@ -77,7 +82,14 @@ from ..fluid import framework
 from ..observability import locks as _locks
 from ..observability import trace as _trace
 from ..observability.metrics import default_registry, unique_instance_label
-from .kv_cache import KVCache, PagedKVCache, PoolExhausted, PrefixCache
+from .kv_cache import (
+    KVCache,
+    PagedKVCache,
+    PoolExhausted,
+    PrefixCache,
+    flatten_layers,
+    group_layers,
+)
 from .sampling import (
     SamplingParams,
     make_base_key,
@@ -514,7 +526,8 @@ class GenerationEngine:
             self._draft_cache = KVCache(
                 dcfg.num_layers, n, self.max_len, dcfg.num_heads,
                 dcfg.head_dim)
-            ddonate = (1, 2) if donate else ()
+            ddonate = (tuple(range(1, 1 + 2 * dcfg.num_layers))
+                       if donate else ())
             self._draft_decode_fn = jax.jit(
                 _named(self._make_draft_decode_fn(),
                        "generation_draft_decode"),
@@ -635,46 +648,64 @@ class GenerationEngine:
         finally:
             framework._dygraph_tracer = old
 
-    def _make_decode_fn(self):
-        """ONE decode step over all slots (see module docstring)."""
+    def _run_cached(self, model, params, ids, pos, arrays,
+                    cache_positions, tables=None):
+        """``model``'s cached forward (decode / chunk / verify): ids and
+        pos ``[B, S]``, the S tokens of row b written at
+        ``cache_positions[b]..+S-1`` of each layer's own cache arrays.
+        Returns ``(logits [B, S, V], updated arrays)``."""
         from ..fluid.dygraph import to_variable
 
+        def run(m):
+            logits, layers = m(
+                to_variable(ids), to_variable(pos),
+                caches=group_layers(arrays, len(m.blocks)),
+                cache_positions=cache_positions, block_tables=tables,
+                block_size=self.block_size)
+            return logits.data, flatten_layers(layers)
+
+        return self._apply_model(params, run, model=model)
+
+    def _run_prefill(self, model, params, tokens, bucket):
+        """``model``'s full causal forward on the flash path over
+        ``tokens [1, bucket]``: ``(logits, [(k, v), ...])``, the
+        per-layer ``[1, bucket, H, D]`` rows for the cache."""
+        from ..fluid.dygraph import to_variable
+
+        def run(m):
+            pos = jnp.arange(bucket, dtype=jnp.int32)[None]
+            logits, kvs = m(to_variable(tokens), to_variable(pos),
+                            use_cache=True)
+            return logits.data, kvs
+
+        return self._apply_model(params, run, model=model)
+
+    # the three hooks a tensor-parallel engine overrides: the served
+    # model's two forwards, and what wraps a step function's body
+    def _forward_cached(self, params, ids, pos, arrays, cache_positions,
+                        tables=None):
+        return self._run_cached(self.model, params, ids, pos, arrays,
+                                cache_positions, tables)
+
+    def _forward_prefill(self, params, tokens, bucket):
+        return self._run_prefill(self.model, params, tokens, bucket)
+
+    def _wrap_step(self, body):
+        return body
+
+    def _make_decode_fn(self):
+        """ONE decode step over all slots (see module docstring)."""
         nc = self._nc
-        if not self.paged:
-            def decode(params, k_stack, v_stack, lengths, tokens, keys,
-                       steps, temp, top_k, top_p):
-                def run(model):
-                    logits, caches = model(
-                        to_variable(tokens[:, None].astype(jnp.int32)),
-                        to_variable(lengths[:, None].astype(jnp.int32)),
-                        caches=(k_stack, v_stack), cache_positions=lengths)
-                    return logits.data, caches
-
-                logits, (k2, v2) = self._apply_model(params, run)
-                nxt = sample_tokens(logits[:, 0], keys, steps, temp,
-                                    top_k, top_p)
-                if self.return_logprobs:
-                    return k2, v2, nxt, token_logprobs(logits[:, 0], nxt)
-                return k2, v2, nxt
-
-            return decode
-
-        bs = self.block_size
 
         def decode(params, *args):
             arrays = args[:nc]
+            # a paged engine's last operand is the block tables
             (lengths, tokens, keys, steps, temp, top_k, top_p,
-             tables) = args[nc:]
-
-            def run(model):
-                logits, caches = model(
-                    to_variable(tokens[:, None].astype(jnp.int32)),
-                    to_variable(lengths[:, None].astype(jnp.int32)),
-                    caches=arrays, cache_positions=lengths,
-                    block_tables=tables, block_size=bs)
-                return logits.data, caches
-
-            logits, new_arrays = self._apply_model(params, run)
+             *tables) = args[nc:]
+            logits, new_arrays = self._forward_cached(
+                params, tokens[:, None].astype(jnp.int32),
+                lengths[:, None].astype(jnp.int32), arrays, lengths,
+                *tables)
             nxt = sample_tokens(logits[:, 0], keys, steps, temp,
                                 top_k, top_p)
             if self.return_logprobs:
@@ -682,99 +713,55 @@ class GenerationEngine:
                         token_logprobs(logits[:, 0], nxt))
             return (*new_arrays, nxt)
 
-        return decode
+        return self._wrap_step(decode)
+
+    def _prefill_rows(self, bucket, where):
+        """Cache indices ``(i0, i1)`` of a prompt's ``bucket`` rows (the
+        arguments of `kv_write`).  ``where`` is the slot's block-table
+        row ``[1, max_blocks]`` (paged: position p -> pool block
+        table[p // bs], row p % bs; padded positions past the allocated
+        blocks hit table entry 0, the reserved garbage block) or the
+        slot itself (dense: row p of the slot)."""
+        p = jnp.arange(bucket, dtype=jnp.int32)
+        if jnp.ndim(where) == 0:
+            return jnp.full((bucket,), where, jnp.int32), p
+        bs = self.block_size
+        logical = jnp.clip(p // bs, 0, where.shape[1] - 1)
+        return where[0][logical], p % bs
+
+    def _write_prefill(self, arrays, kvs, where):
+        """Every layer's prompt rows into that layer's own arrays."""
+        from ..ops.pallas.paged_attention import kv_write
+
+        i0, i1 = self._prefill_rows(int(kvs[0][0].shape[1]), where)
+        return flatten_layers([
+            kv_write(mine, i0, i1, k[0], v[0])
+            for mine, (k, v) in zip(group_layers(arrays, len(kvs)), kvs)])
 
     def _make_prefill_fn(self, bucket):
-        from ..fluid.dygraph import to_variable
-
-        if not self.paged:
-            def prefill(params, k_stack, v_stack, tokens, length, slot,
-                        key, temp, top_k, top_p):
-                """tokens [1, bucket]; length/slot scalars; writes the
-                slot's cache rows and samples generated token 0."""
-                def run(model):
-                    pos = jnp.arange(bucket, dtype=jnp.int32)[None]
-                    logits, kvs = model(to_variable(tokens),
-                                        to_variable(pos), use_cache=True)
-                    return logits.data, kvs
-
-                logits, kvs = self._apply_model(params, run)
-                for li, (k, v) in enumerate(kvs):
-                    idx = (li, slot, 0, 0, 0)
-                    k_stack = jax.lax.dynamic_update_slice(
-                        k_stack, k.astype(k_stack.dtype)[None], idx)
-                    v_stack = jax.lax.dynamic_update_slice(
-                        v_stack, v.astype(v_stack.dtype)[None], idx)
-                last = jax.lax.dynamic_index_in_dim(
-                    logits[0], length - 1, axis=0)      # [1, V]
-                tok0 = sample_tokens(last, key[None],
-                                     jnp.zeros((1,), jnp.int32),
-                                     temp[None], top_k[None],
-                                     top_p[None])[0]
-                if self.return_logprobs:
-                    return (k_stack, v_stack, tok0,
-                            token_logprobs(last, tok0[None])[0])
-                return k_stack, v_stack, tok0
-
-            return prefill
-
-        from ..ops.pallas.paged_attention import quantize_kv
-
         nc = self._nc
-        bs = self.block_size
-        quant = self.cache.quantized
 
         def prefill(params, *args):
-            """Same flash forward as the dense engine's prefill (bit-
-            identical logits), but the cache write scatters through the
-            slot's table row: position p -> pool block table[p // bs],
-            row p % bs.  Padded positions past the allocated blocks hit
-            table entry 0 — the reserved garbage block."""
+            """tokens [1, bucket]; one flash forward (the dense and the
+            paged engine's are bit-identical), every layer's K/V rows
+            written into the slot's cache rows (`_prefill_rows`:
+            ``where`` is the slot's table row, or for a dense cache the
+            slot), generated token 0 sampled from the last real
+            position."""
             arrays = args[:nc]
-            tokens, length, table, key, temp, top_k, top_p = args[nc:]
-
-            def run(model):
-                pos = jnp.arange(bucket, dtype=jnp.int32)[None]
-                logits, kvs = model(to_variable(tokens),
-                                    to_variable(pos), use_cache=True)
-                return logits.data, kvs
-
-            logits, kvs = self._apply_model(params, run)
-            p = jnp.arange(bucket, dtype=jnp.int32)
-            logical = jnp.clip(p // bs, 0, table.shape[1] - 1)
-            bi = table[0][logical]
-            off = p % bs
-            if quant:
-                k_pool, v_pool, k_sc, v_sc = arrays
-            else:
-                k_pool, v_pool = arrays
-            for li, (k, v) in enumerate(kvs):
-                k_rows = k[0]                        # [bucket, H, Dh]
-                v_rows = v[0]
-                if quant:
-                    kq, ks = quantize_kv(k_rows)
-                    vq, vs = quantize_kv(v_rows)
-                    k_pool = k_pool.at[li, bi, off].set(kq)
-                    v_pool = v_pool.at[li, bi, off].set(vq)
-                    k_sc = k_sc.at[li, bi, off].set(ks)
-                    v_sc = v_sc.at[li, bi, off].set(vs)
-                else:
-                    k_pool = k_pool.at[li, bi, off].set(
-                        k_rows.astype(k_pool.dtype))
-                    v_pool = v_pool.at[li, bi, off].set(
-                        v_rows.astype(v_pool.dtype))
+            tokens, length, where, key, temp, top_k, top_p = args[nc:]
+            logits, kvs = self._forward_prefill(params, tokens, bucket)
+            out = self._write_prefill(arrays, kvs, where)
             last = jax.lax.dynamic_index_in_dim(
                 logits[0], length - 1, axis=0)          # [1, V]
             tok0 = sample_tokens(last, key[None],
                                  jnp.zeros((1,), jnp.int32),
                                  temp[None], top_k[None], top_p[None])[0]
-            out = (k_pool, v_pool, k_sc, v_sc) if quant \
-                else (k_pool, v_pool)
             if self.return_logprobs:
                 return (*out, tok0, token_logprobs(last, tok0[None])[0])
             return (*out, tok0)
 
-        return prefill
+        return self._wrap_step(prefill)
 
     def _make_chunk_fn(self, width):
         """One prefill chunk for ONE slot: ``width`` prompt tokens
@@ -783,26 +770,16 @@ class GenerationEngine:
         math in `ops.pallas.paged_attention`).  Always samples from row
         ``last_index`` — the host ignores the sample on non-final
         chunks, so every chunk runs the same executable."""
-        from ..fluid.dygraph import to_variable
-
         nc = self._nc
-        bs = self.block_size
 
         def chunk(params, *args):
             arrays = args[:nc]
             (tokens, start, table, last_index, key, temp, top_k,
              top_p) = args[nc:]
-
-            def run(model):
-                pos = start + jnp.arange(width, dtype=jnp.int32)[None]
-                logits, caches = model(
-                    to_variable(tokens), to_variable(pos),
-                    caches=arrays,
-                    cache_positions=jnp.reshape(start, (1,)),
-                    block_tables=table, block_size=bs)
-                return logits.data, caches
-
-            logits, new_arrays = self._apply_model(params, run)
+            pos = start + jnp.arange(width, dtype=jnp.int32)[None]
+            logits, new_arrays = self._forward_cached(
+                params, tokens, pos, arrays, jnp.reshape(start, (1,)),
+                table)
             last = jax.lax.dynamic_index_in_dim(
                 logits[0], last_index, axis=0)          # [1, V]
             tok = sample_tokens(last, key[None],
@@ -813,34 +790,24 @@ class GenerationEngine:
                         token_logprobs(last, tok[None])[0])
             return (*new_arrays, tok)
 
-        return chunk
+        return self._wrap_step(chunk)
 
     def _make_verify_fn(self):
         """Speculative verify: feed ``[last, d_1..d_k]`` per slot at
         positions ``L..L+k`` in ONE call; row i is sampled with the
         slot's key at ``steps + i`` so accepted tokens consume exactly
         the PRNG states plain decode would have."""
-        from ..fluid.dygraph import to_variable
-
         nc = self._nc
-        bs = self.block_size
         s_len = self.draft_len + 1
 
         def verify(params, *args):
             arrays = args[:nc]
             (lengths, tok_in, keys, steps, temp, top_k, top_p,
              tables) = args[nc:]
-
-            def run(model):
-                pos = (lengths[:, None]
-                       + jnp.arange(s_len, dtype=jnp.int32)[None])
-                logits, caches = model(
-                    to_variable(tok_in), to_variable(pos),
-                    caches=arrays, cache_positions=lengths,
-                    block_tables=tables, block_size=bs)
-                return logits.data, caches
-
-            logits, new_arrays = self._apply_model(params, run)
+            pos = (lengths[:, None]
+                   + jnp.arange(s_len, dtype=jnp.int32)[None])
+            logits, new_arrays = self._forward_cached(
+                params, tok_in, pos, arrays, lengths, tables)
             toks = jnp.stack(
                 [sample_tokens(logits[:, i], keys, steps + i, temp,
                                top_k, top_p) for i in range(s_len)],
@@ -852,49 +819,30 @@ class GenerationEngine:
                 return (*new_arrays, toks, lps)
             return (*new_arrays, toks)
 
-        return verify
+        return self._wrap_step(verify)
 
     def _make_draft_decode_fn(self):
         """One greedy draft-model decode step over all slots (dense
         draft cache, PR-15 layout)."""
-        from ..fluid.dygraph import to_variable
-
-        def ddecode(params, kd, vd, lengths, tokens):
-            def run(model):
-                logits, caches = model(
-                    to_variable(tokens[:, None].astype(jnp.int32)),
-                    to_variable(lengths[:, None].astype(jnp.int32)),
-                    caches=(kd, vd), cache_positions=lengths)
-                return logits.data, caches
-
-            logits, (k2, v2) = self._apply_model(
-                params, run, model=self.draft_model)
-            return k2, v2, jnp.argmax(logits[:, 0],
-                                      axis=-1).astype(jnp.int32)
+        def ddecode(params, *args):
+            *arrays, lengths, tokens = args
+            logits, new_arrays = self._run_cached(
+                self.draft_model, params,
+                tokens[:, None].astype(jnp.int32),
+                lengths[:, None].astype(jnp.int32), arrays, lengths)
+            return (*new_arrays,
+                    jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32))
 
         return ddecode
 
     def _make_draft_prefill_fn(self, bucket):
         """Write the prompt's K/V into the draft model's dense cache
         (no sampling — the draft only ever proposes from decode)."""
-        from ..fluid.dygraph import to_variable
-
-        def dprefill(params, kd, vd, tokens, slot):
-            def run(model):
-                pos = jnp.arange(bucket, dtype=jnp.int32)[None]
-                logits, kvs = model(to_variable(tokens),
-                                    to_variable(pos), use_cache=True)
-                return logits.data, kvs
-
-            _, kvs = self._apply_model(params, run,
-                                       model=self.draft_model)
-            for li, (k, v) in enumerate(kvs):
-                idx = (li, slot, 0, 0, 0)
-                kd = jax.lax.dynamic_update_slice(
-                    kd, k.astype(kd.dtype)[None], idx)
-                vd = jax.lax.dynamic_update_slice(
-                    vd, v.astype(vd.dtype)[None], idx)
-            return kd, vd
+        def dprefill(params, *args):
+            *arrays, tokens, slot = args
+            _, kvs = self._run_prefill(self.draft_model, params, tokens,
+                                       bucket)
+            return self._write_prefill(arrays, kvs, slot)
 
         return dprefill
 
@@ -1204,39 +1152,25 @@ class GenerationEngine:
         sp = request.sampling
         n_prompt = len(request.prompt_ids)
         key = make_base_key(sp.seed).astype(np.uint32)
-        if not self.paged:
-            self._dense_prefill(slot, request, handle, key)
-            return True
-        n_cached, shared = (self._prefix.lookup(request.prompt_ids)
-                            if self._prefix is not None else (0, []))
-        if self._prefix is not None:
-            if n_cached:
-                self._m_prefix_hits.inc()
-                self._m_prefix_tokens.inc(n_cached)
-            else:
-                self._m_prefix_misses.inc()
-        for j, b in enumerate(shared):
-            self.cache.assign(slot, j, b)
-        self._slot_blocks[slot] = list(shared)
-        if not self._ensure_blocks(slot, n_prompt):
-            self._release_blocks(slot)
-            return False
-        if n_cached > 0 or self.prefill_chunk is not None:
-            tr = _trace.default_tracer()
-            if tr.enabled:
-                tr.async_begin("prefill", handle.trace.trace_id,
-                               cat="generation",
-                               args={"chunked": True,
-                                     "prefix_cached": n_cached})
-            self._chunking[slot] = _ChunkState(
-                request, handle, n_cached, key, time.perf_counter())
-            self._chunk_step(slot)
-            return True
-        # whole-prompt flash prefill through the block table
+        if self.paged:
+            n_cached = self._claim_blocks(slot, request)
+            if n_cached is None:
+                return False
+            if n_cached > 0 or self.prefill_chunk is not None:
+                tr = _trace.default_tracer()
+                if tr.enabled:
+                    tr.async_begin("prefill", handle.trace.trace_id,
+                                   cat="generation",
+                                   args={"chunked": True,
+                                         "prefix_cached": n_cached})
+                self._chunking[slot] = _ChunkState(
+                    request, handle, n_cached, key, time.perf_counter())
+                self._chunk_step(slot)
+                return True
+        # whole-prompt flash prefill into the slot's rows
         bucket = self._bucket_for(n_prompt)
         tokens = np.zeros((1, bucket), np.int32)
         tokens[0, :n_prompt] = request.prompt_ids
-        table = self.cache.table_row(slot)[None].astype(np.int32)
         t0 = time.perf_counter()
         tr = _trace.default_tracer()
         if tr.enabled:
@@ -1251,7 +1185,7 @@ class GenerationEngine:
             with _TRACE_LOCK:
                 out = self._prefill_fns[bucket](
                     self._params, *self.cache.arrays(), tokens,
-                    np.int32(n_prompt), table, key,
+                    np.int32(n_prompt), self._prefill_where(slot), key,
                     np.float32(sp.temperature), np.int32(sp.top_k),
                     np.float32(sp.top_p))
             tok0 = int(out[self._nc])
@@ -1265,35 +1199,32 @@ class GenerationEngine:
         self._activate(slot, request, handle, tok0, lp0, key)
         return True
 
-    def _dense_prefill(self, slot, request, handle, key):
-        sp = request.sampling
-        n_prompt = len(request.prompt_ids)
-        bucket = self._bucket_for(n_prompt)
-        tokens = np.zeros((1, bucket), np.int32)
-        tokens[0, :n_prompt] = request.prompt_ids
-        t0 = time.perf_counter()
-        tr = _trace.default_tracer()
-        if tr.enabled:
-            tr.async_begin("prefill", handle.trace.trace_id,
-                           cat="generation", args={"bucket": bucket})
-        with _DeviceCall(self, "generation.prefill",
-                         args={"bucket": bucket, "slot": int(slot),
-                               "request_id": request.request_id},
-                         trace_id=handle.trace.trace_id):
-            with _TRACE_LOCK:
-                out = self._prefill_fns[bucket](
-                    self._params, self.cache.k, self.cache.v, tokens,
-                    np.int32(n_prompt), np.int32(slot), key,
-                    np.float32(sp.temperature), np.int32(sp.top_k),
-                    np.float32(sp.top_p))
-            tok0 = int(out[2])
-            lp0 = float(out[3]) if self.return_logprobs else None
-        self.cache.update(out[0], out[1])
-        self._m_prefill_ms.observe((time.perf_counter() - t0) * 1e3)
-        if tr.enabled:
-            tr.async_end("prefill", handle.trace.trace_id,
-                         cat="generation")
-        self._activate(slot, request, handle, tok0, lp0, key)
+    def _prefill_where(self, slot):
+        """The prefill executable's ``where`` operand: the slot's block
+        table row, or for a dense cache the slot."""
+        if self.paged:
+            return self.cache.table_row(slot)[None].astype(np.int32)
+        return np.int32(slot)
+
+    def _claim_blocks(self, slot, request):
+        """Adopt cached prefix blocks and allocate the rest of the
+        prompt's.  Returns the number of prompt tokens already cached,
+        or None (nothing claimed) when the pool is dry."""
+        n_cached, shared = (self._prefix.lookup(request.prompt_ids)
+                            if self._prefix is not None else (0, []))
+        if self._prefix is not None:
+            if n_cached:
+                self._m_prefix_hits.inc()
+                self._m_prefix_tokens.inc(n_cached)
+            else:
+                self._m_prefix_misses.inc()
+        for j, b in enumerate(shared):
+            self.cache.assign(slot, j, b)
+        self._slot_blocks[slot] = list(shared)
+        if not self._ensure_blocks(slot, len(request.prompt_ids)):
+            self._release_blocks(slot)
+            return None
+        return n_cached
 
     def _chunk_step(self, slot):
         """Advance one chunked prefill by one chunk (one executable
@@ -1365,10 +1296,9 @@ class GenerationEngine:
             tokens = np.zeros((1, bucket), np.int32)
             tokens[0, :n_prompt] = request.prompt_ids
             with _TRACE_LOCK:
-                kd, vd = self._draft_prefill_fns[bucket](
+                self._draft_cache.update(*self._draft_prefill_fns[bucket](
                     self._draft_params, *self._draft_cache.arrays(),
-                    tokens, np.int32(slot))
-            self._draft_cache.update(kd, vd)
+                    tokens, np.int32(slot)))
         st = _Slot(request, handle)
         self._slot_state[slot] = st
         self._lengths[slot] = n_prompt
@@ -1410,18 +1340,11 @@ class GenerationEngine:
                             "slot left to make room")
                 if not self._active.any():
                     return
-                operands = (*self.cache.arrays(), self._lengths,
-                            self._last_tokens, self._keys, self._steps,
-                            self._temp, self._top_k, self._top_p,
-                            self._decode_tables())
-        else:
-            operands = (self.cache.k, self.cache.v, self._lengths,
-                        self._last_tokens, self._keys, self._steps,
-                        self._temp, self._top_k, self._top_p)
+        operands = self._decode_operands()
         t0 = time.perf_counter()
         with _DeviceCall(self, "generation.decode_dispatch"):
             with _TRACE_LOCK:
-                out = self._decode_step_fn(self._params, *operands)
+                out = self._decode_step_fn(*operands)
         # the host waits here while the device works
         with _DeviceCall(self, "generation.decode_fetch"):
             nxt = np.asarray(out[self._nc])
@@ -1472,18 +1395,17 @@ class GenerationEngine:
         n = self.slots
         drafts = np.zeros((n, k), np.int32)
         cur = self._last_tokens.copy()
-        kd, vd = self._draft_cache.arrays()
         t0 = time.perf_counter()
         for i in range(k):
             with _DeviceCall(self, "generation.decode_dispatch"):
                 with _TRACE_LOCK:
-                    kd, vd, nxt = self._draft_decode_fn(
-                        self._draft_params, kd, vd,
+                    *darrays, nxt = self._draft_decode_fn(
+                        self._draft_params, *self._draft_cache.arrays(),
                         self._lengths + np.int32(i), cur)
+            self._draft_cache.update(*darrays)
             with _DeviceCall(self, "generation.decode_fetch"):
                 cur = np.asarray(nxt)
             drafts[:, i] = cur
-        self._draft_cache.update(kd, vd)
         tok_in = np.concatenate(
             [self._last_tokens[:, None], drafts], axis=1).astype(np.int32)
         tables = self._decode_tables()
@@ -1693,7 +1615,6 @@ class GenerationEngine:
             bucket = self._bucket_for(n_prompt)
             tokens = np.zeros((1, bucket), np.int32)
             tokens[0, :n_prompt] = request.prompt_ids
-            table = self.cache.table_row(slot)[None].astype(np.int32)
             t0 = time.perf_counter()
             tr = _trace.default_tracer()
             if tr.enabled:
@@ -1703,7 +1624,7 @@ class GenerationEngine:
             with _TRACE_LOCK:
                 out = self._prefill_fns[bucket](
                     self._params, *self.cache.arrays(), tokens,
-                    np.int32(n_prompt), table, key,
+                    np.int32(n_prompt), self._prefill_where(slot), key,
                     np.float32(sp.temperature), np.int32(sp.top_k),
                     np.float32(sp.top_p))
             self.cache.update(*out[:self._nc])
@@ -1714,7 +1635,7 @@ class GenerationEngine:
             if tr.enabled:
                 tr.async_end("prefill", tc.trace_id, cat="generation")
             idx = np.asarray(self._slot_blocks[slot], np.int32)
-            pages = tuple(np.asarray(a[:, idx])
+            pages = tuple(np.asarray(a[idx])
                           for a in self.cache.arrays())
             self._release_blocks(slot)
             self._free.append(slot)
@@ -1748,12 +1669,16 @@ class GenerationEngine:
         if handoff.kv_dtype != self.cache.kv_dtype:
             raise ValueError("handoff kv_dtype %r != engine %r"
                              % (handoff.kv_dtype, self.cache.kv_dtype))
-        shape = self.cache.shape
-        if handoff.pages[0].shape[0] != shape[0] or \
-                handoff.pages[0].shape[2:] != shape[2:]:
+        # the wire form is the pool's own: one page array per pool
+        # array, [n_blocks, block_size, H*D] (scales [.., H])
+        pools = self.cache.arrays()
+        if len(handoff.pages) != len(pools) or any(
+                page.shape[1:] != a.shape[1:]
+                for page, a in zip(handoff.pages, pools)):
             raise ValueError(
-                "handoff page geometry %r does not fit pool %r"
-                % (handoff.pages[0].shape, shape))
+                "handoff page geometry %r x %d does not fit pool %r x %d"
+                % (handoff.pages[0].shape, len(handoff.pages),
+                   pools[0].shape, len(pools)))
         with self._lock:
             if self._dead:
                 raise EngineDeadError("engine %s is dead" % self._engine)
@@ -1794,7 +1719,7 @@ class GenerationEngine:
         rebuild the table row, copy pages in, arm decode.  Returns
         False (caller requeues) when the pool is dry."""
         self._slot_blocks[slot] = []
-        n_blocks = int(handoff.pages[0].shape[1])
+        n_blocks = int(handoff.pages[0].shape[0])
         try:
             ids = self.cache.pool.alloc(n_blocks)
         except PoolExhausted:
@@ -1810,7 +1735,7 @@ class GenerationEngine:
         self._set_block_gauges()
         idx = np.asarray(ids, np.int32)
         arrays = tuple(
-            jnp.asarray(a).at[:, idx].set(page)
+            jnp.asarray(a).at[idx].set(page)
             for a, page in zip(self.cache.arrays(), handoff.pages))
         self.cache.update(*arrays)
         tr = _trace.default_tracer()
@@ -1870,20 +1795,18 @@ class GenerationEngine:
         """Optimized HLO of the ACTUAL decode executable, lowered with
         the engine's live operands — what the TP comm drills pin
         `decode_comm_estimate` against and `chip_smoke.py` searches for
-        the decode kernel's custom call."""
+        a kernel's custom call."""
         with self._lock, _TRACE_LOCK:      # lowering retraces the model
-            if self.paged:
-                lowered = self._decode_step_fn.lower(
-                    self._params, *self.cache.arrays(), self._lengths,
-                    self._last_tokens, self._keys, self._steps,
-                    self._temp, self._top_k, self._top_p,
-                    self._decode_tables())
-            else:
-                lowered = self._decode_step_fn.lower(
-                    self._params, self.cache.k, self.cache.v,
-                    self._lengths, self._last_tokens, self._keys,
-                    self._steps, self._temp, self._top_k, self._top_p)
+            lowered = self._decode_step_fn.lower(*self._decode_operands())
         return lowered.compile().as_text()
+
+    def _decode_operands(self):
+        """The decode executable's live operands; a paged engine's last
+        one is the block tables."""
+        return (self._params, *self.cache.arrays(), self._lengths,
+                self._last_tokens, self._keys, self._steps, self._temp,
+                self._top_k, self._top_p,
+                *((self._decode_tables(),) if self.paged else ()))
 
     def _decode_cache_size(self):
         """Jit-cache entries of the decode step — the compile-once pin."""

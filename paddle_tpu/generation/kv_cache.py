@@ -1,15 +1,22 @@
 """KV caches: the decode step's working set, dense and PAGED.
 
-`KVCache` (PR 15) is the dense layout — ``[L, slots, max_len, H, D]``
-per array, every slot paying ``max_len`` HBM whether its sequence is 20
-tokens or 2000.  PERF.md round 13 proved the decode step is KV-read
-memory-bound, which makes those idle bytes the top perf lever left on
-the table (ROADMAP item 1).
+Both caches hold ONE ARRAY PER LAYER for K and for V, heads and head
+dimension merged into one last dimension (`_layer_shape` says why):
+``arrays()`` is the flat tuple ``(k_0..k_{L-1}, v_0..v_{L-1})`` (int8
+pools: then the scales the same way), the donated operands of every
+step function in the engine's argument order, and ``update(*arrays)``
+its inverse.  A step writes its new rows into each layer's own donated
+array (`ops.pallas.paged_attention.kv_write`) and that array is the
+step's output: nothing pool-sized is sliced, stacked or copied.
+
+`KVCache` (PR 15) is the dense layout — ``[slots, max_len, H*D]`` per
+layer, every slot paying ``max_len`` HBM whether its sequence is 20
+tokens or 2000.
 
 `PagedKVCache` rebuilds the store as a BLOCK POOL:
 
-* device arrays ``[L, num_blocks, block_size, H, D]`` (k and v) — a
-  fixed-shape pool every slot draws from, so the compiled decode
+* device arrays ``[num_blocks, block_size, H*D]`` per layer (k and v)
+  — a fixed-shape pool every slot draws from, so the compiled decode
   executable never changes as blocks migrate between requests;
 * a host-side per-slot block table ``[slots, max_blocks_per_slot]``
   int32 mapping logical block j to a physical pool block.  The table
@@ -23,7 +30,7 @@ the table (ROADMAP item 1).
   entirely.  Only full blocks are ever shared, so the writable tail is
   always private and copy-on-write never arises;
 * optional int8 storage (``kv_dtype="int8"``): pools hold int8 rows
-  plus per-row per-head f32 scales — halving (vs f32: quartering) the
+  plus per-row per-head f32 scales ``[num_blocks, block_size, H]`` — halving (vs f32: quartering) the
   KV bytes the memory-bound step streams, under the documented-
   tolerance opt-in policy (`PADDLE_TPU_FLASH_ACC` discipline).
 
@@ -43,7 +50,58 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["BlockPool", "KVCache", "PagedKVCache", "PoolExhausted",
-           "PrefixCache"]
+           "PrefixCache", "flatten_layers", "group_layers"]
+
+
+def group_layers(arrays, num_layers):
+    """The flat ``arrays()`` tuple as one tuple per layer,
+    ``[(k_l, v_l), ...]`` (an int8 pool: ``(k_l, v_l, k_scale_l,
+    v_scale_l)``): what a cached forward takes, so that a model hands
+    each block its own arrays and never learns the engine's operand
+    order."""
+    return [tuple(arrays[li::num_layers]) for li in range(num_layers)]
+
+
+def flatten_layers(layers):
+    """Inverse of `group_layers`: per-layer tuples back into the order
+    of ``arrays()`` (each kind of array runs over the layers)."""
+    return tuple(a for kind in zip(*layers) for a in kind)
+
+
+def _layer_shape(rows, row_len, num_heads, head_dim):
+    """One layer's K (or V) array: ``[rows, row_len, H*D]``.
+
+    Why the heads are merged into the last dimension: the chip tiles an
+    array's two minor dimensions into (8, 128) float32 tiles.  With
+    ``[..., H, D]`` and D = 64 every tile would be half padding, so for
+    ``f32[1025, 16, 16, 64]`` the compiler instead stores the array
+    with the BLOCK index minor-most (``{0,3,2,1:T(8,128)}``, 1025
+    padded to 1152), a layout in which nothing can be scattered or
+    gathered by block: every step then transposed each layer's pool to
+    row-major, updated it and transposed it back (PERF.md section 6,
+    PR 27).  With H*D a multiple of 128 (1024 = 8 lane tiles for
+    GPT-2-medium) the default layout of the merged form is row-major
+    and unpadded, a row write is an in-place scatter into the donated
+    array and a block gather reads contiguous rows.  Where H*D is not
+    such a multiple (the tests' tiny models) the last dimension is
+    padded up to the next one, which is still never more than the
+    padding of a separate ``[H, D]`` pair, so the one form serves every
+    head shape."""
+    return (int(rows), int(row_len), int(num_heads) * int(head_dim))
+
+
+def _adopt(cache, arrays):
+    """`update` of both caches: adopt the arrays a donated call
+    returned, in the order of `arrays()` (the old handles are invalid
+    once donated — never keep them)."""
+    if len(arrays) != len(cache._arrays):
+        raise ValueError("expected %d cache arrays, got %d"
+                         % (len(cache._arrays), len(arrays)))
+    cache._arrays = tuple(arrays)
+
+
+def _nbytes(arrays):
+    return int(sum(a.size * a.dtype.itemsize for a in arrays))
 
 
 class KVCache:
@@ -58,26 +116,22 @@ class KVCache:
         self.num_heads = int(num_heads)
         self.head_dim = int(head_dim)
         self.dtype = jnp.dtype(dtype)
-        shape = (self.num_layers, self.slots, self.max_len,
-                 self.num_heads, self.head_dim)
-        self.k = jnp.zeros(shape, self.dtype)
-        self.v = jnp.zeros(shape, self.dtype)
-
-    @property
-    def shape(self):
-        return tuple(self.k.shape)
+        self.layer_shape = _layer_shape(self.slots, self.max_len,
+                                        self.num_heads, self.head_dim)
+        self._arrays = tuple(jnp.zeros(self.layer_shape, self.dtype)
+                             for _ in range(2 * self.num_layers))
 
     @property
     def nbytes(self):
-        return int(2 * np.prod(self.shape) * self.dtype.itemsize)
+        return _nbytes(self._arrays)
 
     def arrays(self):
-        return self.k, self.v
+        """``(k_0..k_{L-1}, v_0..v_{L-1})``: the donated operands."""
+        return self._arrays
 
-    def update(self, k, v):
-        """Adopt the arrays a donated prefill/decode call returned (the
-        old handles are invalid once donated — never keep them)."""
-        self.k, self.v = k, v
+    def update(self, *arrays):
+        """Adopt donated-call outputs (order of `arrays`)."""
+        _adopt(self, arrays)
 
     def describe(self):
         return {
@@ -291,48 +345,36 @@ class PagedKVCache:
         self.kv_dtype = kv_dtype
         self.quantized = kv_dtype == "int8"
         store = jnp.int8 if self.quantized else self.dtype
-        shape = (self.num_layers, self.num_blocks, self.block_size,
-                 self.num_heads, self.head_dim)
-        self.k = jnp.zeros(shape, store)
-        self.v = jnp.zeros(shape, store)
+        self.layer_shape = _layer_shape(self.num_blocks, self.block_size,
+                                        self.num_heads, self.head_dim)
+        n = 2 * self.num_layers
+        self._arrays = tuple(jnp.zeros(self.layer_shape, store)
+                             for _ in range(n))
         if self.quantized:
-            sshape = shape[:-1]
-            self.k_scale = jnp.zeros(sshape, jnp.float32)
-            self.v_scale = jnp.zeros(sshape, jnp.float32)
-        else:
-            self.k_scale = self.v_scale = None
+            self._arrays += tuple(
+                jnp.zeros(self.layer_shape[:2] + (self.num_heads,),
+                          jnp.float32) for _ in range(n))
         self.pool = BlockPool(self.num_blocks)
         self.block_tables = np.zeros(
             (self.slots, self.max_blocks_per_slot), np.int32)
 
     @property
-    def shape(self):
-        return tuple(self.k.shape)
-
-    @property
     def nbytes(self):
-        store = jnp.int8 if self.quantized else self.dtype
-        n = int(2 * np.prod(self.shape) * jnp.dtype(store).itemsize)
-        if self.quantized:
-            n += int(2 * np.prod(self.k_scale.shape) * 4)
-        return n
+        return _nbytes(self._arrays)
 
     @property
     def capacity_tokens(self):
         return (self.num_blocks - 1) * self.block_size
 
     def arrays(self):
-        """The donated operands, in the engine's argument order."""
-        if self.quantized:
-            return self.k, self.v, self.k_scale, self.v_scale
-        return self.k, self.v
+        """The donated operands, in the engine's argument order:
+        ``(k_0..k_{L-1}, v_0..v_{L-1})``, for an int8 pool followed by
+        its scales the same way."""
+        return self._arrays
 
     def update(self, *arrays):
         """Adopt donated-call outputs (order of `arrays`)."""
-        if self.quantized:
-            self.k, self.v, self.k_scale, self.v_scale = arrays
-        else:
-            self.k, self.v = arrays
+        _adopt(self, arrays)
 
     # -- slot bookkeeping (host) ------------------------------------------
     def blocks_for(self, n_tokens):

@@ -160,11 +160,12 @@ class MultiHeadAttention(dygraph.Layer):
           returns the projected ``(k, v)`` as raw ``[B, S, H, Dh]``
           jax arrays — what the engine copies into its slot cache.
         * ``cache=(k_cache, v_cache, pos)`` (decode): ``query`` is ONE
-          token per row; its K/V are written into the ``[B, T, H, Dh]``
-          cache arrays at index ``pos`` ([B] int) and attention runs
-          over the cache through `ops.pallas.decode_attention` with
-          positions ``<= pos`` live.  Returns
-          ``(out, (k_cache', v_cache'))``.
+          token per row; its K/V are written into this layer's
+          ``[B, T, H*Dh]`` cache arrays at index ``pos`` ([B] int) and
+          attention runs over the cache with positions ``<= pos`` live
+          (`ops.pallas.paged_attention.cached_attention`).
+          Returns ``(out, (k_cache', v_cache'))``.  The paged forms
+          are in `_decode_with_cache`.
         """
         key = key if key is not None else query
         value = value if value is not None else key
@@ -229,118 +230,25 @@ class MultiHeadAttention(dygraph.Layer):
         return out
 
     def _decode_with_cache(self, q, k, v, cache):
-        """Decode/chunk attention over a cache: write the C query
-        tokens' K/V at positions ``pos..pos+C-1``, then attend row i
-        over positions ``<= pos+i`` (C == 1 is the classic decode step;
-        C > 1 is a chunked-prefill / speculative-verify call).  Fixed
-        shapes throughout — each (C,) config compiles once.
-
-        Cache tuple forms:
-
-        * dense  — ``(k_cache, v_cache, pos)`` with ``[B, T, H, Dh]``
-          arrays (the PR-15 layout);
-        * paged  — ``(k_pool, v_pool, pos, tables, block_size)`` with
-          ``[NB, bs, H, Dh]`` pools and a ``[B, max_blocks]`` int32
-          block table: writes scatter through the table, attention
-          gathers through it (`ops.pallas.paged_attention`);
-        * paged int8 — ``(k_pool, v_pool, k_scale, v_scale, pos,
-          tables, block_size)``: int8 pools + per-row per-head f32
-          scales, rows quantized on write and dequantized in-kernel.
-
-        Returns ``(out, updated cache arrays)`` in the same order the
-        tuple carried them."""
-        import jax
+        """Decode/chunk attention over one layer's cache: the C query
+        tokens' K/V are written at positions ``pos..pos+C-1`` of the
+        layer's own cache arrays and row i attends positions
+        ``<= pos+i``.  The cache tuple forms (dense, paged, paged int8;
+        arrays with heads and head dimension merged) and the math are
+        `ops.pallas.paged_attention.cached_attention`'s, which the
+        tensor-parallel forward shares.  Returns ``(out, updated cache
+        arrays)`` in the order the tuple carried them."""
         import jax.numpy as jnp
 
         from ..fluid.dygraph import to_variable
-        from ..ops.pallas.decode_attention import decode_attention
-        from ..ops.pallas.paged_attention import (
-            chunked_attention_reference,
-            paged_decode_attention,
-            paged_gather_kv,
-            quantize_kv,
-        )
+        from ..ops.pallas.paged_attention import cached_attention
 
-        scale = self.d_head ** -0.5
-        c_len = int(q.shape[1])
-        k_new = jnp.asarray(k.data)                  # [B, C, H, Dh]
-        v_new = jnp.asarray(v.data)
-        q_arr = jnp.asarray(q.data)
-
-        if len(cache) == 3:                          # dense
-            k_cache, v_cache, pos = cache
-            pos = jnp.asarray(pos).astype(jnp.int32)
-
-            def write_rows(cbuf, new, p):
-                # cbuf [T, H, Dh]; new [C, H, Dh]; p scalar
-                return jax.lax.dynamic_update_slice(cbuf, new, (p, 0, 0))
-
-            k_cache = jax.vmap(write_rows)(jnp.asarray(k_cache),
-                                           k_new, pos)
-            v_cache = jax.vmap(write_rows)(jnp.asarray(v_cache),
-                                           v_new, pos)
-            if c_len == 1:
-                ctx = decode_attention(q_arr[:, 0], k_cache, v_cache,
-                                       pos + 1, scale=scale)[:, None]
-            else:
-                ctx = chunked_attention_reference(
-                    q_arr, k_cache, v_cache, pos, scale=scale)
-            new_cache = (k_cache, v_cache)
-        elif len(cache) in (5, 7):                   # paged
-            if len(cache) == 5:
-                k_pool, v_pool, pos, tables, bs = cache
-                k_scale = v_scale = None
-            else:
-                (k_pool, v_pool, k_scale, v_scale, pos, tables,
-                 bs) = cache
-            bs = int(bs)
-            pos = jnp.asarray(pos).astype(jnp.int32)
-            tables = jnp.asarray(tables).astype(jnp.int32)
-            nb = int(tables.shape[1])
-            # scatter the C new rows through the table: position p ->
-            # pool block tables[n, p // bs], row p % bs.  Inactive
-            # slots' tables are all-zero, so their garbage rows land in
-            # the reserved block nobody reads.
-            p = pos[:, None] + jnp.arange(c_len, dtype=jnp.int32)[None]
-            logical = jnp.clip(p // bs, 0, nb - 1)
-            bi = jnp.take_along_axis(tables, logical, axis=1).ravel()
-            off = (p % bs).ravel()
-            k_pool = jnp.asarray(k_pool)
-            v_pool = jnp.asarray(v_pool)
-            h, dh = k_new.shape[2], k_new.shape[3]
-            k_rows = k_new.reshape(-1, h, dh)
-            v_rows = v_new.reshape(-1, h, dh)
-            if k_scale is not None:
-                k_q, k_s = quantize_kv(k_rows)
-                v_q, v_s = quantize_kv(v_rows)
-                k_pool = k_pool.at[bi, off].set(k_q)
-                v_pool = v_pool.at[bi, off].set(v_q)
-                k_scale = jnp.asarray(k_scale).at[bi, off].set(k_s)
-                v_scale = jnp.asarray(v_scale).at[bi, off].set(v_s)
-            else:
-                k_pool = k_pool.at[bi, off].set(
-                    k_rows.astype(k_pool.dtype))
-                v_pool = v_pool.at[bi, off].set(
-                    v_rows.astype(v_pool.dtype))
-            if c_len == 1:
-                ctx = paged_decode_attention(
-                    q_arr[:, 0], k_pool, v_pool, tables, pos + 1,
-                    scale=scale, k_scale=k_scale,
-                    v_scale=v_scale)[:, None]
-            else:
-                k_dense = paged_gather_kv(k_pool, tables, k_scale)
-                v_dense = paged_gather_kv(v_pool, tables, v_scale)
-                ctx = chunked_attention_reference(
-                    q_arr, k_dense, v_dense, pos, scale=scale)
-            new_cache = ((k_pool, v_pool) if k_scale is None
-                         else (k_pool, v_pool, k_scale, v_scale))
-        else:
-            raise ValueError(
-                "cache tuple must have 3 (dense), 5 (paged) or 7 "
-                "(paged int8) entries, got %d" % len(cache))
+        ctx, new_cache = cached_attention(
+            jnp.asarray(q.data), jnp.asarray(k.data), jnp.asarray(v.data),
+            cache, scale=self.d_head ** -0.5)
         ctxv = to_variable(ctx)                      # [B, C, H, Dh]
-        ctxv = layers.reshape(ctxv,
-                              [0, c_len, self.n_head * self.d_head])
+        ctxv = layers.reshape(
+            ctxv, [0, int(q.shape[1]), self.n_head * self.d_head])
         return self.dropout(self.out_proj(ctxv)), new_cache
 
 

@@ -10,11 +10,13 @@ input/output embeddings (GPT-style).  Three forward modes:
 * ``forward(..., use_cache=True)`` — prefill: same math on the flash
   path, but every layer also hands back its projected ``(k, v)``
   ``[B, S, H, Dh]`` arrays for the engine to copy into its slot cache;
-* ``forward(..., caches=(k_stack, v_stack), cache_positions=pos)`` —
-  decode: one token per row; K/V written into the
-  ``[L, N, T, H, Dh]`` cache stacks at ``pos`` and attention runs over
-  the cache (`ops.pallas.decode_attention`), returning the updated
-  stacks.  Fixed shapes, so the engine's decode step compiles ONCE.
+* ``forward(..., caches=[(k_0, v_0), ...], cache_positions=pos)`` —
+  decode: one token per row; each layer's K/V written at ``pos`` into
+  that layer's own ``[N, T, H*Dh]`` cache arrays (`generation.kv_cache`:
+  one array per layer, heads merged into the last dimension) and
+  attention runs over the cache (`ops.pallas.paged_attention.
+  cached_attention`), returning the updated arrays, again one tuple
+  per layer.  Fixed shapes, so the engine's decode step compiles ONCE.
 """
 
 from __future__ import annotations
@@ -128,12 +130,13 @@ class TransformerLM(dygraph.Layer):
         ``use_cache=True`` (prefill) it is ``(logits, [(k, v), ...])``
         per layer; otherwise just ``logits [B, S, V]``.
 
-        ``caches`` is dense ``(k_stack, v_stack)`` of
-        ``[L, B, T, H, Dh]`` (PR-15), or — when ``block_tables``
-        ``[B, max_blocks]`` and ``block_size`` are given — a PAGED pool
-        ``[L, NB, bs, H, Dh]`` pair, optionally followed by int8
-        per-row scale stacks ``[L, NB, bs, H]``
-        (``(k, v, k_scale, v_scale)``)."""
+        ``caches`` is one tuple per layer (`generation.kv_cache.
+        group_layers` of a cache's arrays): ``(k_l, v_l)`` dense
+        ``[B, T, H*Dh]`` arrays (PR-15), or — when ``block_tables``
+        ``[B, max_blocks]`` and ``block_size`` are given — PAGED pools
+        ``[NB, bs, H*Dh]``, optionally followed by the layer's int8
+        per-row scales ``[NB, bs, H]`` (``(k_l, v_l, k_scale_l,
+        v_scale_l)``)."""
         s_len = int(input_ids.shape[1])
         emb = self.word(input_ids) + self.position(position_ids)
         # the lookup op squeezes Paddle's [B, 1] ids convention; decode
@@ -142,21 +145,14 @@ class TransformerLM(dygraph.Layer):
         h = self.dropout(emb)
         new_kv = []
         if caches is not None:
-            import jax.numpy as jnp
-
-            stacks = [jnp.asarray(c) for c in caches]
-            out_rows = [[] for _ in stacks]
-            for li, block in enumerate(self.blocks):
-                per_layer = tuple(s[li] for s in stacks)
-                if block_tables is None:
-                    cache = per_layer + (cache_positions,)
-                else:
-                    cache = per_layer + (cache_positions, block_tables,
-                                         block_size)
-                h, updated = block(h, cache=cache)
-                for rows, arr in zip(out_rows, updated):
-                    rows.append(arr)
-            out_caches = tuple(jnp.stack(rows) for rows in out_rows)
+            tail = ((cache_positions,) if block_tables is None
+                    else (cache_positions, block_tables, block_size))
+            out_caches = []
+            for block, mine in zip(self.blocks, caches):
+                # the block writes into ITS arrays and hands them back;
+                # nothing is sliced out of a stack or stacked again
+                h, updated = block(h, cache=tuple(mine) + tail)
+                out_caches.append(tuple(updated))
         else:
             for block in self.blocks:
                 if use_cache:

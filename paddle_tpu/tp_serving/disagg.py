@@ -6,9 +6,11 @@ block-table row — to decode workers, which run ONLY the decode step.
 The two phases stop competing for the same chip: prefill's long
 compute-bound calls no longer stall decode's latency-bound steps.
 
-* `KVHandoff` — the wire unit: finished pool pages ``[L, n_blocks,
-  bs, H, Dh]`` (+ int8 scales), first sampled token, the request's
-  PRNG key, and geometry for validation.  ``nbytes`` is what a real
+* `KVHandoff` — the wire unit: finished pool pages in the pool's own
+  form, one ``[n_blocks, bs, H*Dh]`` array per layer for K and for V
+  (+ int8 scales ``[n_blocks, bs, H]``; the order of
+  `PagedKVCache.arrays`), first sampled token, the request's PRNG key,
+  and geometry for validation.  ``nbytes`` is what a real
   deployment would move over ICI/DCN; `DisaggPair` and
   `ShardGroupFleet` meter it as ``kv_transfer_bytes``.
 * `DisaggPair` — one co-scheduled group: a prefill-role engine and a
@@ -76,7 +78,7 @@ class KVHandoff:
         return {
             "request_id": self.request.request_id,
             "n_prompt": self.n_prompt,
-            "blocks": int(self.pages[0].shape[1]),
+            "blocks": int(self.pages[0].shape[0]),
             "bytes": self.nbytes,
             "kv_dtype": self.kv_dtype or "float32",
         }

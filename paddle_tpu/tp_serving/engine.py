@@ -4,17 +4,17 @@ tensor-parallel programs over a one-axis ``Mesh(("tp",))``.
 
 Everything host-side is INHERITED unchanged — scheduling, block
 accounting, prefix cache, chunked prefill, speculative decoding,
-admission, metrics, hot-swap: the subclass only overrides the
-``_make_*_fn`` factories to return `jax.shard_map`
-wrappings of the shard-local functional forward (`tp_serving.model`)
-with IDENTICAL positional signatures, so every call site, the
-compile-count pin, and the one-executable-per-config invariant carry
-over verbatim.  Weights enter through `tp_serving.layout`: column
+admission, metrics, hot-swap — and so are the step functions' bodies
+(sampling, the cache write): the subclass overrides the served model's
+two forwards with the shard-local functional forward
+(`tp_serving.model`) and wraps each body in `jax.shard_map`, with
+IDENTICAL positional signatures, so every call site, the compile-count
+pin, and the one-executable-per-config invariant carry over verbatim.  Weights enter through `tp_serving.layout`: column
 shards for qkv/fc1, row shards for out_proj/fc2 (two all-reduces per
 layer — one per sub-layer), replicated embeddings/norms; the KV cache
-(dense stacks and the paged block pool alike) shards over the HEADS
-axis, so each chip stores ``1/tp`` of the pool and of the attention
-weights — the "serve models bigger than one chip" claim, priced by
+(dense and the paged block pool alike, one array per layer) shards its
+merged ``H*Dh`` dimension, which is a split by HEADS, so each chip
+stores ``1/tp`` of the pool and of the attention weights — the "serve models bigger than one chip" claim, priced by
 `analysis.perf.decode_step_cost(tp=...)`.
 
 The draft model of speculative decoding stays replicated (it is small
@@ -34,7 +34,7 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from ..generation.engine import GenerationEngine
-from ..generation.sampling import sample_tokens, token_logprobs
+from ..generation.kv_cache import flatten_layers, group_layers
 from . import model as tp_model
 from .layout import (
     prepare_tp_params,
@@ -84,204 +84,52 @@ class TPGenerationEngine(GenerationEngine):
         # traced call returns mesh-committed arrays, and jit keys on
         # that — without this the SECOND call of each prefill bucket
         # would get a second executable, breaking the
-        # one-executable-per-config pin.  Trailing-None specs are
-        # trimmed to match the canonical form traced outputs carry
-        # (P(...,'tp',None) and P(...,'tp') are the same sharding but
-        # DIFFERENT jit keys).
-        def _canon(spec):
-            parts = list(spec)
-            while parts and parts[-1] is None:
-                parts.pop()
-            return NamedSharding(self._mesh, P(*parts))
-
-        self.cache.update(*(jax.device_put(a, _canon(s)) for a, s in
-                            zip(self.cache.arrays(),
-                                self._cache_specs())))
+        # one-executable-per-config pin.  (The specs end in "tp", the
+        # canonical form traced outputs carry: a trailing None would be
+        # the same sharding but a DIFFERENT jit key.)
+        self.cache.update(*(
+            jax.device_put(a, NamedSharding(self._mesh, s))
+            for a, s in zip(self.cache.arrays(), self._cache_specs())))
 
     # -- sharding plumbing -------------------------------------------------
     def _cache_specs(self):
-        """KV arrays shard over the heads axis: pool/stack layouts are
-        ``[L, *, *, H, Dh]`` and int8 scale stacks ``[L, NB, bs, H]``."""
-        kv = P(None, None, None, "tp", None)
-        if self.paged and self.cache.quantized:
-            return (kv, kv, P(None, None, None, "tp"),
-                    P(None, None, None, "tp"))
-        return (kv, kv)
+        """Every KV array shards over its LAST dimension: the merged
+        ``H*Dh`` of a layer's ``[*, *, H*Dh]`` pool or dense cache (a
+        contiguous split of it is a split by heads) and the ``H`` of an
+        int8 pool's ``[NB, bs, H]`` scales."""
+        return (P(None, None, "tp"),) * self._nc
 
-    def _tp_wrap(self, body, n_host):
-        """shard_map a traced-fn body: params tree + heads-sharded
-        cache operands + ``n_host`` replicated host operands in; cache
-        arrays + replicated token outputs (sampling runs post-psum on
-        identical logits, so every shard computes the same tokens)."""
-        cache_specs = self._cache_specs()
-        in_specs = ((self._param_specs,) + cache_specs
-                    + (P(),) * n_host)
-        out_specs = cache_specs + (P(),) * (
-            2 if self.return_logprobs else 1)
-        return jax.shard_map(body, mesh=self._mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
+    def _wrap_step(self, body):
+        """shard_map a step function's body: params tree +
+        heads-sharded cache operands + replicated host operands in;
+        cache arrays + replicated token outputs (sampling runs
+        post-psum on identical logits, so every shard computes the
+        same tokens)."""
+        def sharded(params, *args):
+            cache_specs = self._cache_specs()
+            in_specs = ((self._param_specs,) + cache_specs
+                        + (P(),) * (len(args) - self._nc))
+            out_specs = cache_specs + (P(),) * (
+                2 if self.return_logprobs else 1)
+            return jax.shard_map(body, mesh=self._mesh, in_specs=in_specs,
+                                 out_specs=out_specs,
+                                 check_vma=False)(params, *args)
 
-    # -- traced-function factories (same signatures as the base) ----------
-    def _make_decode_fn(self):
-        cfg, tp, nc = self.cfg, self.tp, self._nc
-        if not self.paged:
-            def decode(params, k_stack, v_stack, lengths, tokens, keys,
-                       steps, temp, top_k, top_p):
-                logits, (k2, v2) = tp_model.cached_forward(
-                    params, tokens[:, None].astype(jnp.int32),
-                    lengths[:, None].astype(jnp.int32),
-                    (k_stack, v_stack), lengths, cfg, tp)
-                nxt = sample_tokens(logits[:, 0], keys, steps, temp,
-                                    top_k, top_p)
-                if self.return_logprobs:
-                    return k2, v2, nxt, token_logprobs(logits[:, 0], nxt)
-                return k2, v2, nxt
+        return sharded
 
-            return self._tp_wrap(decode, 7)
+    # -- the served model's two forwards, shard-local ----------------------
+    def _forward_cached(self, params, ids, pos, arrays, cache_positions,
+                        tables=None):
+        logits, layers = tp_model.cached_forward(
+            params, ids, pos, group_layers(arrays, self.cfg.num_layers),
+            cache_positions, self.cfg, self.tp, block_tables=tables,
+            block_size=self.block_size)
+        return logits, flatten_layers(layers)
 
-        bs = self.block_size
-
-        def decode(params, *args):
-            arrays = args[:nc]
-            (lengths, tokens, keys, steps, temp, top_k, top_p,
-             tables) = args[nc:]
-            logits, new_arrays = tp_model.cached_forward(
-                params, tokens[:, None].astype(jnp.int32),
-                lengths[:, None].astype(jnp.int32), arrays, lengths,
-                cfg, tp, block_tables=tables, block_size=bs)
-            nxt = sample_tokens(logits[:, 0], keys, steps, temp,
-                                top_k, top_p)
-            if self.return_logprobs:
-                return (*new_arrays, nxt,
-                        token_logprobs(logits[:, 0], nxt))
-            return (*new_arrays, nxt)
-
-        return self._tp_wrap(decode, 8)
-
-    def _make_prefill_fn(self, bucket):
-        cfg, tp, nc = self.cfg, self.tp, self._nc
-        if not self.paged:
-            def prefill(params, k_stack, v_stack, tokens, length, slot,
-                        key, temp, top_k, top_p):
-                pos = jnp.arange(bucket, dtype=jnp.int32)[None]
-                logits, kvs = tp_model.prefill_forward(
-                    params, tokens, pos, cfg, tp)
-                for li, (k, v) in enumerate(kvs):
-                    idx = (li, slot, 0, 0, 0)
-                    k_stack = jax.lax.dynamic_update_slice(
-                        k_stack, k.astype(k_stack.dtype)[None], idx)
-                    v_stack = jax.lax.dynamic_update_slice(
-                        v_stack, v.astype(v_stack.dtype)[None], idx)
-                last = jax.lax.dynamic_index_in_dim(
-                    logits[0], length - 1, axis=0)
-                tok0 = sample_tokens(last, key[None],
-                                     jnp.zeros((1,), jnp.int32),
-                                     temp[None], top_k[None],
-                                     top_p[None])[0]
-                if self.return_logprobs:
-                    return (k_stack, v_stack, tok0,
-                            token_logprobs(last, tok0[None])[0])
-                return k_stack, v_stack, tok0
-
-            return self._tp_wrap(prefill, 7)
-
-        from ..ops.pallas.paged_attention import quantize_kv
-
-        bs = self.block_size
-        quant = self.cache.quantized
-
-        def prefill(params, *args):
-            arrays = args[:nc]
-            tokens, length, table, key, temp, top_k, top_p = args[nc:]
-            pos = jnp.arange(bucket, dtype=jnp.int32)[None]
-            logits, kvs = tp_model.prefill_forward(
-                params, tokens, pos, cfg, tp)
-            p = jnp.arange(bucket, dtype=jnp.int32)
-            logical = jnp.clip(p // bs, 0, table.shape[1] - 1)
-            bi = table[0][logical]
-            off = p % bs
-            if quant:
-                k_pool, v_pool, k_sc, v_sc = arrays
-            else:
-                k_pool, v_pool = arrays
-            for li, (k, v) in enumerate(kvs):
-                k_rows = k[0]
-                v_rows = v[0]
-                if quant:
-                    kq, ks = quantize_kv(k_rows)
-                    vq, vs = quantize_kv(v_rows)
-                    k_pool = k_pool.at[li, bi, off].set(kq)
-                    v_pool = v_pool.at[li, bi, off].set(vq)
-                    k_sc = k_sc.at[li, bi, off].set(ks)
-                    v_sc = v_sc.at[li, bi, off].set(vs)
-                else:
-                    k_pool = k_pool.at[li, bi, off].set(
-                        k_rows.astype(k_pool.dtype))
-                    v_pool = v_pool.at[li, bi, off].set(
-                        v_rows.astype(v_pool.dtype))
-            last = jax.lax.dynamic_index_in_dim(
-                logits[0], length - 1, axis=0)
-            tok0 = sample_tokens(last, key[None],
-                                 jnp.zeros((1,), jnp.int32),
-                                 temp[None], top_k[None], top_p[None])[0]
-            out = (k_pool, v_pool, k_sc, v_sc) if quant \
-                else (k_pool, v_pool)
-            if self.return_logprobs:
-                return (*out, tok0, token_logprobs(last, tok0[None])[0])
-            return (*out, tok0)
-
-        return self._tp_wrap(prefill, 7)
-
-    def _make_chunk_fn(self, width):
-        cfg, tp, nc = self.cfg, self.tp, self._nc
-        bs = self.block_size
-
-        def chunk(params, *args):
-            arrays = args[:nc]
-            (tokens, start, table, last_index, key, temp, top_k,
-             top_p) = args[nc:]
-            pos = start + jnp.arange(width, dtype=jnp.int32)[None]
-            logits, new_arrays = tp_model.cached_forward(
-                params, tokens, pos, arrays, jnp.reshape(start, (1,)),
-                cfg, tp, block_tables=table, block_size=bs)
-            last = jax.lax.dynamic_index_in_dim(
-                logits[0], last_index, axis=0)
-            tok = sample_tokens(last, key[None],
-                                jnp.zeros((1,), jnp.int32),
-                                temp[None], top_k[None], top_p[None])[0]
-            if self.return_logprobs:
-                return (*new_arrays, tok,
-                        token_logprobs(last, tok[None])[0])
-            return (*new_arrays, tok)
-
-        return self._tp_wrap(chunk, 8)
-
-    def _make_verify_fn(self):
-        cfg, tp, nc = self.cfg, self.tp, self._nc
-        bs = self.block_size
-        s_len = self.draft_len + 1
-
-        def verify(params, *args):
-            arrays = args[:nc]
-            (lengths, tok_in, keys, steps, temp, top_k, top_p,
-             tables) = args[nc:]
-            pos = (lengths[:, None]
-                   + jnp.arange(s_len, dtype=jnp.int32)[None])
-            logits, new_arrays = tp_model.cached_forward(
-                params, tok_in, pos, arrays, lengths, cfg, tp,
-                block_tables=tables, block_size=bs)
-            toks = jnp.stack(
-                [sample_tokens(logits[:, i], keys, steps + i, temp,
-                               top_k, top_p) for i in range(s_len)],
-                axis=1)
-            if self.return_logprobs:
-                lps = jnp.stack(
-                    [token_logprobs(logits[:, i], toks[:, i])
-                     for i in range(s_len)], axis=1)
-                return (*new_arrays, toks, lps)
-            return (*new_arrays, toks)
-
-        return self._tp_wrap(verify, 8)
+    def _forward_prefill(self, params, tokens, bucket):
+        pos = jnp.arange(bucket, dtype=jnp.int32)[None]
+        return tp_model.prefill_forward(params, tokens, pos, self.cfg,
+                                        self.tp)
 
     # -- comm pricing (analysis.comm) --------------------------------------
     def decode_comm_estimate(self, dtype_bytes=4):

@@ -6,7 +6,7 @@ This mirrors the single-chip lowering op for op (`models.transformer_lm`
 through `fluid/ops`): f32 LayerNorm (eps 1e-5), erf gelu, the flattened
 ``mul`` matmul for Linear, the same attention dispatch
 (`ops.attention.scaled_dot_product_attention` for prefill,
-`ops.pallas.decode_attention` / `paged_attention` for cached decode),
+`ops.pallas.paged_attention.cached_attention` for cached decode),
 and tied-embedding logits.  Each shard holds ``H/tp`` heads and
 ``I/tp`` FFN columns; per-head attention math and column-parallel
 matmuls are bit-exact per shard, and the only place the floating-point
@@ -20,8 +20,10 @@ the chunk/verify reference paths.
 
 All functions here take the LOCAL parameter shards (see
 `tp_serving.layout`: the fused qkv output axis is pre-grouped so the
-local thirds are this shard's q/k/v) and local KV cache arrays
-(``H/tp`` on the heads axis); scalars/tables/tokens arrive replicated.
+local thirds are this shard's q/k/v) and local KV cache arrays (one
+per layer, ``(H/tp) * Dh`` of the merged last dimension: a contiguous
+split of it is a split by heads); scalars/tables/tokens arrive
+replicated.
 """
 
 from __future__ import annotations
@@ -30,13 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.attention import scaled_dot_product_attention
-from ..ops.pallas.decode_attention import decode_attention
-from ..ops.pallas.paged_attention import (
-    chunked_attention_reference,
-    paged_decode_attention,
-    paged_gather_kv,
-    quantize_kv,
-)
+from ..ops.pallas.paged_attention import cached_attention
 
 __all__ = ["cached_forward", "prefill_forward"]
 
@@ -99,69 +95,14 @@ def _attn_prefill(p, li, x, h_loc, d_head):
 
 
 def _attn_cached(p, li, x, cache, h_loc, d_head):
-    """`models.bert.MultiHeadAttention._decode_with_cache` ported to
-    local head shards: write the C new rows, attend row i over
-    positions ``<= pos+i``.  Cache tuple forms are the model's (dense /
-    paged / paged-int8), with all arrays carrying ``h_loc`` heads."""
+    """`models.bert.MultiHeadAttention._decode_with_cache` on local
+    head shards: the same `cached_attention` (write the C new rows,
+    attend row i over positions ``<= pos+i``), its cache arrays
+    carrying ``h_loc * d_head`` of the merged dimension."""
     q, k, v = _qkv_split(p, li, x, h_loc, d_head)
-    scale = d_head ** -0.5
-    c_len = q.shape[1]
-    if len(cache) == 3:                              # dense
-        k_cache, v_cache, pos = cache
-        pos = jnp.asarray(pos).astype(jnp.int32)
-
-        def write_rows(cbuf, new, s):
-            return jax.lax.dynamic_update_slice(cbuf, new, (s, 0, 0))
-
-        k_cache = jax.vmap(write_rows)(jnp.asarray(k_cache), k, pos)
-        v_cache = jax.vmap(write_rows)(jnp.asarray(v_cache), v, pos)
-        if c_len == 1:
-            ctx = decode_attention(q[:, 0], k_cache, v_cache, pos + 1,
-                                   scale=scale)[:, None]
-        else:
-            ctx = chunked_attention_reference(q, k_cache, v_cache, pos,
-                                              scale=scale)
-        new_cache = (k_cache, v_cache)
-    else:                                            # paged / paged int8
-        if len(cache) == 5:
-            k_pool, v_pool, pos, tables, bs = cache
-            k_scale = v_scale = None
-        else:
-            k_pool, v_pool, k_scale, v_scale, pos, tables, bs = cache
-        bs = int(bs)
-        pos = jnp.asarray(pos).astype(jnp.int32)
-        tables = jnp.asarray(tables).astype(jnp.int32)
-        nb = int(tables.shape[1])
-        pp = pos[:, None] + jnp.arange(c_len, dtype=jnp.int32)[None]
-        logical = jnp.clip(pp // bs, 0, nb - 1)
-        bi = jnp.take_along_axis(tables, logical, axis=1).ravel()
-        off = (pp % bs).ravel()
-        k_pool = jnp.asarray(k_pool)
-        v_pool = jnp.asarray(v_pool)
-        k_rows = k.reshape(-1, h_loc, d_head)
-        v_rows = v.reshape(-1, h_loc, d_head)
-        if k_scale is not None:
-            k_q, k_s = quantize_kv(k_rows)
-            v_q, v_s = quantize_kv(v_rows)
-            k_pool = k_pool.at[bi, off].set(k_q)
-            v_pool = v_pool.at[bi, off].set(v_q)
-            k_scale = jnp.asarray(k_scale).at[bi, off].set(k_s)
-            v_scale = jnp.asarray(v_scale).at[bi, off].set(v_s)
-        else:
-            k_pool = k_pool.at[bi, off].set(k_rows.astype(k_pool.dtype))
-            v_pool = v_pool.at[bi, off].set(v_rows.astype(v_pool.dtype))
-        if c_len == 1:
-            ctx = paged_decode_attention(
-                q[:, 0], k_pool, v_pool, tables, pos + 1, scale=scale,
-                k_scale=k_scale, v_scale=v_scale)[:, None]
-        else:
-            k_dense = paged_gather_kv(k_pool, tables, k_scale)
-            v_dense = paged_gather_kv(v_pool, tables, v_scale)
-            ctx = chunked_attention_reference(q, k_dense, v_dense, pos,
-                                              scale=scale)
-        new_cache = ((k_pool, v_pool) if k_scale is None
-                     else (k_pool, v_pool, k_scale, v_scale))
-    b = ctx.shape[0]
+    ctx, new_cache = cached_attention(q, k, v, cache,
+                                      scale=d_head ** -0.5)
+    b, c_len = ctx.shape[0], ctx.shape[1]
     pre = "blocks.%d.attn." % li
     part = _linear(ctx.reshape(b, c_len, h_loc * d_head),
                    p[pre + "out_proj.weight"])
@@ -216,22 +157,18 @@ def prefill_forward(p, ids, pos_ids, cfg, tp):
 
 def cached_forward(p, ids, pos_ids, caches, cache_positions, cfg, tp,
                    block_tables=None, block_size=None):
-    """Decode/chunk/verify forward over stacked LOCAL cache arrays
-    (`TransformerLM.forward(caches=...)` contract): S tokens per row
-    written at ``cache_positions..+S-1``; returns ``(logits, updated
-    stacks)``."""
+    """Decode/chunk/verify forward over the per-layer LOCAL cache
+    arrays (`TransformerLM.forward(caches=...)` contract: one tuple of
+    arrays per layer): S tokens per row written at
+    ``cache_positions..+S-1``, each layer into its own arrays; returns
+    ``(logits, updated per-layer tuples)``."""
     h_loc = cfg.num_heads // tp
-    stacks = [jnp.asarray(c) for c in caches]
-    out_rows = [[] for _ in stacks]
+    tail = ((cache_positions,) if block_tables is None
+            else (cache_positions, block_tables, block_size))
+    out = []
     h = _embed(p, ids, pos_ids)
-    for li in range(cfg.num_layers):
-        per_layer = tuple(s[li] for s in stacks)
-        if block_tables is None:
-            cache = per_layer + (cache_positions,)
-        else:
-            cache = per_layer + (cache_positions, block_tables,
-                                 block_size)
-        h, updated = _block(p, li, h, cache, False, h_loc, cfg.head_dim)
-        for rows, arr in zip(out_rows, updated):
-            rows.append(arr)
-    return _finalize(p, h), tuple(jnp.stack(rows) for rows in out_rows)
+    for li, mine in enumerate(caches):
+        h, updated = _block(p, li, h, tuple(mine) + tail, False, h_loc,
+                            cfg.head_dim)
+        out.append(tuple(updated))
+    return _finalize(p, h), out

@@ -22,6 +22,7 @@ import pytest
 import paddle_tpu
 from paddle_tpu import models
 from paddle_tpu.fluid import dygraph
+from paddle_tpu.generation import kv_cache
 
 gen = paddle_tpu.generation
 
@@ -235,6 +236,10 @@ class TestTransformerLM:
         import jax.numpy as jnp
 
         from paddle_tpu.fluid import framework
+        from paddle_tpu.generation.kv_cache import (
+            flatten_layers,
+            group_layers,
+        )
 
         rng = np.random.RandomState(0)
         B, S, T = 2, 8, 16
@@ -250,16 +255,25 @@ class TestTransformerLM:
             _, kvs = lm(dygraph.to_variable(ids[:, :S - 1]),
                         dygraph.to_variable(pos[:, :S - 1]),
                         use_cache=True)
-            k_stack = np.zeros((L, B, T, H, Dh), np.float32)
-            v_stack = np.zeros((L, B, T, H, Dh), np.float32)
+            # the cache's form: one [B, T, H*Dh] array per layer, K's
+            # over the layers and then V's (`KVCache.arrays`)
+            cache = gen.KVCache(L, B, T, H, Dh)
+            arrays = [np.zeros(cache.layer_shape, np.float32)
+                      for _ in range(2 * L)]
             for li, (k, v) in enumerate(kvs):
-                k_stack[li, :, :S - 1] = np.asarray(k)
-                v_stack[li, :, :S - 1] = np.asarray(v)
-            logits, (k2, v2) = lm(
+                arrays[li][:, :S - 1] = np.asarray(k).reshape(B, S - 1, -1)
+                arrays[L + li][:, :S - 1] = np.asarray(v).reshape(
+                    B, S - 1, -1)
+            # the forward takes each layer's arrays as one tuple
+            layers = group_layers([jnp.asarray(a) for a in arrays], L)
+            assert [len(mine) for mine in layers] == [2] * L
+            logits, out = lm(
                 dygraph.to_variable(ids[:, S - 1:S]),
                 dygraph.to_variable(np.full((B, 1), S - 1, np.int64)),
-                caches=(jnp.asarray(k_stack), jnp.asarray(v_stack)),
+                caches=layers,
                 cache_positions=jnp.asarray([S - 1] * B))
+            assert [len(mine) for mine in out] == [2] * L
+            out = flatten_layers(out)
         # the cached path IS the full math at the last row, but not its
         # summation order: the decode step contracts a [1, D] query row
         # where the full forward contracts [S, D], and the CPU backend
@@ -267,8 +281,16 @@ class TestTransformerLM:
         # pin is a float32 rounding bound, not bit equality
         np.testing.assert_allclose(logits.numpy()[:, 0], full[:, -1],
                                    rtol=0, atol=1e-6)
-        # and the step wrote this token's K/V at position S-1
-        assert np.any(np.asarray(k2)[0, :, S - 1] != 0)
+        # and the step wrote this token's K/V at position S-1 of every
+        # layer's own arrays, and nothing else
+        assert len(out) == 2 * L
+        for before, after in zip(arrays, out):
+            after = np.asarray(after)
+            assert after.shape == cache.layer_shape
+            assert np.all(after[:, S - 1] != 0)
+            np.testing.assert_array_equal(after[:, :S - 1],
+                                          before[:, :S - 1])
+            assert not after[:, S:].any()
 
 
 # ---------------------------------------------------------------------------
@@ -389,10 +411,15 @@ class TestEngine:
 def test_kv_cache_shape_and_bytes():
     c = gen.KVCache(num_layers=2, slots=3, max_len=64, num_heads=4,
                     head_dim=8)
-    assert c.shape == (2, 3, 64, 4, 8)
+    # one array per layer for K and for V, heads merged into the last
+    # dimension
+    assert c.layer_shape == (3, 64, 32)
+    assert len(c.arrays()) == 2 * 2
+    assert all(a.shape == c.layer_shape for a in c.arrays())
     assert c.nbytes == 2 * 2 * 3 * 64 * 4 * 8 * 4
     d = c.describe()
-    assert d["bytes"] == c.nbytes and d["dtype"] == "float32"
+    assert d["bytes"] == c.nbytes == sum(a.nbytes for a in c.arrays())
+    assert d["dtype"] == "float32"
 
 
 def test_decode_step_cost_units():
@@ -676,6 +703,116 @@ class TestPagedKernels:
                 np.testing.assert_allclose(out[i, ci], ref, rtol=1e-5,
                                            atol=1e-5)
 
+    @pytest.mark.parametrize("c,h", [(1, 4), (3, 4), (40, 4)],
+                             ids=["decode", "verify", "wide-chunk"])
+    def test_merged_attention_equals_the_split_heads_reference(self, c, h):
+        """What the cached forward runs over a cache held with its heads
+        merged (block-diagonal queries while C*H fits the MXU's 128
+        rows, the view's heads split beyond) is the split-heads
+        reference's math, dead rows and empty slots included."""
+        import jax.numpy as jnp
+
+        from paddle_tpu.ops.pallas.paged_attention import (
+            chunked_attention_reference,
+            merged_attention,
+        )
+
+        rng = np.random.RandomState(5)
+        n, t, d = 3, 48, 8
+        q = rng.randn(n, c, h, d).astype(np.float32)
+        k = rng.randn(n, t, h, d).astype(np.float32)
+        v = rng.randn(n, t, h, d).astype(np.float32)
+        start = jnp.asarray([5, -1, t - c], jnp.int32)   # slot 1: empty
+        want = np.asarray(chunked_attention_reference(
+            jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), start))
+        got = np.asarray(merged_attention(
+            jnp.asarray(q), jnp.asarray(k.reshape(n, t, h * d)),
+            jnp.asarray(v.reshape(n, t, h * d)), start))
+        assert got.shape == (n, c, h, d)
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+        assert not got[1, 0].any()                       # nothing live
+
+    @pytest.mark.parametrize("quantized", [False, True],
+                             ids=["float32", "int8"])
+    def test_kv_write_scatters_rows_into_each_array(self, quantized):
+        """The one cache write: row r lands at [i0[r], i1[r]] of every
+        array of the layer (heads merged; int8 rows quantized on the
+        way in, with their scales), and nothing else moves."""
+        import jax.numpy as jnp
+
+        from paddle_tpu.ops.pallas.paged_attention import (
+            dequantize_kv,
+            kv_write,
+        )
+
+        rng = np.random.RandomState(6)
+        a, b, h, d, r = 5, 4, 2, 8, 3
+        k_rows = rng.randn(r, h, d).astype(np.float32)
+        v_rows = rng.randn(r, h, d).astype(np.float32)
+        i0 = jnp.asarray([4, 0, 2], jnp.int32)
+        i1 = jnp.asarray([1, 3, 0], jnp.int32)
+        store = jnp.int8 if quantized else jnp.float32
+        arrays = (jnp.zeros((a, b, h * d), store),
+                  jnp.zeros((a, b, h * d), store))
+        if quantized:
+            arrays += (jnp.zeros((a, b, h), jnp.float32),) * 2
+        out = kv_write(arrays, i0, i1, jnp.asarray(k_rows),
+                       jnp.asarray(v_rows))
+        assert [(o.shape, o.dtype) for o in out] == [
+            (x.shape, x.dtype) for x in arrays]
+        for j, rows in enumerate((k_rows, v_rows)):
+            got = np.array(out[j])
+            if quantized:
+                got = np.array(dequantize_kv(
+                    jnp.asarray(got).reshape(a, b, h, d), out[2 + j]))
+                got = got.reshape(a, b, h * d)
+            written = got[np.asarray(i0), np.asarray(i1)]
+            np.testing.assert_allclose(
+                written, rows.reshape(r, h * d),
+                atol=0.02 if quantized else 0)
+            got[np.asarray(i0), np.asarray(i1)] = 0
+            assert not got.any()
+
+    def test_cached_attention_dense_and_paged_agree(self):
+        """One layer's write-then-attend through a dense cache and
+        through a permuted block pool: the same context, each new row
+        in its own place, for one token a slot and for three."""
+        import jax.numpy as jnp
+
+        from paddle_tpu.ops.pallas.paged_attention import (
+            cached_attention,
+            paged_gather_kv,
+        )
+
+        rng = np.random.RandomState(7)
+        n, t, h, d, bs = 3, 32, 4, 8, 8
+        k = rng.randn(n, t, h, d).astype(np.float32)
+        v = rng.randn(n, t, h, d).astype(np.float32)
+        k_pool, v_pool, tables = self._pool_from_dense(k, v, bs)
+        merged = lambda x: jnp.asarray(x.reshape(x.shape[:2] + (h * d,)))
+        pos = jnp.asarray([4, 0, 20], jnp.int32)
+        for c in (1, 3):
+            q, k_new, v_new = (jnp.asarray(
+                rng.randn(n, c, h, d).astype(np.float32)) for _ in range(3))
+            dense, (dk, dv) = cached_attention(
+                q, k_new, v_new, (merged(k), merged(v), pos))
+            paged, (pk, pv) = cached_attention(
+                q, k_new, v_new, (merged(k_pool), merged(v_pool), pos,
+                                  jnp.asarray(tables), bs))
+            np.testing.assert_allclose(np.asarray(paged),
+                                       np.asarray(dense),
+                                       rtol=1e-5, atol=1e-6)
+            assert dk.shape == (n, t, h * d)
+            assert pk.shape == merged(k_pool).shape
+            for i in range(n):
+                rows = slice(int(pos[i]), int(pos[i]) + c)
+                np.testing.assert_array_equal(
+                    np.asarray(dk)[i, rows],
+                    np.asarray(k_new)[i].reshape(c, h * d))
+            np.testing.assert_array_equal(
+                np.asarray(paged_gather_kv(pv, jnp.asarray(tables))),
+                np.asarray(dv))
+
     def test_int8_roundtrip_and_zero_rows(self):
         import jax.numpy as jnp
 
@@ -797,8 +934,12 @@ class TestPrefixCache:
 def test_paged_kv_cache_shapes_bytes_and_tables():
     c = gen.PagedKVCache(num_layers=2, num_blocks=9, block_size=16,
                          num_heads=4, head_dim=8, slots=3, max_len=64)
-    assert c.shape == (2, 9, 16, 4, 8)
-    assert len(c.arrays()) == 2
+    # 2 * L arrays (K's over the layers, then V's), each one layer's
+    # pool with the heads merged into the last dimension
+    assert c.layer_shape == (9, 16, 32)
+    assert len(c.arrays()) == 2 * 2
+    assert all(a.shape == c.layer_shape and a.dtype == np.float32
+               for a in c.arrays())
     assert c.nbytes == 2 * 2 * 9 * 16 * 4 * 8 * 4
     assert c.capacity_tokens == 8 * 16
     assert c.blocks_for(17) == 2
@@ -811,15 +952,51 @@ def test_paged_kv_cache_shapes_bytes_and_tables():
     d = c.describe()
     assert d["paged"] is True and d["kv_dtype"] == "float32"
     assert d["blocks_used"] == 2
+    assert d["bytes"] == sum(a.nbytes for a in c.arrays())
+    assert (d["block_size"], d["num_blocks"]) == (16, 9)
 
     i8 = gen.PagedKVCache(num_layers=2, num_blocks=9, block_size=16,
                           num_heads=4, head_dim=8, slots=3, max_len=64,
                           kv_dtype="int8")
-    assert len(i8.arrays()) == 4           # + per-head scale stacks
+    assert len(i8.arrays()) == 4 * 2       # + per-head scales per layer
+    assert [a.shape for a in i8.arrays()] == (
+        [(9, 16, 32)] * 4 + [(9, 16, 4)] * 4)
+    assert [str(a.dtype) for a in i8.arrays()] == (
+        ["int8"] * 4 + ["float32"] * 4)
     assert i8.nbytes == (2 * 2 * 9 * 16 * 4 * 8 * 1
                          + 2 * 2 * 9 * 16 * 4 * 4)
     assert i8.nbytes < c.nbytes
     assert i8.describe()["kv_dtype"] == "int8"
+    assert i8.describe()["bytes"] == sum(a.nbytes for a in i8.arrays())
+
+
+@pytest.mark.parametrize("kind", ["dense", "paged", "paged-int8"])
+def test_cache_update_of_its_own_arrays_is_the_identity(kind):
+    """`update(*arrays())` is the inverse of `arrays()`: same objects,
+    same order; a call with another count is refused."""
+    if kind == "dense":
+        c = gen.KVCache(num_layers=3, slots=2, max_len=32, num_heads=4,
+                        head_dim=8)
+    else:
+        c = gen.PagedKVCache(
+            num_layers=3, num_blocks=5, block_size=8, num_heads=4,
+            head_dim=8, slots=2, max_len=32,
+            kv_dtype="int8" if kind == "paged-int8" else None)
+    before = c.arrays()
+    assert len(before) == (4 if kind == "paged-int8" else 2) * 3
+    c.update(*before)
+    assert len(c.arrays()) == len(before)
+    assert all(a is b for a, b in zip(c.arrays(), before))
+    assert c.describe()["bytes"] == sum(a.nbytes for a in before)
+    # a cached forward takes the arrays one tuple per layer (K, V, then
+    # an int8 pool's two scales); flattening that gives the order back
+    layers = kv_cache.group_layers(before, c.num_layers)
+    assert len(layers) == 3
+    assert all(mine == before[li::3] for li, mine in enumerate(layers))
+    flat = kv_cache.flatten_layers(layers)
+    assert all(a is b for a, b in zip(flat, before))
+    with pytest.raises(ValueError):
+        c.update(*before[:2])
 
 
 # ---------------------------------------------------------------------------

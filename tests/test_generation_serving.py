@@ -288,6 +288,14 @@ class TestHttpFront:
             assert rep["kv_cache"]["blocks_free"] >= 0
             assert "blocks_used" in rep["kv_cache"]
             assert rep["preempted"] >= 0
+        # the report's bytes are what the per-layer pool arrays hold
+        fleet = front[0]
+        for rep, replica in zip(stats["replicas"], fleet.replicas):
+            cache = replica.engine.cache
+            assert len(cache.arrays()) == 2 * cache.num_layers
+            assert rep["kv_cache"]["bytes"] == sum(
+                a.nbytes for a in cache.arrays())
+            assert rep["kv_cache"]["num_blocks"] == cache.layer_shape[0]
         conn.close()
 
 

@@ -159,6 +159,35 @@ class TestTensorParallel:
         # .lower() for the check must not have grown the jit cache
         assert tp2.stats()["executables"]["decode_step"] == 1
 
+    def test_cache_arrays_shard_their_merged_last_dimension(self, lm, tp2):
+        """One array per layer for K and for V, each committed to the
+        mesh split over its merged ``H*Dh`` dimension (a contiguous
+        split of it is a split by heads), before and after traffic;
+        an int8 pool's scales split over their ``H`` the same way."""
+        from jax.sharding import PartitionSpec as P
+
+        L, hd = CFG.num_layers, CFG.num_heads * CFG.head_dim
+
+        def check(eng, kinds):
+            arrays = eng.cache.arrays()
+            assert len(arrays) == eng._nc == kinds * L
+            assert eng._cache_specs() == (P(None, None, "tp"),) * eng._nc
+            for a in arrays[:2 * L]:
+                assert a.shape == (eng.cache.num_blocks, 16, hd)
+            for a in arrays[2 * L:]:
+                assert a.shape == (eng.cache.num_blocks, 16, CFG.num_heads)
+            for a in arrays:
+                assert tuple(a.sharding.spec) == (None, None, "tp")
+                shard = a.addressable_shards[0].data
+                assert shard.shape == a.shape[:2] + (a.shape[2] // eng.tp,)
+            assert eng.cache.describe()["bytes"] == sum(
+                a.nbytes for a in arrays)
+
+        check(tp2, 2)
+        run_all(tp2, mixed_requests(2))
+        check(tp2, 2)
+        check(make_engine(lm, tp=2, kv_dtype="int8"), 4)
+
     def test_stats_surface_tp_block(self, tp2):
         t = tp2.stats()["tp"]
         assert t["degree"] == 2
@@ -292,9 +321,57 @@ class TestDisaggregation:
         pair.run_until_idle()
         assert len(h.result(timeout=30.0)) == 2
 
+    def test_handoff_round_trip_is_exact(self, lm, pair):
+        """The wire form is the pool's own: one ``[n_blocks, bs, H*Dh]``
+        page array per pool array.  What one engine extracts, a second
+        adopts into OTHER blocks and hands on again to the byte, and the
+        request decodes from it to the single-engine stream."""
+        prompt = list(range(3, 23))                 # 20 tokens: 2 blocks
+        want = run_all(make_engine(lm, prefill_buckets=[8, 16, 32]), [
+            gen.GenerationRequest(prompt, max_new_tokens=5)])[0]
+        first = make_engine(lm, prefill_buckets=[8, 16, 32])
+        second = make_engine(lm, prefill_buckets=[8, 16, 32], slots=2,
+                             kv_blocks=9)
+        handoff = first.prefill_extract(
+            gen.GenerationRequest(prompt, max_new_tokens=5))
+        L, hd = CFG.num_layers, CFG.num_heads * CFG.head_dim
+        assert len(handoff.pages) == 2 * L == len(first.cache.arrays())
+        assert all(p.shape == (2, 16, hd) and p.dtype == np.float32
+                   for p in handoff.pages)
+        assert handoff.describe()["blocks"] == 2
+        assert handoff.nbytes == 2 * L * 2 * 16 * hd * 4
+        # the prompt's rows are there (the last block's tail holds the
+        # padded positions' rows, which nothing reads)
+        assert all(np.abs(p.reshape(32, hd)[:20]).sum(-1).all()
+                   for p in handoff.pages)
+        # occupy the second pool's lowest blocks so the pages land elsewhere
+        taken = second.cache.pool.alloc(3)
+        h = second.inject_prefilled(handoff)
+        second.step()                               # adopts the pages
+        ids = np.asarray(second._slot_blocks[
+            [i for i, st in enumerate(second._slot_state) if st][0]])
+        assert not set(ids.tolist()) & set(taken)
+        for page, a in zip(handoff.pages, second.cache.arrays()):
+            np.testing.assert_array_equal(
+                np.asarray(a)[ids].reshape(32, hd)[:20],
+                page.reshape(32, hd)[:20])
+        second.run_until_idle()
+        assert h.result(timeout=30.0) == want
+        second.cache.pool.decref(taken)
+
     def test_geometry_validation(self, lm, pair):
         req = gen.GenerationRequest([1, 2, 3], max_new_tokens=2)
         handoff = pair.prefill.prefill_extract(req)
+        # one page array per pool array, each a pool array's geometry
+        assert len(handoff.pages) == len(pair.decode.cache.arrays())
+        assert all(p.shape[1:] == a.shape[1:] for p, a in zip(
+            handoff.pages, pair.decode.cache.arrays()))
+        short = tps.KVHandoff(
+            handoff.request, handoff.n_prompt, handoff.tok0, handoff.lp0,
+            handoff.key, handoff.pages[:2], handoff.block_size,
+            handoff.kv_dtype)
+        with pytest.raises(ValueError, match="geometry"):
+            pair.decode.inject_prefilled(short)
         dense = gen.GenerationEngine(lm, slots=2, max_len=64,
                                      prefill_buckets=[8], max_queue=8,
                                      paged=False)

@@ -189,3 +189,106 @@ def test_exact_gelu_is_routed_to_xla_not_to_a_compile_error(
                            *GEMM)
     assert CUSTOM_CALL not in hlo
     assert dispatch.choices()[key] > before
+
+
+# GPT-2-medium's cache widths (the serving cell's): 16 heads of 64, 16
+# slots of 1024 positions, blocks of 16; the depth and the vocabulary are
+# cut, since only the cache handling is under test
+CACHE_LAYERS = 2
+
+
+@pytest.fixture(scope="module")
+def cache_width_lm():
+    from paddle_tpu.fluid import dygraph
+    from paddle_tpu.models.transformer_lm import (
+        TransformerLM,
+        TransformerLMConfig,
+    )
+
+    cfg = TransformerLMConfig(
+        vocab_size=512, hidden_size=1024, num_layers=CACHE_LAYERS,
+        num_heads=16, intermediate_size=1024,
+        max_position_embeddings=1024, dropout=0.0)
+    with dygraph.guard():
+        return TransformerLM(cfg)
+
+
+def _pool_sized_writers(hlo, pool_shape, stacked_shape):
+    """Lines of the optimized HLO where a `copy`, a `concatenate` or a
+    `dynamic-update-slice` (alone or as a fusion's name) produces an
+    array of a whole per-layer pool's shape, or of the stack of them."""
+    import re
+
+    shapes = ["f32[%s]" % ",".join(map(str, s))
+              for s in (pool_shape, stacked_shape)]
+    op = re.compile(r"= (\S+?)(?:\{[^}]*\})? (copy|concatenate|"
+                    r"dynamic-update-slice)\(")
+    named = re.compile(r"%\S*(copy|concatenate|dynamic-update-slice)\S*"
+                       r" = (\S+?)(?:\{[^}]*\})? fusion\(")
+    bad = []
+    for line in hlo.splitlines():
+        m, f = op.search(line), named.search(line)
+        shape = m.group(1) if m else f.group(2) if f else None
+        if shape in shapes:
+            bad.append(line.strip()[:160])
+    return bad
+
+
+@pytest.mark.parametrize("paged", [True, False], ids=["paged", "dense"])
+def test_cached_steps_write_the_kv_cache_in_place(
+        cache_width_lm, one_chip, no_persistent_cache, paged):
+    """The decode step and a prefill, compiled for the described chip,
+    hold the KV cache as donated per-layer arrays that they write in
+    place: the outputs alias the whole cache, the temporaries stay under
+    half of it (the parent's stacked `[L, NB, bs, H, D]` pool needed
+    three times its size: each layer's pool sliced out, transposed to a
+    layout a scatter can work in, written, transposed back and stacked),
+    and nothing copies, concatenates or rewrites a pool-sized array.
+
+    These are the programs the chip runs: a merged cache, dense or
+    paged at any block size, is attended over as it lies
+    (`merged_attention`), and a 128-token prefill takes the naive
+    attention on the chip too."""
+    import numpy as np
+
+    from paddle_tpu import generation
+
+    engine = generation.GenerationEngine(
+        cache_width_lm, slots=16, max_len=1024, block_size=16,
+        paged=paged, donate=True, logprobs=True)
+    arrays = engine.cache.arrays()
+    assert len(arrays) == engine._nc == 2 * CACHE_LAYERS
+    pool_shape = arrays[0].shape
+    assert pool_shape == ((16 * 64 + 1, 16, 1024) if paged
+                          else (16, 1024, 1024))
+    cache_bytes = engine.cache.describe()["bytes"]
+    assert cache_bytes == sum(a.nbytes for a in arrays)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                           sharding=one_chip), tree)
+
+    bucket = 128
+    tables = (engine._decode_tables(),) if paged else ()
+    where = (engine.cache.table_row(0)[None].astype(np.int32) if paged
+             else np.int32(0))
+    programs = {
+        "decode": (engine._decode_step_fn, (
+            engine._params, *arrays, engine._lengths, engine._last_tokens,
+            engine._keys, engine._steps, engine._temp, engine._top_k,
+            engine._top_p, *tables)),
+        "prefill": (engine._prefill_fns[bucket], (
+            engine._params, *arrays, np.zeros((1, bucket), np.int32),
+            np.int32(bucket), where, np.zeros(2, np.uint32),
+            np.float32(0.0), np.int32(0), np.float32(1.0))),
+    }
+    for name, (fn, operands) in programs.items():
+        compiled = fn.lower(*on_chip(operands)).compile()
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= cache_bytes, name
+        assert mem.temp_size_in_bytes < cache_bytes // 2, (
+            name, mem.temp_size_in_bytes, cache_bytes)
+        bad = _pool_sized_writers(
+            compiled.as_text(), pool_shape, (CACHE_LAYERS,) + pool_shape)
+        assert not bad, (name, bad)

@@ -789,7 +789,9 @@ def test_server_autotune_incumbent_ladder_competes(tmp_path):
     only keep or beat the incumbent, never regress it unmeasured."""
     from paddle_tpu.inference.server import InferenceServer
 
-    runner = _RowCostRunner()
+    # 2 ms a row: a padded row has to outweigh a host whose other cores
+    # run the rest of the suite (at 0.4 ms a sleep's jitter can hide it)
+    runner = _RowCostRunner(per_row_s=2e-3)
     incumbent = [5, 8]      # hand-tuned; distinct from every enumerated
     server = InferenceServer(runner, max_batch=8,  # candidate ladder
                              batch_buckets=list(incumbent),
