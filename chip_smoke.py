@@ -519,10 +519,10 @@ def phase_serve(ctx):
              "serve: a decode step reached a kernel (custom calls %r): "
              "update this check" % (calls,))
         print("[serve] NOTE the engine holds its cache with the heads "
-              "merged ([.., H*D]) and every decode step attends over it as "
-              "it lies (merged_attention): 0 custom calls.  The decode "
-              "kernels read [.., H, D] blocks (phase_kernels runs them); "
-              "ROADMAP S1(b) gives them the merged form", flush=True)
+              "merged ([.., H*D]) and every decode step walks its live "
+              "part as it lies (cached_attention): 0 custom calls.  The "
+              "decode kernels read [.., H, D] blocks (phase_kernels runs "
+              "them); ROADMAP S1 gives them the merged form", flush=True)
 
 
 # ---------------------------------------------------------------------------

@@ -17,6 +17,10 @@ The execution model, and why the executable set stays enumerable:
   Pool arrays are donated, the table is passed as DATA, shapes never
   change — the step compiles once per engine config and
   `_decode_cache_size()` plus the PR-4 compile accumulator pin it.
+  Attention walks only the live slots, each as far as it reaches: who
+  is live is data too (the table rows `_decode_tables` zeroes; a dense
+  engine sends its active mask), and `generation_attn_walk_share`
+  says each step what part of slots x positions that was.
 * **paged KV** (the PR-17 rebuild) — the store is a block pool, one
   ``[num_blocks, block_size, H*D]`` array per layer for K and for V
   (`kv_cache` says why that shape), plus a host per-slot block table
@@ -405,6 +409,8 @@ class GenerationEngine:
     the pool (documented-tolerance opt-in); ``draft_model`` +
     ``draft_len`` enable speculative decoding."""
 
+    tp = 1      # head shards a step runs over (`tp_serving`'s engine: more)
+
     def __init__(self, model, *, slots=4, max_len=256,
                  prefill_buckets=None, max_queue=64, name="gen",
                  metrics_registry=None, step_hook=None, donate=None,
@@ -573,6 +579,12 @@ class GenerationEngine:
             "generation_queue_wait_ms",
             "Entry of submit -> the pop that admits the request (ms)",
             labelnames=lbl).labels(self._engine)
+        self._m_walk = reg.histogram(
+            "generation_attn_walk_share",
+            "Cache positions a decode/verify step's attention fetches, "
+            "over slots x positions", labelnames=lbl,
+            buckets=(0.01, 0.02, 0.05, 0.1, 0.2, 0.5, 1.0)
+        ).labels(self._engine)
         self._m_sched_host = reg.histogram(
             "generation_sched_host_ms",
             "A step() that decoded, less its time in device calls (ms)",
@@ -649,22 +661,34 @@ class GenerationEngine:
             framework._dygraph_tracer = old
 
     def _run_cached(self, model, params, ids, pos, arrays,
-                    cache_positions, tables=None):
+                    cache_positions, where):
         """``model``'s cached forward (decode / chunk / verify): ids and
         pos ``[B, S]``, the S tokens of row b written at
         ``cache_positions[b]..+S-1`` of each layer's own cache arrays.
-        Returns ``(logits [B, S, V], updated arrays)``."""
+        ``where`` says which rows are live: a paged cache's block
+        tables ``[B, max_blocks]`` (a zeroed row is a dead slot), a
+        dense cache's ``[B]`` bool.  Returns ``(logits [B, S, V],
+        updated arrays)``."""
         from ..fluid.dygraph import to_variable
 
         def run(m):
             logits, layers = m(
                 to_variable(ids), to_variable(pos),
                 caches=group_layers(arrays, len(m.blocks)),
-                cache_positions=cache_positions, block_tables=tables,
-                block_size=self.block_size)
+                cache_positions=cache_positions,
+                **self._cache_index(where))
             return logits.data, flatten_layers(layers)
 
         return self._apply_model(params, run, model=model)
+
+    def _cache_index(self, where):
+        """A cached forward's keywords for the operand that says which
+        rows are live: block tables ``[B, max_blocks]`` or, for a dense
+        cache (the draft model's is one in a paged engine), a mask
+        ``[B]``."""
+        if jnp.ndim(where) == 2:
+            return {"block_tables": where, "block_size": self.block_size}
+        return {"cache_live": where}
 
     def _run_prefill(self, model, params, tokens, bucket):
         """``model``'s full causal forward on the flash path over
@@ -683,9 +707,9 @@ class GenerationEngine:
     # the three hooks a tensor-parallel engine overrides: the served
     # model's two forwards, and what wraps a step function's body
     def _forward_cached(self, params, ids, pos, arrays, cache_positions,
-                        tables=None):
+                        where):
         return self._run_cached(self.model, params, ids, pos, arrays,
-                                cache_positions, tables)
+                                cache_positions, where)
 
     def _forward_prefill(self, params, tokens, bucket):
         return self._run_prefill(self.model, params, tokens, bucket)
@@ -699,13 +723,14 @@ class GenerationEngine:
 
         def decode(params, *args):
             arrays = args[:nc]
-            # a paged engine's last operand is the block tables
+            # the last operand says who is live: the block tables of a
+            # paged engine, the active mask of a dense one
             (lengths, tokens, keys, steps, temp, top_k, top_p,
-             *tables) = args[nc:]
+             where) = args[nc:]
             logits, new_arrays = self._forward_cached(
                 params, tokens[:, None].astype(jnp.int32),
                 lengths[:, None].astype(jnp.int32), arrays, lengths,
-                *tables)
+                where)
             nxt = sample_tokens(logits[:, 0], keys, steps, temp,
                                 top_k, top_p)
             if self.return_logprobs:
@@ -825,11 +850,11 @@ class GenerationEngine:
         """One greedy draft-model decode step over all slots (dense
         draft cache, PR-15 layout)."""
         def ddecode(params, *args):
-            *arrays, lengths, tokens = args
+            *arrays, lengths, tokens, live = args
             logits, new_arrays = self._run_cached(
                 self.draft_model, params,
                 tokens[:, None].astype(jnp.int32),
-                lengths[:, None].astype(jnp.int32), arrays, lengths)
+                lengths[:, None].astype(jnp.int32), arrays, lengths, live)
             return (*new_arrays,
                     jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32))
 
@@ -936,6 +961,23 @@ class GenerationEngine:
             self._release_blocks(slot)
         self._free.append(slot)
         st.handle._fail(msg)
+
+    def _walk_share(self, rows):
+        """`generation_attn_walk_share` of the step about to run, its
+        ``rows`` new tokens a slot: what the device's loops will walk,
+        from the same `walk_plan` (the TP engine's head shards walk the
+        same positions: the fork past `_BLOCK_DIAGONAL_ROWS` is by local
+        heads)."""
+        from ..ops.pallas.paged_attention import attention_walk_share
+
+        heads = self.cfg.num_heads // self.tp
+        positions = (self.cache.block_tables.shape[1] * self.block_size
+                     if self.paged else self.max_len)
+        share = float(attention_walk_share(
+            np.where(self._active, self._lengths + rows, 0).astype(np.int32),
+            rows * heads, positions, self.block_size))
+        self._m_walk.observe(share)
+        return share
 
     def _decode_tables(self):
         """The table operand for batched decode/verify: rows of slots
@@ -1342,7 +1384,8 @@ class GenerationEngine:
                     return
         operands = self._decode_operands()
         t0 = time.perf_counter()
-        with _DeviceCall(self, "generation.decode_dispatch"):
+        with _DeviceCall(self, "generation.decode_dispatch",
+                         args={"attn_walk_share": self._walk_share(1)}):
             with _TRACE_LOCK:
                 out = self._decode_step_fn(*operands)
         # the host waits here while the device works
@@ -1401,7 +1444,7 @@ class GenerationEngine:
                 with _TRACE_LOCK:
                     *darrays, nxt = self._draft_decode_fn(
                         self._draft_params, *self._draft_cache.arrays(),
-                        self._lengths + np.int32(i), cur)
+                        self._lengths + np.int32(i), cur, self._active)
             self._draft_cache.update(*darrays)
             with _DeviceCall(self, "generation.decode_fetch"):
                 cur = np.asarray(nxt)
@@ -1409,7 +1452,8 @@ class GenerationEngine:
         tok_in = np.concatenate(
             [self._last_tokens[:, None], drafts], axis=1).astype(np.int32)
         tables = self._decode_tables()
-        with _DeviceCall(self, "generation.decode_dispatch"):
+        with _DeviceCall(self, "generation.decode_dispatch",
+                         args={"attn_walk_share": self._walk_share(k + 1)}):
             with _TRACE_LOCK:
                 out = self._verify_fn(
                     self._params, *self.cache.arrays(), self._lengths,
@@ -1801,12 +1845,12 @@ class GenerationEngine:
         return lowered.compile().as_text()
 
     def _decode_operands(self):
-        """The decode executable's live operands; a paged engine's last
-        one is the block tables."""
+        """The decode executable's live operands; the last one says who
+        is live: a paged engine's block tables, a dense one's mask."""
         return (self._params, *self.cache.arrays(), self._lengths,
                 self._last_tokens, self._keys, self._steps, self._temp,
                 self._top_k, self._top_p,
-                *((self._decode_tables(),) if self.paged else ()))
+                self._decode_tables() if self.paged else self._active)
 
     def _decode_cache_size(self):
         """Jit-cache entries of the decode step — the compile-once pin."""
@@ -1833,6 +1877,8 @@ class GenerationEngine:
             "cache": self.cache.describe(),
             "decode_executables": self._decode_cache_size(),
             "preempted": int(self._m_preempt.value),
+            # mean over the decode/verify steps so far (None before one)
+            "attn_walk_share": self._m_walk.summary().get("mean"),
         })
         ex = {
             "decode_step": self._decode_cache_size(),

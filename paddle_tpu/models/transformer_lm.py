@@ -121,7 +121,7 @@ class TransformerLM(dygraph.Layer):
 
     def forward(self, input_ids, position_ids, caches=None,
                 cache_positions=None, use_cache=False,
-                block_tables=None, block_size=None):
+                block_tables=None, block_size=None, cache_live=None):
         """input_ids/position_ids: [B, S] int.  With ``caches`` given
         (decode/chunk: S tokens per row written at positions
         ``cache_positions..+S-1``, row i attending the cache through
@@ -136,7 +136,9 @@ class TransformerLM(dygraph.Layer):
         ``[B, max_blocks]`` and ``block_size`` are given — PAGED pools
         ``[NB, bs, H*Dh]``, optionally followed by the layer's int8
         per-row scales ``[NB, bs, H]`` (``(k_l, v_l, k_scale_l,
-        v_scale_l)``)."""
+        v_scale_l)``).  Rows that are not live attend nothing: those
+        whose table row is all zeros, or for dense caches those where
+        ``cache_live`` ``[B]`` bool, if given, is false."""
         s_len = int(input_ids.shape[1])
         emb = self.word(input_ids) + self.position(position_ids)
         # the lookup op squeezes Paddle's [B, 1] ids convention; decode
@@ -145,7 +147,7 @@ class TransformerLM(dygraph.Layer):
         h = self.dropout(emb)
         new_kv = []
         if caches is not None:
-            tail = ((cache_positions,) if block_tables is None
+            tail = ((cache_positions, cache_live) if block_tables is None
                     else (cache_positions, block_tables, block_size))
             out_caches = []
             for block, mine in zip(self.blocks, caches):

@@ -14,8 +14,18 @@ Entry points:
 * `cached_attention` — what every cached forward calls (decode, chunk,
   verify; dense and paged; one chip or a head shard): write the new
   rows into the layer's merged cache arrays (`kv_write`, the one cache
-  write, which the prefill uses too), then attend over them as they
-  lie (`merged_attention`).
+  write, which the prefill uses too), then attend over the LIVE part
+  of them as they lie (`_attend_live`): the live slots, longest first
+  in groups, each group in chunks of positions up to its longest
+  member's extent, folded into an online softmax.  Which slots are
+  live and how far each reaches is read on the device from the step's
+  own operands (positions, zeroed table rows, a dense cache's mask;
+  `walk_plan`, which the host runs too for
+  `generation_attn_walk_share`), so the loops' trip counts are data
+  and one executable serves every load; no view of slots x positions
+  is ever made.  `merged_attention` is the same math over a whole view:
+  the reference the walk is pinned against, and the path of a call too
+  wide for the block-diagonal form (a prefill chunk: one slot).
 * `paged_decode_attention` — one query token per slot against the
   slot's table-mapped blocks of a ``[NB, bs, H, D]`` pool.  On TPU
   this is a pallas kernel with the
@@ -29,8 +39,8 @@ Entry points:
   the jnp oracle is the reference both paths are pinned against.
 * `paged_gather_kv` — the dense view of a slot's blocks in the pool's
   own form (table gather, then ONE reshape of the view, never of the
-  pool), used by the gather reference, the cached forward and the int8
-  dequant fallback.
+  pool), used by the gather reference, a wide prefill chunk and the
+  int8 dequant fallback.
 * `chunked_attention_reference` — C query rows per slot over a dense
   ``[N, T, H, D]`` cache view with per-row causal limits
   ``t <= start + i`` (the chunked-prefill / speculative-verify math;
@@ -49,6 +59,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -64,6 +75,7 @@ from .decode_attention import (
 NEG_INF = -1e30
 
 __all__ = [
+    "attention_walk_share",
     "cached_attention",
     "chunked_attention_reference",
     "dequantize_kv",
@@ -73,6 +85,8 @@ __all__ = [
     "paged_decode_attention_reference",
     "paged_gather_kv",
     "quantize_kv",
+    "walk_geometry",
+    "walk_plan",
 ]
 
 
@@ -171,6 +185,23 @@ def chunked_attention_reference(q, k_cache, v_cache, start, n_real=None,
 _BLOCK_DIAGONAL_ROWS = 128
 
 
+def _spread_heads(q):
+    """q [N, C, H, D] -> the block-diagonal queries [N, C*H, H*D]: row
+    (c, h) holds ``q[c, h]`` in head h's D columns, zeros elsewhere."""
+    n, c, h, d = q.shape
+    eye = jnp.eye(h, dtype=jnp.float32)
+    return (q.astype(jnp.float32)[:, :, :, None, :]
+            * eye[None, None, :, :, None]).reshape(n, c * h, h * d)
+
+
+def _own_heads(full, c, h, d):
+    """The diagonal blocks of a block-diagonal product: full
+    [N, C*H, H*D] -> [N, C, H, D], row (c, h) keeping head h's columns."""
+    eye = jnp.eye(h, dtype=jnp.float32)
+    return jnp.sum(full.reshape(-1, c, h, h, d)
+                   * eye[None, None, :, :, None], axis=3)
+
+
 def merged_attention(q, k_view, v_view, start, scale=None):
     """`chunked_attention_reference` over cache views that keep the
     heads MERGED: q [N, C, H, D]; k/v_view [N, T, H*D] (a dense cache
@@ -210,10 +241,8 @@ def merged_attention(q, k_view, v_view, start, scale=None):
     if scale is None:
         scale = float(d) ** -0.5
     exact = jax.lax.Precision.HIGHEST
-    eye = jnp.eye(h, dtype=jnp.float32)
-    q_bd = (q.astype(jnp.float32)[:, :, :, None, :]
-            * eye[None, None, :, :, None]).reshape(n, c * h, h * d)
-    s = jnp.einsum("nrm,ntm->nrt", q_bd, k_view.astype(jnp.float32),
+    s = jnp.einsum("nrm,ntm->nrt", _spread_heads(q),
+                   k_view.astype(jnp.float32),
                    precision=exact).reshape(n, c, h, t) * scale
     pos = jnp.arange(t, dtype=jnp.int32)
     limit = start[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
@@ -226,9 +255,179 @@ def merged_attention(q, k_view, v_view, start, scale=None):
     p = p / jnp.maximum(l, 1e-30)
     full = jnp.einsum("nrt,ntm->nrm", p.reshape(n, c * h, t),
                       v_view.astype(jnp.float32), precision=exact)
-    out = jnp.sum(full.reshape(n, c, h, h, d) * eye[None, None, :, :, None],
-                  axis=3)                                # [N, C, H, D]
+    out = _own_heads(full, c, h, d)
     return jnp.where(m <= NEG_INF / 2, 0.0, out).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# the walk: attention over the LIVE part of a cache
+# ---------------------------------------------------------------------------
+
+# Slots a group and positions a chunk of the walk (`_attend_live`),
+# placed by the engine's own decode step on a v5e (16 slots of 1024 at
+# GPT-2-medium's widths, blocks of 16; ms a step back to back with 1
+# slot live at 300 tokens / 4 live at 100-700 / all 16 live at 100-700;
+# the whole-table form 11.1 / 11.4 / 12.4, a step with nobody live
+# 4.25; PERF.md section 6, PR 30):
+#   2 x 128  4.37 / 5.07 / 9.11      4 x 256  4.78 / 5.14 / 8.80
+#   4 x 128  4.66 / 5.18 / 8.40      8 x 256  6.28 / 7.45 / 9.82
+#   8 x 128  4.96 / 6.92 / 8.74      4 x 512  5.42 / 6.44 / 10.71
+#  16 x 128  7.40 / 10.58 / 11.15   16 x 512  7.53 / 10.72 / 11.37
+# An iteration costs what it fetches (5.7 us for 4 slots x 128
+# positions of K and V, 4 MB), not a launch: small groups win while
+# few slots are live, and of 2, 4 and 8 slots a group 4 is the fastest
+# with every slot live and gives up 0.3 ms to 2 with one.
+_WALK_SLOTS = 4
+_WALK_POSITIONS = 128
+
+
+def walk_geometry(slots, positions, block_size=None):
+    """``(G, L)``: the slots of a group and the positions of a chunk
+    for a cache of ``slots`` x ``positions``.  A paged cache
+    (``positions = max_blocks * block_size``) is walked in whole
+    blocks.  A dense one is cut into chunks that tile it, whole
+    (8, 128) tiles of rows each, so that ``[N, T, H*D]`` taken as
+    ``[N*T/L, L, H*D]`` is the same bytes: the longest such L within
+    `_WALK_POSITIONS`, or all T where there is none."""
+    if block_size is None:
+        chunk = next((l for l in range(min(_WALK_POSITIONS, positions), 7, -1)
+                      if positions % l == 0 and l % 8 == 0), positions)
+    else:
+        chunk = min(max(_WALK_POSITIONS // block_size, 1) * block_size,
+                    positions)
+    return min(_WALK_SLOTS, slots), chunk
+
+
+def walk_plan(extent, group, chunk, xp=jnp):
+    """Which slots the walk visits and how far: the ONE definition, run
+    on the device for the loops' trip counts (``xp=jnp``) and on the
+    host for `generation_attn_walk_share` (``xp=numpy``).
+
+    extent [N] int: positions a slot attends over, 0 for a dead one.
+    The slots are padded with dead ones to a multiple of ``group`` and
+    ordered longest first (ties by index; comparisons and sums only, so
+    both array libraries give the same order).  Returns ``(order, rank,
+    chunks)``: ``order[r]`` the slot of rank r, ``rank`` its inverse,
+    ``chunks[g]`` the chunks group g (ranks ``g*group ..``) walks, its
+    longest member's.  The groups with ``chunks > 0`` are the first
+    ``ceil(live / group)``."""
+    pad = -extent.shape[0] % group
+    if pad:
+        extent = xp.concatenate([extent, xp.zeros(pad, extent.dtype)])
+    idx = xp.arange(extent.shape[0], dtype=xp.int32)
+    ahead = (extent[None, :] > extent[:, None]) | (
+        (extent[None, :] == extent[:, None]) & (idx[None, :] < idx[:, None]))
+    rank = ahead.sum(axis=1).astype(xp.int32)
+    order = ((rank[None, :] == idx[:, None]) * idx[None, :]).sum(
+        axis=1).astype(xp.int32)
+    chunks = -(-extent[order[::group]] // chunk)
+    return order, rank, chunks.astype(xp.int32)
+
+
+def attention_walk_share(extent, rows, positions, block_size=None):
+    """Positions `cached_attention` fetches for a step over those it
+    would for the whole cache: groups x G x chunks x L over slots x
+    positions, from the host's copy of the step's operands.  ``extent``
+    (numpy) as in `walk_plan`, unclipped is fine; ``rows`` = C*H of
+    the call: past `_BLOCK_DIAGONAL_ROWS` the whole view is read, 1.0."""
+    if rows > _BLOCK_DIAGONAL_ROWS:
+        return 1.0
+    slots = extent.shape[0]
+    group, chunk = walk_geometry(slots, positions, block_size)
+    _, _, chunks = walk_plan(np.clip(extent, 0, positions), group, chunk,
+                             xp=np)
+    return float(group * chunk * chunks.sum()) / (slots * positions)
+
+
+def _attend_live(q, start, live, pools, tables, group, chunk, scale):
+    """`merged_attention` without the view: q [N, C, H, D] (C*H within
+    `_BLOCK_DIAGONAL_ROWS`); start [N] int32, row i of slot n attends
+    ``t <= start[n] + i``; live [N] bool, a dead slot attends nothing
+    and returns 0.  ``pools`` is ``(k, v)`` of ``[NB, bs, H*D]``, or
+    ``(k, v, k_scale, v_scale)`` with int8 pools and ``[NB, bs, H]``
+    scales; ``tables [N, max_blocks]`` int32 maps a slot's positions to
+    blocks.
+
+    The slots are walked in groups of ``group`` in the order of
+    `walk_plan` (longest first), the first ``ceil(live / group)``
+    groups only; a group walks chunks of ``chunk`` positions (whole
+    blocks, fetched through its rows of the table and dequantized a
+    chunk at a time) up to its longest member's extent, folding each
+    into the online-softmax state (m, l, acc) of its rows.  Both trip
+    counts are data: one executable for every load.  Where the table's
+    end clamps the last chunk, the mask leaves out what the chunk
+    before it covered.  The padding slots' table rows are zeros, like a
+    dead slot's.  The same block-diagonal queries and
+    float32-precision products as `merged_attention`; a slot whose
+    group walks past its own extent folds fully masked chunks in, which
+    change nothing (corr = 1, p = 0), so a slot's result does not
+    depend on who shares its group."""
+    n, c, h, d = q.shape
+    r, hd = c * h, h * d
+    exact = jax.lax.Precision.HIGHEST
+    q_bd = _spread_heads(q)
+    start = jnp.where(live, start, -c)
+    bs, blocks = pools[0].shape[1], tables.shape[1]
+    cb = chunk // bs
+    order, rank, chunks = walk_plan(
+        jnp.clip(start + c, 0, blocks * bs), group, chunk)
+    pad = order.shape[0] - n
+    limit = jnp.repeat(start[:, None] + jnp.arange(c, dtype=jnp.int32),
+                       h, axis=1)                           # [N, C*H]
+    # the padding slots: dead rows with nothing to attend
+    q_bd = jnp.pad(q_bd, ((0, pad), (0, 0), (0, 0)))[order]
+    limit = jnp.pad(limit, ((0, pad), (0, 0)), constant_values=-1)[order]
+    tables = jnp.pad(tables, ((0, pad), (0, 0)))[order]
+    offs = jnp.arange(chunk, dtype=jnp.int32)
+
+    k_scale, v_scale = pools[2:] if len(pools) == 4 else (None, None)
+
+    def fetch(pool, scales, ids):
+        x = pool[ids].reshape(group, chunk, hd)
+        if scales is None:
+            return x.astype(jnp.float32)
+        return dequantize_kv(
+            x.reshape(group, chunk, h, d),
+            scales[ids].reshape(group, chunk, h)).reshape(x.shape)
+
+    def walk_group(g, out):
+        at = g * group
+        rows = jax.lax.dynamic_slice(tables, (at, 0), (group, blocks))
+        q_g = jax.lax.dynamic_slice(q_bd, (at, 0, 0), (group, r, hd))
+        lim = jax.lax.dynamic_slice(limit, (at, 0), (group, r))[..., None]
+
+        def fold_chunk(j, state):
+            m, l, acc = state
+            j0 = jnp.minimum(j * cb, blocks - cb)
+            ids = jax.lax.dynamic_slice(rows, (0, j0), (group, cb))
+            k = fetch(pools[0], k_scale, ids)
+            v = fetch(pools[1], v_scale, ids)
+            s = jnp.einsum("grm,gtm->grt", q_g, k, precision=exact) * scale
+            t = j0 * bs + offs
+            valid = (t >= j * chunk) & (t <= lim)           # [G, C*H, L]
+            s = jnp.where(valid, s, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            # a fully masked chunk leaves m where it was, exp(s - m) = 1
+            # there: masked rows are zeroed by the mask, not the exponent
+            p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+            corr = jnp.exp(m - m_new)
+            l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
+            acc = acc * corr + jnp.einsum("grt,gtm->grm", p, v,
+                                          precision=exact)
+            return m_new, l, acc
+
+        _, l, acc = jax.lax.fori_loop(
+            0, chunks[g], fold_chunk,
+            (jnp.full((group, r, 1), NEG_INF, jnp.float32),
+             jnp.zeros((group, r, 1), jnp.float32),
+             jnp.zeros((group, r, hd), jnp.float32)))
+        ctx = acc / jnp.where(l == 0.0, 1.0, l)             # dead rows: 0
+        return jax.lax.dynamic_update_slice(out, ctx, (at, 0, 0))
+
+    full = jax.lax.fori_loop(
+        0, jnp.sum(chunks > 0), walk_group,
+        jnp.zeros((n + pad, r, hd), jnp.float32))[rank[:n]]
+    return _own_heads(full, c, h, d).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -368,24 +567,34 @@ def cached_attention(q, k_new, v_new, cache, scale=None):
     parallelism).  Cache tuple forms, arrays merged as
     `generation.kv_cache` holds them:
 
-    * dense  — ``(k_cache, v_cache, pos)`` with ``[B, T, H*D]`` arrays;
+    * dense  — ``(k_cache, v_cache, pos)`` with ``[B, T, H*D]`` arrays,
+      or ``(k_cache, v_cache, pos, live)`` with a ``[B]`` bool (None:
+      every slot): a slot that is not live attends nothing (a dense
+      cache has no table row to zero);
     * paged  — ``(k_pool, v_pool, pos, tables, block_size)`` with
       ``[NB, bs, H*D]`` pools and a ``[B, max_blocks]`` int32 block
-      table: writes scatter through the table, attention gathers
-      through it;
+      table: writes scatter through the table, and a slot whose table
+      row is all zeros (`GenerationEngine._decode_tables`) is not live;
     * paged int8 — ``(k_pool, v_pool, k_scale, v_scale, pos, tables,
       block_size)``: int8 pools + per-row per-head f32 scales
       ``[NB, bs, H]``, rows quantized on write and dequantized on read.
 
     Returns ``(ctx [B, C, H, D], updated cache arrays)``, the arrays in
-    the order the tuple carried them.  Attention runs over the arrays
-    as they lie (`merged_attention`), for a paged pool over the gather
-    of the slots' blocks."""
-    if len(cache) not in (3, 5, 7):
+    the order the tuple carried them.  The attend half walks the arrays
+    as they lie (`_attend_live`): live slots only, longest first in
+    groups, each group in chunks of positions up to its longest
+    member's ``pos + C``; a chunk is fetched through the table
+    (``pool[tables[slots, j*cb:(j+1)*cb]]``, dequantized if int8; a
+    dense cache is walked as the pool its chunks make, in order).  No
+    view of slots x positions exists, and a dead slot's row of ctx is
+    0.  A call wider
+    than `_BLOCK_DIAGONAL_ROWS` (a prefill chunk: one slot) keeps
+    `merged_attention` over that slot's whole view."""
+    if len(cache) not in (3, 4, 5, 7):
         raise ValueError(
-            "cache tuple must have 3 (dense), 5 (paged) or 7 "
+            "cache tuple must have 3 or 4 (dense), 5 (paged) or 7 "
             "(paged int8) entries, got %d" % len(cache))
-    dense = len(cache) == 3
+    dense = len(cache) in (3, 4)
     n_arr = 4 if len(cache) == 7 else 2
     arrays = tuple(jnp.asarray(a) for a in cache[:n_arr])
     pos = jnp.asarray(cache[n_arr]).astype(jnp.int32)
@@ -409,11 +618,6 @@ def cached_attention(q, k_new, v_new, cache, scale=None):
     arrays = kv_write(arrays, i0.ravel(), i1.ravel(),
                       k_new.reshape(b * c, h, d),
                       v_new.reshape(b * c, h, d))
-    k_view, v_view = arrays[:2]
-    if not dense:
-        k_scale, v_scale = arrays[2:] if n_arr == 4 else (None, None)
-        k_view = paged_gather_kv(k_view, tables, k_scale)
-        v_view = paged_gather_kv(v_view, tables, v_scale)
     if c == 1:
         # the decode kernels read [.., H, D] blocks: on a merged cache
         # that is a relayout of all of it every step, three times the
@@ -423,4 +627,28 @@ def cached_attention(q, k_new, v_new, cache, scale=None):
             "decode_attention" if dense else "paged_decode_attention",
             "reference" if dense else "gather reference",
             "the cache's heads are merged; the kernel reads [.., H, D]")
-    return merged_attention(q, k_view, v_view, pos, scale=scale), arrays
+    if c * h > _BLOCK_DIAGONAL_ROWS:
+        k_view, v_view = arrays[:2]
+        if not dense:
+            k_scale, v_scale = arrays[2:] if n_arr == 4 else (None, None)
+            k_view = paged_gather_kv(k_view, tables, k_scale)
+            v_view = paged_gather_kv(v_view, tables, v_scale)
+        return merged_attention(q, k_view, v_view, pos, scale=scale), arrays
+
+    if dense:
+        live = cache[3] if len(cache) == 4 else None
+        live = (jnp.ones((b,), bool) if live is None
+                else jnp.asarray(live).astype(bool))
+        positions = arrays[0].shape[1]
+        group, chunk = walk_geometry(b, positions)
+        # a dense cache is a pool whose blocks are its chunks, in order
+        # (the same bytes: `walk_geometry` cuts it in whole tiles)
+        per = positions // chunk
+        pools = tuple(a.reshape(b * per, chunk, h * d) for a in arrays)
+        tables = jnp.arange(b * per, dtype=jnp.int32).reshape(b, per)
+    else:
+        live = jnp.any(tables != 0, axis=1)
+        group, chunk = walk_geometry(b, tables.shape[1] * bs, bs)
+        pools = arrays
+    ctx = _attend_live(q, pos, live, pools, tables, group, chunk, scale)
+    return ctx, arrays

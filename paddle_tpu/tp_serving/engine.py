@@ -119,11 +119,11 @@ class TPGenerationEngine(GenerationEngine):
 
     # -- the served model's two forwards, shard-local ----------------------
     def _forward_cached(self, params, ids, pos, arrays, cache_positions,
-                        tables=None):
+                        where):
         logits, layers = tp_model.cached_forward(
             params, ids, pos, group_layers(arrays, self.cfg.num_layers),
-            cache_positions, self.cfg, self.tp, block_tables=tables,
-            block_size=self.block_size)
+            cache_positions, self.cfg, self.tp,
+            **self._cache_index(where))
         return logits, flatten_layers(layers)
 
     def _forward_prefill(self, params, tokens, bucket):

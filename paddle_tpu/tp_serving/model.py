@@ -156,14 +156,14 @@ def prefill_forward(p, ids, pos_ids, cfg, tp):
 
 
 def cached_forward(p, ids, pos_ids, caches, cache_positions, cfg, tp,
-                   block_tables=None, block_size=None):
+                   block_tables=None, block_size=None, cache_live=None):
     """Decode/chunk/verify forward over the per-layer LOCAL cache
     arrays (`TransformerLM.forward(caches=...)` contract: one tuple of
     arrays per layer): S tokens per row written at
     ``cache_positions..+S-1``, each layer into its own arrays; returns
     ``(logits, updated per-layer tuples)``."""
     h_loc = cfg.num_heads // tp
-    tail = ((cache_positions,) if block_tables is None
+    tail = ((cache_positions, cache_live) if block_tables is None
             else (cache_positions, block_tables, block_size))
     out = []
     h = _embed(p, ids, pos_ids)

@@ -224,6 +224,7 @@ HISTOGRAMS = [      # family, registry, observations the script makes
     ("generation_stream_lag_ms", "served", lambda r: sum(r["tokens"])),
     ("generation_sched_host_ms", "served", lambda r: r["decode_steps"]),
     ("generation_itl_ms", "served", lambda r: r["decode_steps"]),
+    ("generation_attn_walk_share", "served", lambda r: r["decode_steps"]),
     ("generation_prefill_ms", "served", lambda r: REQUESTS),
     ("train_step_dispatch_ms", "train", lambda r: TRAIN_STEPS),
     ("io_step_wait_ms", "train", lambda r: TRAIN_STEPS),
@@ -237,6 +238,22 @@ def test_histogram_is_observed_as_often_as_the_script_says(
     assert scripted["tokens"] == [NEW_TOKENS] * REQUESTS
     assert scripted["decode_steps"] >= NEW_TOKENS - 1
     assert count(scripted[registry], family) == expected(scripted)
+
+
+def test_decode_dispatch_spans_carry_the_walk_share(scripted):
+    """Each decode step's `generation.decode_dispatch` span says what
+    part of the cache its attention walks: the value the step gave
+    `generation_attn_walk_share`."""
+    # the served engine's loop thread (the chunk-prefill engine decodes
+    # on the test's own thread, into a registry of its own)
+    loop = {s.thread for s in scripted["spans"]
+            if s.name == "generation.idle_wait"}
+    shares = [float(s.args["attn_walk_share"]) for s in scripted["spans"]
+              if s.name == "generation.decode_dispatch" and s.thread in loop]
+    assert len(shares) == scripted["decode_steps"]
+    assert all(0.0 < x <= 1.0 for x in shares)
+    series = scripted["served"]["generation_attn_walk_share"]["series"]
+    assert sum(s["sum"] for s in series) == pytest.approx(sum(shares))
 
 
 def test_a_step_function_is_known_by_its_name():

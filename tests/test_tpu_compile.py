@@ -20,6 +20,7 @@ one).  All of these tests live in this one file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -213,14 +214,18 @@ def cache_width_lm():
         return TransformerLM(cfg)
 
 
+def _f32(shape):
+    return "f32[%s]" % ",".join(map(str, shape))
+
+
 def _pool_sized_writers(hlo, pool_shape, stacked_shape):
     """Lines of the optimized HLO where a `copy`, a `concatenate` or a
     `dynamic-update-slice` (alone or as a fusion's name) produces an
-    array of a whole per-layer pool's shape, or of the stack of them."""
+    array of a whole per-layer pool's shape, or of the stack of them:
+    anywhere in the module, the bodies of its `while` loops included."""
     import re
 
-    shapes = ["f32[%s]" % ",".join(map(str, s))
-              for s in (pool_shape, stacked_shape)]
+    shapes = [_f32(pool_shape), _f32(stacked_shape)]
     op = re.compile(r"= (\S+?)(?:\{[^}]*\})? (copy|concatenate|"
                     r"dynamic-update-slice)\(")
     named = re.compile(r"%\S*(copy|concatenate|dynamic-update-slice)\S*"
@@ -246,9 +251,15 @@ def test_cached_steps_write_the_kv_cache_in_place(
     and nothing copies, concatenates or rewrites a pool-sized array.
 
     These are the programs the chip runs: a merged cache, dense or
-    paged at any block size, is attended over as it lies
-    (`merged_attention`), and a 128-token prefill takes the naive
-    attention on the chip too."""
+    paged at any block size, is attended over as it lies, and a
+    128-token prefill takes the naive attention on the chip too.
+
+    The decode step walks the live part of the cache (`_attend_live`):
+    two nested `while` loops a layer whose trip counts are data, the
+    cache arrays passing through them as the donated operands they are.
+    No view of slots x max_len x H*D exists (the parent gathered a
+    `[1024,16,1024]` one a layer and array), and the largest array a
+    loop body makes is a chunk of a group's slots."""
     import numpy as np
 
     from paddle_tpu import generation
@@ -270,14 +281,10 @@ def test_cached_steps_write_the_kv_cache_in_place(
                                            sharding=one_chip), tree)
 
     bucket = 128
-    tables = (engine._decode_tables(),) if paged else ()
     where = (engine.cache.table_row(0)[None].astype(np.int32) if paged
              else np.int32(0))
     programs = {
-        "decode": (engine._decode_step_fn, (
-            engine._params, *arrays, engine._lengths, engine._last_tokens,
-            engine._keys, engine._steps, engine._temp, engine._top_k,
-            engine._top_p, *tables)),
+        "decode": (engine._decode_step_fn, engine._decode_operands()),
         "prefill": (engine._prefill_fns[bucket], (
             engine._params, *arrays, np.zeros((1, bucket), np.int32),
             np.int32(bucket), where, np.zeros(2, np.uint32),
@@ -289,6 +296,28 @@ def test_cached_steps_write_the_kv_cache_in_place(
         assert mem.alias_size_in_bytes >= cache_bytes, name
         assert mem.temp_size_in_bytes < cache_bytes // 2, (
             name, mem.temp_size_in_bytes, cache_bytes)
+        hlo = compiled.as_text()
         bad = _pool_sized_writers(
-            compiled.as_text(), pool_shape, (CACHE_LAYERS,) + pool_shape)
+            hlo, pool_shape, (CACHE_LAYERS,) + pool_shape)
         assert not bad, (name, bad)
+        if name != "decode":
+            continue
+        assert hlo.count(" while(") == 2 * CACHE_LAYERS
+        # slots x max_len x H*D elements: the gathered view, in either
+        # order of its first two dimensions.  A dense cache IS such an
+        # array, so there only its own in-place scatters may make one.
+        view = {_f32((1024, 16, 1024)), _f32((16, 1024, 1024))}
+        makers = re.findall(
+            r"= (f32\[[\d,]+\])(?:\{[^}]*\})? ([\w\-]+)\(", hlo)
+        whole = {op for shape, op in makers if shape in view}
+        assert whole <= (set() if paged else
+                         {"parameter", "scatter", "fusion", "bitcast",
+                          "get-tuple-element"}), whole
+        assert not paged or not any(v in hlo for v in view)
+        # a dense cache is walked as the pool its 128-position chunks
+        # make: the same bytes, never a copy of them
+        assert paged or not _pool_sized_writers(
+            hlo, (16 * 8, 128, 1024), (CACHE_LAYERS, 16 * 8, 128, 1024))
+        # and the temporaries are a few chunks, not a view (the parent's
+        # step held 25.5 MB of them at these widths and two layers)
+        assert mem.temp_size_in_bytes < 16 << 20, mem.temp_size_in_bytes
