@@ -319,21 +319,10 @@ def _run(on_tpu):
         # pre-place the batches on device (a production input pipeline
         # double-buffers transfers; an in-loop device_put would bill the
         # host-to-device copy to the step time)
-        np_batches = batches
         batches = [step.place_batch(b) for b in batches]
 
         dt, dt_worst, state = _marginal_step_time(
             step, state, batches, k_short, k_long, reps)
-
-        autotune = None
-        if "--autotune" in sys.argv:
-            try:
-                autotune = _autotune_bert_step(
-                    cfg, mesh, loss_fn, np_batches, k_short, k_long,
-                    reps, dt, on_tpu, B, S)
-            except Exception as e:  # search must never sink the bench
-                print("bench autotune failed: %r" % (e,), file=sys.stderr)
-                autotune = {"error": repr(e)[:300]}
 
     tokens_per_sec = B * S / dt
     mfu = (flops_step / dt) / peak
@@ -388,8 +377,6 @@ def _run(on_tpu):
     if mfu_measured is not None:
         out["mfu_measured"] = round(mfu_measured, 4)
         out["flops_per_step_xla"] = cost["flops"]
-    if autotune is not None:
-        out["autotune"] = autotune
     if resnet is not None:
         out["extra"] = resnet
     out["metrics_snapshot"] = _metrics_snapshot()
@@ -520,99 +507,6 @@ def _run_multichip(n):
         out["autotune"] = autotune
     print(json.dumps(out))
     return 0
-
-
-def _autotune_bert_step(cfg, mesh, loss_fn, np_batches, k_short, k_long,
-                        reps, default_dt, on_tpu, B, S):
-    """--autotune: measured search over the train step's honest knobs
-    (remat, donation, the fused single-block flash backward), each
-    variant timed under the SAME marginal-step harness as the headline
-    number.  The already-measured default step time is reused for the
-    default variant (identical harness, zero extra cost), so "tuned"
-    can never beat "default" by harness mismatch.  Winners persist in
-    the tuning cache; the platform/smoke_config fields on the output
-    line keep a CPU capture from impersonating TPU tuning numbers."""
-    import jax
-
-    from paddle_tpu import distributed as dist
-    from paddle_tpu import models, tune
-    from paddle_tpu.fluid import dygraph
-    from paddle_tpu.fluid.optimizer import AdamWOptimizer
-
-    variants = [
-        ("default", {"remat": False, "donate": True, "fused_bwd": True,
-                     "fused_ffn": False, "head_layout": "BSHD"}),
-        ("remat", {"remat": True, "donate": True, "fused_bwd": True,
-                   "fused_ffn": False, "head_layout": "BSHD"}),
-        ("no_fused_flash_bwd",
-         {"remat": False, "donate": True, "fused_bwd": False,
-          "fused_ffn": False, "head_layout": "BSHD"}),
-        # fused-epilogue FFN (matmul_bias_act, the MatmulBiasActFusePass
-        # target) vs XLA's own fusion of the unfused chain
-        ("fused_ffn", {"remat": False, "donate": True, "fused_bwd": True,
-                       "fused_ffn": True, "head_layout": "BSHD"}),
-        # the head-major layout that MATERIALIZES the [B,S,H,D]<->
-        # [B,H,S,D] transposes — the negative control for the
-        # transpose-free default (what TransposeFoldPass restores)
-        ("bhsd_head_transposes",
-         {"remat": False, "donate": True, "fused_bwd": True,
-          "fused_ffn": False, "head_layout": "BHSD"}),
-    ]
-
-    _ENV_KNOBS = (
-        ("PADDLE_TPU_FLASH_FUSED_BWD",
-         lambda p: "1" if p.get("fused_bwd", True) else "0"),
-        ("PADDLE_TPU_FUSED_FFN",
-         lambda p: "1" if p.get("fused_ffn") else "0"),
-        ("PADDLE_TPU_BERT_HEAD_LAYOUT",
-         lambda p: p.get("head_layout", "BSHD")),
-    )
-
-    def build_and_time(params):
-        if params == variants[0][1]:
-            return default_dt          # measured by the headline harness
-        prev = {k: os.environ.get(k) for k, _v in _ENV_KNOBS}
-        for k, val in _ENV_KNOBS:
-            os.environ[k] = val(params)
-        try:
-            with dygraph.guard():
-                model = models.BertForPretraining(cfg)
-                opt = AdamWOptimizer(learning_rate=1e-4, weight_decay=0.01)
-                step = dist.ShardedTrainStep(
-                    model, opt, loss_fn, mesh, zero_stage=0,
-                    donate=params.get("donate", True),
-                    remat=params.get("remat", False),
-                    amp="bf16" if on_tpu else None)
-                state = step.init()
-                for i in range(2):
-                    state, loss = step(state, np_batches[i % len(np_batches)])
-                float(loss)
-                placed = [step.place_batch(b) for b in np_batches]
-                v_dt, _w, _s = _marginal_step_time(
-                    step, state, placed, k_short, k_long, reps)
-            return v_dt
-        finally:
-            for k, old in prev.items():
-                if old is None:
-                    os.environ.pop(k, None)
-                else:
-                    os.environ[k] = old
-
-    workload = "bench.bert_step:B%d.S%d.L%d.h%d" % (
-        B, S, cfg.num_hidden_layers, cfg.hidden_size)
-    report = tune.search_step(build_and_time, variants, workload=workload,
-                              mesh=mesh)
-    print("bench autotune:\n%s" % report.format(), file=sys.stderr)
-    winner = report.winner
-    return {
-        "cache_hit": report.cache_hit,
-        "default_step_ms": round(default_dt * 1e3, 3),
-        "tuned_step_ms": (round(winner.measured_s * 1e3, 3)
-                          if winner and winner.measured_s else None),
-        "winner": winner.to_dict() if winner else None,
-        "counts": report.counts(),
-        "platform": jax.default_backend(),
-    }
 
 
 def _bench_resnet(on_tpu, peak):

@@ -410,10 +410,10 @@ class TransposeFoldPass(Pass):
 
     1. **flash-attention layout fold** — transpose([0,2,1,3]) on Q/K/V
        into a BHSD ``flash_attention`` whose output is transposed
-       straight back: the kernel already reads BSHD natively
-       (``layout`` attr), so the pass flips the attr and deletes all
-       four transposes — the model never materializes
-       [B,S,H,D]<->[B,H,S,D].
+       straight back: the op takes BSHD itself (``layout`` attr), so
+       the pass flips the attr and deletes all four transposes — the
+       op does the one relayout its head-major kernels need, beside
+       them.
     2. **adjacent pair** — transpose(p1) -> transpose(p2) with
        p1∘p2 = identity (p1's out consumed only by p2): the second
        transpose becomes an ``assign`` (XLA elides it) and the first

@@ -8,7 +8,7 @@ passes to token streams).
   table (`BlockPool` refcounted allocation, PagedAttention layout);
   the pool is provisioned to the MEAN sequence length instead of
   ``slots * max_len``, and the decode step gathers K/V through the
-  table (`ops.pallas.paged_attention`) so shapes stay static and the
+  table (`ops.cached_attention`) so shapes stay static and the
   step still compiles ONCE.  `KVCache` keeps the dense PR-15 layout as
   the A/B baseline and the speculative draft's cache;
 * `PrefixCache` — refcounted FULL-block prefix reuse keyed by a

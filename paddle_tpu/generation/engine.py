@@ -12,7 +12,7 @@ The execution model, and why the executable set stays enumerable:
   logits.  The first token is emitted immediately — the TTFT path.
 * **decode** — every scheduler iteration runs ONE jitted step over ALL
   slots: one token per slot in, attention through the block table
-  (`ops.pallas.paged_attention.cached_attention`), one sampled token
+  (`ops.cached_attention.cached_attention`), one sampled token
   per slot out.
   Pool arrays are donated, the table is passed as DATA, shapes never
   change — the step compiles once per engine config and
@@ -26,7 +26,7 @@ The execution model, and why the executable set stays enumerable:
   (`kv_cache` says why that shape), plus a host per-slot block table
   (`kv_cache.PagedKVCache`).  Every step function takes the
   ``2 * num_layers`` arrays as donated operands and writes its new
-  rows into them in place (`ops.pallas.paged_attention.kv_write`):
+  rows into them in place (`ops.cached_attention.kv_write`):
   no step slices, stacks or copies a pool.  Slots allocate blocks as they
   grow instead of reserving ``max_len`` rows up front, so the pool is
   provisioned to the MEAN sequence length; when it runs dry the engine
@@ -45,10 +45,10 @@ The execution model, and why the executable set stays enumerable:
   of in-flight requests interleave with a long prefill instead of
   stalling behind it (prefix-hit suffixes ride the same path).
 * **int8 KV** — ``kv_dtype="int8"`` stores the pool quantized with
-  per-row per-head scales, quartering decode's KV-read bytes.  Opt-in
-  under the documented-tolerance policy (`PADDLE_TPU_FLASH_ACC`
-  discipline): logits move within quantization error, so token streams
-  may differ from the f32 engine.
+  per-row per-head scales, quartering decode's KV-read bytes.  Opt-in,
+  never a default: logits move within quantization error (the bounds
+  are in tests/test_generation.py), so token streams may differ from
+  the f32 engine.
 * **speculative decoding** — with ``draft_model``/``draft_len=k``, a
   small draft LM (its own dense cache) proposes k greedy tokens and
   ONE batched verify call scores all k+1 positions; greedy slots
@@ -756,7 +756,7 @@ class GenerationEngine:
 
     def _write_prefill(self, arrays, kvs, where):
         """Every layer's prompt rows into that layer's own arrays."""
-        from ..ops.pallas.paged_attention import kv_write
+        from ..ops.cached_attention import kv_write
 
         i0, i1 = self._prefill_rows(int(kvs[0][0].shape[1]), where)
         return flatten_layers([
@@ -792,7 +792,7 @@ class GenerationEngine:
         """One prefill chunk for ONE slot: ``width`` prompt tokens
         written at ``start..start+width-1`` through the slot's table
         row, attention with per-row causal limits (the chunked-prefill
-        math in `ops.pallas.paged_attention`).  Always samples from row
+        math in `ops.cached_attention`).  Always samples from row
         ``last_index`` — the host ignores the sample on non-final
         chunks, so every chunk runs the same executable."""
         nc = self._nc
@@ -968,7 +968,7 @@ class GenerationEngine:
         from the same `walk_plan` (the TP engine's head shards walk the
         same positions: the fork past `_BLOCK_DIAGONAL_ROWS` is by local
         heads)."""
-        from ..ops.pallas.paged_attention import attention_walk_share
+        from ..ops.cached_attention import attention_walk_share
 
         heads = self.cfg.num_heads // self.tp
         positions = (self.cache.block_tables.shape[1] * self.block_size

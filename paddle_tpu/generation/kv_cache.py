@@ -6,7 +6,7 @@ dimension merged into one last dimension (`_layer_shape` says why):
 pools: then the scales the same way), the donated operands of every
 step function in the engine's argument order, and ``update(*arrays)``
 its inverse.  A step writes its new rows into each layer's own donated
-array (`ops.pallas.paged_attention.kv_write`) and that array is the
+array (`ops.cached_attention.kv_write`) and that array is the
 step's output: nothing pool-sized is sliced, stacked or copied.
 
 `KVCache` (PR 15) is the dense layout — ``[slots, max_len, H*D]`` per
@@ -31,8 +31,8 @@ tokens or 2000.
   always private and copy-on-write never arises;
 * optional int8 storage (``kv_dtype="int8"``): pools hold int8 rows
   plus per-row per-head f32 scales ``[num_blocks, block_size, H]`` — halving (vs f32: quartering) the
-  KV bytes the memory-bound step streams, under the documented-
-  tolerance opt-in policy (`PADDLE_TPU_FLASH_ACC` discipline).
+  KV bytes the memory-bound step streams; opt-in, since values move
+  within the quantization error (bounds in tests/test_generation.py).
 
 Capacity math: dense charges ``slots * max_len`` rows; the pool charges
 ``num_blocks * block_size`` rows — provisioned to the MEAN sequence

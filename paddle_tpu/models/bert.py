@@ -12,36 +12,11 @@ Attention uses the fused `flash_attention` op (pallas kernel on TPU).
 from __future__ import annotations
 
 import math
-import os
 
 from ..fluid import dygraph, layers
 from ..fluid.initializer import NormalInitializer, ConstantInitializer
 from ..fluid.layer_helper import ParamAttr
 from ..fluid.layers.common import append_simple_op
-
-
-def _fused_ffn_enabled():
-    """``PADDLE_TPU_FUSED_FFN=1`` routes the FFN's fc1+gelu through the
-    fused-epilogue ``matmul_bias_act`` op instead of the
-    mul -> elementwise_add -> gelu chain — the knob `bench.py
-    --autotune` arbitrates (measure-keep-or-reject) and the eager-mode
-    twin of what `ir.MatmulBiasActFusePass` does to static programs."""
-    return os.getenv("PADDLE_TPU_FUSED_FFN") == "1"
-
-
-def _head_layout():
-    """``PADDLE_TPU_BERT_HEAD_LAYOUT=BHSD`` rebuilds attention in the
-    head-major layout, MATERIALIZING the [B,S,H,D]<->[B,H,S,D]
-    transposes the default transpose-free BSHD path avoids — the
-    negative control `bench.py --autotune` times against the default,
-    and (in static mode) the exact hazard `ir.TransposeFoldPass`
-    cancels."""
-    v = os.getenv("PADDLE_TPU_BERT_HEAD_LAYOUT", "BSHD").upper()
-    if v not in ("BSHD", "BHSD"):
-        raise ValueError(
-            "PADDLE_TPU_BERT_HEAD_LAYOUT must be BSHD or BHSD, got %r"
-            % v)
-    return v
 
 
 class BertConfig:
@@ -145,9 +120,8 @@ class MultiHeadAttention(dygraph.Layer):
         )
 
     def _split(self, x, seq_len):
-        # [B, S, D] -> [B, S, H, Dh]: the flash op consumes BSHD natively
-        # so no [B,H,S,D] head transpose is ever materialized (8 relayout
-        # passes per layer saved vs the head-major layout)
+        # [B, S, D] -> [B, S, H, Dh], the cache's layout; the flash op
+        # takes it as "BSHD" and transposes around its head-major kernels
         return layers.reshape(x, [0, seq_len, self.n_head, self.d_head])
 
     def forward(self, query, key=None, value=None, attn_bias=None,
@@ -163,7 +137,7 @@ class MultiHeadAttention(dygraph.Layer):
           token per row; its K/V are written into this layer's
           ``[B, T, H*Dh]`` cache arrays at index ``pos`` ([B] int) and
           attention runs over the cache with positions ``<= pos`` live
-          (`ops.pallas.paged_attention.cached_attention`).
+          (`ops.cached_attention.cached_attention`).
           Returns ``(out, (k_cache', v_cache'))``.  The paged forms
           are in `_decode_with_cache`.
         """
@@ -187,11 +161,6 @@ class MultiHeadAttention(dygraph.Layer):
             v = self._split(self.v_proj(value), kv_len)
         if cache is not None:
             return self._decode_with_cache(q, k, v, cache)
-        layout = _head_layout()
-        if layout == "BHSD":
-            q = layers.transpose(q, [0, 2, 1, 3])
-            k = layers.transpose(k, [0, 2, 1, 3])
-            v = layers.transpose(v, [0, 2, 1, 3])
         ins = {"Q": q, "K": k, "V": v}
         if attn_bias is not None:
             ins["Bias"] = attn_bias
@@ -214,18 +183,12 @@ class MultiHeadAttention(dygraph.Layer):
             "flash_attention",
             ins,
             {"scale": self.d_head ** -0.5, "causal": causal,
-             "layout": layout},
+             "layout": "BSHD"},
         )
-        if layout == "BHSD":
-            ctxv = layers.transpose(ctxv, [0, 2, 1, 3])
         ctxv = layers.reshape(ctxv, [0, q_len, self.n_head * self.d_head])
         out = self.dropout(self.out_proj(ctxv))
         if use_cache:
-            # BSHD is the cache-native layout; hand back arrays in it
-            # regardless of the (env-controlled) compute layout
-            if layout == "BHSD":
-                k = layers.transpose(k, [0, 2, 1, 3])
-                v = layers.transpose(v, [0, 2, 1, 3])
+            # BSHD is the cache-native layout
             return out, (k.data, v.data)
         return out
 
@@ -235,13 +198,13 @@ class MultiHeadAttention(dygraph.Layer):
         layer's own cache arrays and row i attends positions
         ``<= pos+i``.  The cache tuple forms (dense, paged, paged int8;
         arrays with heads and head dimension merged) and the math are
-        `ops.pallas.paged_attention.cached_attention`'s, which the
+        `ops.cached_attention.cached_attention`'s, which the
         tensor-parallel forward shares.  Returns ``(out, updated cache
         arrays)`` in the order the tuple carried them."""
         import jax.numpy as jnp
 
         from ..fluid.dygraph import to_variable
-        from ..ops.pallas.paged_attention import cached_attention
+        from ..ops.cached_attention import cached_attention
 
         ctx, new_cache = cached_attention(
             jnp.asarray(q.data), jnp.asarray(k.data), jnp.asarray(v.data),
@@ -271,13 +234,7 @@ class TransformerEncoderLayer(dygraph.Layer):
         h = self.ln1(
             x + self.attn(x, attn_bias=attn_bias, segment_ids=segment_ids)
         )
-        if _fused_ffn_enabled():
-            from ..nn import functional as F
-
-            f = self.fc2(F.fused_linear(h, self.fc1.weight, self.fc1.bias,
-                                        activation="gelu"))
-        else:
-            f = self.fc2(layers.gelu(self.fc1(h)))
+        f = self.fc2(layers.gelu(self.fc1(h)))
         return self.ln2(h + self.dropout(f))
 
 

@@ -14,7 +14,7 @@ input/output embeddings (GPT-style).  Three forward modes:
   decode: one token per row; each layer's K/V written at ``pos`` into
   that layer's own ``[N, T, H*Dh]`` cache arrays (`generation.kv_cache`:
   one array per layer, heads merged into the last dimension) and
-  attention runs over the cache (`ops.pallas.paged_attention.
+  attention runs over the cache (`ops.cached_attention.
   cached_attention`), returning the updated arrays, again one tuple
   per layer.  Fixed shapes, so the engine's decode step compiles ONCE.
 """

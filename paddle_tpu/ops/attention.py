@@ -59,7 +59,7 @@ def _naive_attention(q, k, v, bias, scale, causal):
 def naive_attention_with_layout(q, k, v, bias, scale, causal,
                                 layout="BHSD"):
     """Single place that adapts the BHSD-native naive composition to a
-    BSHD caller (used by the dispatch below and the pallas fallbacks)."""
+    BSHD caller (used by the dispatch below)."""
     if layout == "BSHD":
         out = _naive_attention(
             q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),

@@ -7,7 +7,9 @@ logical block j to a physical pool block.  All shapes are static — the
 table is DATA, so the decode executable count stays pinned at one no
 matter how blocks migrate between requests.  The engine holds a pool
 MERGED as ``[num_blocks, block_size, H*D]`` (`generation.kv_cache` says
-why); the kernel reads ``[num_blocks, block_size, H, D]``.
+why).  No Pallas kernel reads it: two that read ``[.., H, D]`` blocks
+cost a relayout of every layer's cache each step (27.2 ms against 8.6
+dense and 11.5 paged; PERF.md section 6, PR 27).
 
 Entry points:
 
@@ -26,51 +28,32 @@ Entry points:
   is ever made.  `merged_attention` is the same math over a whole view:
   the reference the walk is pinned against, and the path of a call too
   wide for the block-diagonal form (a prefill chunk: one slot).
-* `paged_decode_attention` — one query token per slot against the
-  slot's table-mapped blocks of a ``[NB, bs, H, D]`` pool.  On TPU
-  this is a pallas kernel with the
-  block table as a SCALAR-PREFETCH operand: the grid is
-  ``(N, max_blocks)`` and the K/V BlockSpec index maps read
-  ``tables[n, j]`` to pick the physical block each step streams through
-  VMEM — the gather never materializes a dense ``[N, T, H, D]`` view in
-  HBM, and blocks past ``ceil(len/bs)`` are skipped by the length mask
-  exactly like the dense kernel's masked tail.  CPU (or
-  ``interpret=True``) runs the same kernel through the interpreter;
-  the jnp oracle is the reference both paths are pinned against.
 * `paged_gather_kv` — the dense view of a slot's blocks in the pool's
   own form (table gather, then ONE reshape of the view, never of the
-  pool), used by the gather reference, a wide prefill chunk and the
-  int8 dequant fallback.
-* `chunked_attention_reference` — C query rows per slot over a dense
-  ``[N, T, H, D]`` cache view with per-row causal limits
-  ``t <= start + i`` (the chunked-prefill / speculative-verify math;
-  C == 1 degrades to the decode reference bit-for-bit).
+  pool), used by the paged reference and a wide prefill chunk.
+* `decode_attention_reference`, `paged_decode_attention_reference`,
+  `chunked_attention_reference` — the plain jnp forms over split-head
+  ``[.., H, D]`` caches that the tests hold the walk to: one query
+  token per slot over a dense cache, the same through a block table,
+  and C query rows per slot with per-row causal limits
+  ``t <= start + i`` (the chunked-prefill / speculative-verify math,
+  which a wide prefill chunk also runs; C == 1 degrades to the decode
+  reference bit-for-bit).
 
 int8 KV: pools may be int8 with per-row per-head scales
-``[num_blocks, block_size, H]`` (``quantize_kv``/``dequantize_kv``).
-Quantized pools take the gather-dequant reference path — the
-documented-tolerance policy (`PADDLE_TPU_FLASH_ACC` discipline) is
-owned by the engine flag that opts a cache into int8.
+``[num_blocks, block_size, H]`` (``quantize_kv``/``dequantize_kv``),
+rows quantized on write and dequantized a chunk at a time on read.  The
+looser tolerance that buys is opted into by the engine flag that makes
+a cache int8, never by default.
 """
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from .. import dispatch
-from .decode_attention import (
-    _KV_VMEM_BUDGET,
-    _online_softmax_block,
-    _stat_scratch,
-    decode_attention_reference,
-    kv_block_vmem_bytes,
-)
+from . import dispatch
 
 NEG_INF = -1e30
 
@@ -78,10 +61,10 @@ __all__ = [
     "attention_walk_share",
     "cached_attention",
     "chunked_attention_reference",
+    "decode_attention_reference",
     "dequantize_kv",
     "kv_write",
     "merged_attention",
-    "paged_decode_attention",
     "paged_decode_attention_reference",
     "paged_gather_kv",
     "quantize_kv",
@@ -134,6 +117,28 @@ def paged_gather_kv(pool, tables, scale_pool=None):
         s = scale_pool[tables].reshape(n, nb * bs, h)
         g = dequantize_kv(g.reshape(n, nb * bs, h, -1), s).reshape(g.shape)
     return g
+
+
+def decode_attention_reference(q, k_cache, v_cache, lengths, scale=None):
+    """jnp oracle: q [N, H, D], k/v_cache [N, T, H, D], lengths [N].
+
+    Attends positions ``t < lengths[n]``; a slot with length 0 emits
+    zeros (like the flash kernel's dead rows)."""
+    if scale is None:
+        scale = float(q.shape[-1]) ** -0.5
+    s = jnp.einsum("nhd,nthd->nht", q.astype(jnp.float32),
+                   k_cache.astype(jnp.float32)) * scale
+    t = jnp.arange(k_cache.shape[1])
+    valid = t[None, :] < lengths[:, None]              # [N, T]
+    s = jnp.where(valid[:, None, :], s, NEG_INF)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    safe_m = jnp.where(m <= NEG_INF / 2, 0.0, m)
+    p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - safe_m))
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    p = p / jnp.maximum(l, 1e-30)
+    out = jnp.einsum("nht,nthd->nhd", p, v_cache.astype(jnp.float32))
+    dead = (m <= NEG_INF / 2)                          # [N, H, 1]
+    return jnp.where(dead, 0.0, out).astype(q.dtype)
 
 
 def paged_decode_attention_reference(q, k_pool, v_pool, tables, lengths,
@@ -431,103 +436,6 @@ def _attend_live(q, start, live, pools, tables, group, chunk, scale):
 
 
 # ---------------------------------------------------------------------------
-# pallas kernel: block table as scalar prefetch
-# ---------------------------------------------------------------------------
-
-
-def _paged_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref,
-                  o_ref, m_ref, l_ref, acc_ref, *, scale, bs, nb):
-    """Grid (N, nb): per slot, stream TABLE-MAPPED pool blocks through
-    the dense kernel's online-softmax block update.  The index maps
-    already routed k_ref/v_ref to pool block ``tables[n, j]``; in here
-    only the length mask remains — positions ``j*bs + o >= lengths[n]``
-    are killed, so blocks wholly past the length contribute nothing
-    (their p rows are exactly zero)."""
-    del tables_ref                      # consumed by the index maps
-    _online_softmax_block(
-        q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-        length=lengths_ref[pl.program_id(0)], j=pl.program_id(1),
-        nblocks=nb, scale=scale, bk=bs)
-
-
-def _pallas_paged(q, k_pool, v_pool, tables, lengths, scale, interpret):
-    n, h, d = q.shape
-    bs = int(k_pool.shape[1])
-    nb = int(tables.shape[1])
-    kernel = functools.partial(_paged_kernel, scale=scale, bs=bs, nb=nb)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,          # tables, lengths
-        grid=(n, nb),
-        in_specs=[
-            pl.BlockSpec((1, h, d), lambda g, j, tab, ln: (g, 0, 0)),
-            # the paged gather: logical block j of slot g IS pool block
-            # tables[g, j] — the indirection lives in the index map
-            # (grid indices first, then the scalar-prefetch refs)
-            pl.BlockSpec((1, bs, h, d),
-                         lambda g, j, tab, ln: (tab[g, j], 0, 0, 0)),
-            pl.BlockSpec((1, bs, h, d),
-                         lambda g, j, tab, ln: (tab[g, j], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, h, d), lambda g, j, tab, ln: (g, 0, 0)),
-        scratch_shapes=_stat_scratch(h, d),
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, h, d), q.dtype),
-        interpret=interpret,
-    )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      q, k_pool, v_pool)
-
-
-def _reference_reason(k_pool, quantized):
-    """The rule that sends this call to the gather reference, or None
-    when the kernel takes it."""
-    if quantized:
-        return "int8 pool: the kernel reads float blocks only"
-    if jax.default_backend() != "tpu":
-        return "backend is not a TPU"
-    _, bs, h, d = (int(x) for x in k_pool.shape)
-    if d % 64:
-        return "head_dim %d is not a multiple of 64" % d
-    if bs % 128:
-        return "block_size %d is not a multiple of 128" % bs
-    need = kv_block_vmem_bytes(bs, h, d, k_pool.dtype)
-    if need > _KV_VMEM_BUDGET:
-        return ("blocks of %d rows at H=%d, D=%d need %d MiB of VMEM, "
-                "over the %d MiB budget"
-                % (bs, h, d, need >> 20, _KV_VMEM_BUDGET >> 20))
-    return None
-
-
-def paged_decode_attention(q, k_pool, v_pool, tables, lengths,
-                           scale=None, interpret=None, k_scale=None,
-                           v_scale=None):
-    """One decode step of attention through the block table.
-
-    q [N, H, D]; pools [NB, bs, H, D]; tables [N, max_blocks] int32;
-    lengths [N] (positions ``t < lengths[n]`` attended — the engine
-    writes the current token's K/V BEFORE calling, decode-kernel
-    contract).  int8 pools (``k_scale``/``v_scale`` given) and
-    non-TPU-tileable block sizes take the gather reference path."""
-    if scale is None:
-        scale = float(q.shape[-1]) ** -0.5
-    lengths = jnp.asarray(lengths).astype(jnp.int32)
-    tables = jnp.asarray(tables).astype(jnp.int32)
-    if interpret is None or k_scale is not None:
-        reason = _reference_reason(k_pool, k_scale is not None)
-        dispatch.record("paged_decode_attention",
-                        "gather reference" if reason else "pallas",
-                        reason or "paged decode kernel shape rules met")
-        if reason:
-            return paged_decode_attention_reference(
-                q, k_pool, v_pool, tables, lengths, scale,
-                k_scale=k_scale, v_scale=v_scale)
-    return _pallas_paged(q, k_pool, v_pool, tables, lengths, scale,
-                         bool(interpret))
-
-
-# ---------------------------------------------------------------------------
 # the cached forward's attention: one write, then attend
 # ---------------------------------------------------------------------------
 
@@ -619,14 +527,13 @@ def cached_attention(q, k_new, v_new, cache, scale=None):
                       k_new.reshape(b * c, h, d),
                       v_new.reshape(b * c, h, d))
     if c == 1:
-        # the decode kernels read [.., H, D] blocks: on a merged cache
-        # that is a relayout of all of it every step, three times the
-        # cost of attending over it as it lies (27.2 against 8.6 ms a
-        # step dense, 11.5 paged; PERF.md section 6, PR 27)
+        # nothing is chosen here: the count is what
+        # `decode_attn_kernel_share.serve` reads (0% with it, null
+        # without), and goes with that metric (ROADMAP D10)
         dispatch.record(
-            "decode_attention" if dense else "paged_decode_attention",
-            "reference" if dense else "gather reference",
-            "the cache's heads are merged; the kernel reads [.., H, D]")
+            "decode_attention", "walk",
+            "no decode kernel exists: the live part of the merged cache "
+            "is walked as it lies")
     if c * h > _BLOCK_DIAGONAL_ROWS:
         k_view, v_view = arrays[:2]
         if not dense:

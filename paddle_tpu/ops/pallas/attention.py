@@ -9,11 +9,11 @@ a row-parallel kernel, dK/dV (and the padding-bias gradient) in a
 column-parallel kernel, each recomputing P blockwise from (Q, K, LSE) —
 the standard flash backward, O(S) memory end to end.
 
-Layouts: "BHSD" ([B, H, S, D] head-major, flattened to [BH, S, D] for the
-kernel) or "BSHD" ([B, S, H, D] — the natural output of a [B,S,HD] qkv
-projection reshape; the kernel blocks the native 4D array with the head
-on a unit grid axis, so the model never materializes the [B,H,S,D]
-transpose that otherwise costs 8 relayout passes per transformer layer).
+Layouts: the kernels are head-major, [B, H, S, D] flattened to
+[BH, S, D].  "BSHD" ([B, S, H, D], the natural output of a [B,S,HD] qkv
+projection reshape) is transposed to that around them: Mosaic refuses a
+(1, bq, 1, d) block, whose last two dims neither divide (8, 128) nor
+equal the array's.
 Supported in-kernel:
   - causal masking,
   - a broadcastable additive bias of shape [BH, 1, Sk] (padding masks),
@@ -33,7 +33,6 @@ kernels through the pallas interpreter for testing.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -47,17 +46,6 @@ NEG_INF = -1e30
 _FALLBACK_WARNED: set = set()
 
 
-def _acc_dtype():
-    """Accumulator dtype for the MULTI-block schedules' running/cross-
-    block accumulators (fwd acc, dq acc, dk/dv acc).  f32 by default;
-    `PADDLE_TPU_FLASH_ACC=bf16` halves accumulator VMEM at a documented
-    accuracy cost (see test_pallas_attention tolerance policy).  Row
-    max/sum statistics always stay f32 — they are tiny and their error
-    compounds through every block's softmax rescale."""
-    return (jnp.bfloat16 if os.getenv("PADDLE_TPU_FLASH_ACC") == "bf16"
-            else jnp.float32)
-
-
 def _pick_block(s):
     for b in (512, 256, 128):
         if s % b == 0:
@@ -67,48 +55,19 @@ def _pick_block(s):
 
 def _block_sizes(sq, sk, block_q=None, block_k=None):
     # explicit arguments (the autotuner / callers who measured their
-    # shape) are a hard contract: they win over the env override and the
-    # heuristic, and an invalid choice raises instead of silently
-    # falling back — a tuner must never time a different grid than the
-    # one it thinks it requested.  A side NOT given explicitly keeps the
-    # normal precedence (env override when it divides, else heuristic).
-    if block_q is not None or block_k is not None:
-        env_q = env_k = None
-        ov = os.getenv("PADDLE_TPU_FLASH_BLOCKS")
-        if ov:
-            try:
-                env_q, env_k = (int(t) for t in ov.split(","))
-            except ValueError:
-                raise ValueError(
-                    "PADDLE_TPU_FLASH_BLOCKS must be 'bq,bk' (two "
-                    "ints), got %r" % ov) from None
-        bq = int(block_q) if block_q is not None else (
-            env_q if env_q and sq % env_q == 0 else _pick_block(sq))
-        bk = int(block_k) if block_k is not None else (
-            env_k if env_k and sk % env_k == 0 else _pick_block(sk))
-        if not bq or not bk or sq % bq or sk % bk:
-            raise ValueError(
-                "explicit flash-attention block sizes (block_q=%r, "
-                "block_k=%r) must divide the padded sequence lengths "
-                "(Sq=%d, Sk=%d)" % (block_q, block_k, sq, sk))
-        return bq, bk
-    ov = os.getenv("PADDLE_TPU_FLASH_BLOCKS")  # "bq,bk" tuning override
-    if ov:
-        import warnings
-
-        try:
-            bq, bk = (int(t) for t in ov.split(","))
-        except ValueError:
-            raise ValueError(
-                "PADDLE_TPU_FLASH_BLOCKS must be 'bq,bk' (two ints), got "
-                "%r" % ov) from None
-        if sq % bq == 0 and sk % bk == 0:
-            return bq, bk
-        warnings.warn(
-            "PADDLE_TPU_FLASH_BLOCKS=%s does not divide (Sq=%d, Sk=%d); "
-            "falling back to the default block sizes" % (ov, sq, sk),
-            stacklevel=3)
-    return _pick_block(sq), _pick_block(sk)
+    # shape) are a hard contract: they win over the heuristic, and an
+    # invalid choice raises instead of silently falling back — a tuner
+    # must never time a different grid than the one it thinks it
+    # requested.  A side NOT given explicitly keeps the heuristic.
+    bq = _pick_block(sq) if block_q is None else int(block_q)
+    bk = _pick_block(sk) if block_k is None else int(block_k)
+    if (block_q is not None or block_k is not None) and (
+            not bq or not bk or sq % bq or sk % bk):
+        raise ValueError(
+            "explicit flash-attention block sizes (block_q=%r, "
+            "block_k=%r) must divide the padded sequence lengths "
+            "(Sq=%d, Sk=%d)" % (block_q, block_k, sq, sk))
+    return bq, bk
 
 
 def _apply_masks(s, bias_ref, qseg_ref, kseg_ref, causal, i, j, bq, bk,
@@ -149,19 +108,6 @@ def _split_refs(refs, has_bias, has_seg):
     return q_ref, k_ref, v_ref, bias_ref, qseg_ref, kseg_ref, refs[idx:]
 
 
-def _ld(ref):
-    """Load a q/k/v/o/do block as [rows, d] for either layout's block
-    shape: (1, rows, d) in BHSD-flat, (1, rows, 1, d) in BSHD."""
-    return ref[0] if len(ref.shape) == 3 else ref[0, :, 0, :]
-
-
-def _st(ref, val):
-    if len(ref.shape) == 3:
-        ref[0, :, :] = val
-    else:
-        ref[0, :, 0, :] = val
-
-
 def _recompute_lse(s):
     """Full-row logsumexp from a score tile that covers the whole row
     (single-block schedule) — matches the forward's dead-row handling."""
@@ -173,19 +119,13 @@ def _recompute_lse(s):
                      safe_m + jnp.log(jnp.maximum(l, 1e-30)))
 
 
-def _row_spec(rows, d, layout, h, pos):
-    """BlockSpec for a row-blocked [.., S, D] tensor in either layout.
+def _row_spec(rows, d, pos):
+    """BlockSpec for a row-blocked [BH, S, D] tensor.
     pos: which positional grid arg (1 or 2) carries this tensor's row
     block index — the fwd/dq grids are (g, i, j), the dkv grid (g, j, i)."""
-    if layout == "BHSD":
-        if pos == 1:
-            return pl.BlockSpec((1, rows, d), lambda g, a, b: (g, a, 0))
-        return pl.BlockSpec((1, rows, d), lambda g, a, b: (g, b, 0))
     if pos == 1:
-        return pl.BlockSpec(
-            (1, rows, 1, d), lambda g, a, b: (g // h, a, g % h, 0))
-    return pl.BlockSpec(
-        (1, rows, 1, d), lambda g, a, b: (g // h, b, g % h, 0))
+        return pl.BlockSpec((1, rows, d), lambda g, a, b: (g, a, 0))
+    return pl.BlockSpec((1, rows, d), lambda g, a, b: (g, b, 0))
 
 
 # ---------------------------------------------------------------------------
@@ -210,9 +150,9 @@ def _fwd_kernel(*refs, scale, causal, bq, bk, nk, has_bias, has_seg,
     i = pl.program_id(1)
 
     def _compute():
-        q = _ld(q_ref).astype(jnp.float32)  # [bq, d]
-        k = _ld(k_ref).astype(jnp.float32)  # [bk, d]
-        v = _ld(v_ref).astype(jnp.float32)  # [bk, d]
+        q = q_ref[0].astype(jnp.float32)  # [bq, d]
+        k = k_ref[0].astype(jnp.float32)  # [bk, d]
+        v = v_ref[0].astype(jnp.float32)  # [bk, d]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -249,7 +189,7 @@ def _fwd_kernel(*refs, scale, causal, bq, bk, nk, has_bias, has_seg,
         # accumulating p = exp(0) = 1 garbage; emit zeros, keep lse at
         # NEG_INF so the backward zeroes it too
         dead = m_ref[:, 0] <= NEG_INF / 2
-        _st(o_ref, jnp.where(dead[:, None], 0.0, o).astype(o_ref.dtype))
+        o_ref[0, :, :] = jnp.where(dead[:, None], 0.0, o).astype(o_ref.dtype)
         if emit_lse:
             lse = jnp.where(dead, NEG_INF, m_ref[:, 0] + jnp.log(safe_l))
             lse_ref[0, :, :] = jnp.broadcast_to(lse[:, None],
@@ -262,34 +202,26 @@ def _fwd_kernel(*refs, scale, causal, bq, bk, nk, has_bias, has_seg,
 
 
 def _fwd(q, k, v, bias, qseg, kseg, n_head, scale, causal, interpret,
-         coff=0, layout="BHSD", block_q=None, block_k=None):
-    """Returns (out, lse); out is [bh,sq,d] (BHSD) or [b,sq,h,d] (BSHD);
-    lse is the [bh,sq,128] row-broadcast residual, EXCEPT on the
-    single-block schedule (nq==nk==1) where it is a (bh,8,128) zero
-    token and the backward kernels recompute lse from the full score
-    row (recompute_lse=True).
+         coff=0, block_q=None, block_k=None):
+    """Returns (out, lse); out is [bh,sq,d]; lse is the [bh,sq,128]
+    row-broadcast residual, EXCEPT on the single-block schedule
+    (nq==nk==1) where it is a (bh,8,128) zero token and the fused
+    backward kernel recomputes lse from the full score row.
 
     qseg: [B, sq, 128] lane-broadcast ids; kseg: [B, 8, sk] sublane-
     broadcast (B = bh // n_head; the index map divides by n_head so the
     ids are not replicated per head in HBM)."""
-    if layout == "BHSD":
-        bh, sq, d = q.shape
-        sk = k.shape[1]
-        out_sds = jax.ShapeDtypeStruct((bh, sq, d), q.dtype)
-    else:
-        b, sq, h_, d = q.shape
-        sk = k.shape[1]
-        bh = b * h_
-        out_sds = jax.ShapeDtypeStruct((b, sq, h_, d), q.dtype)
+    bh, sq, d = q.shape
+    sk = k.shape[1]
     bq, bk = _block_sizes(sq, sk, block_q, block_k)
     nq, nk = sq // bq, sk // bk
     has_bias, has_seg = bias is not None, qseg is not None
     h = n_head
 
     in_specs = [
-        _row_spec(bq, d, layout, h, 1),
-        _row_spec(bk, d, layout, h, 2),
-        _row_spec(bk, d, layout, h, 2),
+        _row_spec(bq, d, 1),
+        _row_spec(bk, d, 2),
+        _row_spec(bk, d, 2),
     ]
     args = [q, k, v]
     if has_bias:
@@ -316,20 +248,20 @@ def _fwd(q, k, v, bias, qseg, kseg, n_head, scale, causal, interpret,
             grid=(bh, nq, nk),
             in_specs=in_specs,
             out_specs=[
-                _row_spec(bq, d, layout, h, 1),
+                _row_spec(bq, d, 1),
                 pl.BlockSpec((1, lse_rows, 128),
                              (lambda b, i, j: (b, i, 0)) if emit_lse
                              else (lambda b, i, j: (b, 0, 0))),
             ],
             out_shape=[
-                out_sds,
+                jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
                 jax.ShapeDtypeStruct(
                     (bh, sq if emit_lse else 8, 128), jnp.float32),
             ],
             scratch_shapes=[
                 pltpu.VMEM((bq, 128), jnp.float32),  # running row max
                 pltpu.VMEM((bq, 128), jnp.float32),  # running row sum
-                pltpu.VMEM((bq, d), _acc_dtype()),  # output accumulator
+                pltpu.VMEM((bq, d), jnp.float32),  # output accumulator
             ],
             interpret=interpret,
             name="flash_attention_fwd",
@@ -343,7 +275,7 @@ def _fwd(q, k, v, bias, qseg, kseg, n_head, scale, causal, interpret,
 
 
 def _bwd_dq_kernel(*refs, scale, causal, bq, bk, nk, has_bias, has_seg,
-                   coff=0, recompute_lse=False):
+                   coff=0):
     (q_ref, k_ref, v_ref, bias_ref, qseg_ref, kseg_ref, tail) = _split_refs(
         refs, has_bias, has_seg
     )
@@ -356,22 +288,18 @@ def _bwd_dq_kernel(*refs, scale, causal, bq, bk, nk, has_bias, has_seg,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def _compute():
-        q = _ld(q_ref).astype(jnp.float32)
-        k = _ld(k_ref).astype(jnp.float32)
-        v = _ld(v_ref).astype(jnp.float32)
-        do = _ld(do_ref).astype(jnp.float32)
-        o = _ld(o_ref).astype(jnp.float32)
+        q = q_ref[0].astype(jnp.float32)
+        k = k_ref[0].astype(jnp.float32)
+        v = v_ref[0].astype(jnp.float32)
+        do = do_ref[0].astype(jnp.float32)
+        o = o_ref[0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale
         s = _apply_masks(s, bias_ref, qseg_ref, kseg_ref, causal, i, j,
                          bq, bk, coff)
-        if recompute_lse:
-            # single-block schedule: this tile IS the full score row
-            lse = _recompute_lse(s)
-        else:
-            lse = lse_ref[0, :, 0]  # [bq] logsumexp rows
+        lse = lse_ref[0, :, 0]  # [bq] logsumexp rows
         # explicit zero where masked: with a fully-masked row lse is
         # NEG_INF and exp(s - lse) would resurrect p = 1
         p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - lse[:, None]))
@@ -395,11 +323,11 @@ def _bwd_dq_kernel(*refs, scale, causal, bq, bk, nk, has_bias, has_seg,
 
     @pl.when(j == nk - 1)
     def _finalize():
-        _st(dq_ref, acc_ref[...].astype(dq_ref.dtype))
+        dq_ref[0, :, :] = acc_ref[...].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(*refs, scale, causal, bq, bk, nq, has_bias, has_seg,
-                    coff=0, recompute_lse=False):
+                    coff=0):
     (q_ref, k_ref, v_ref, bias_ref, qseg_ref, kseg_ref, tail) = _split_refs(
         refs, has_bias, has_seg
     )
@@ -421,21 +349,18 @@ def _bwd_dkv_kernel(*refs, scale, causal, bq, bk, nq, has_bias, has_seg,
             db_acc[...] = jnp.zeros_like(db_acc)
 
     def _compute():
-        q = _ld(q_ref).astype(jnp.float32)
-        k = _ld(k_ref).astype(jnp.float32)
-        v = _ld(v_ref).astype(jnp.float32)
-        do = _ld(do_ref).astype(jnp.float32)
-        o = _ld(o_ref).astype(jnp.float32)
+        q = q_ref[0].astype(jnp.float32)
+        k = k_ref[0].astype(jnp.float32)
+        v = v_ref[0].astype(jnp.float32)
+        do = do_ref[0].astype(jnp.float32)
+        o = o_ref[0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale
         s = _apply_masks(s, bias_ref, qseg_ref, kseg_ref, causal, i, j,
                          bq, bk, coff)
-        if recompute_lse:
-            lse = _recompute_lse(s)
-        else:
-            lse = lse_ref[0, :, 0]
+        lse = lse_ref[0, :, 0]
         p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - lse[:, None]))
         dv_acc[...] = (
             dv_acc[...].astype(jnp.float32) + jax.lax.dot_general(
@@ -466,8 +391,8 @@ def _bwd_dkv_kernel(*refs, scale, causal, bq, bk, nq, has_bias, has_seg,
 
     @pl.when(i == nq - 1)
     def _finalize():
-        _st(dk_ref, dk_acc[...].astype(dk_ref.dtype))
-        _st(dv_ref, dv_acc[...].astype(dv_ref.dtype))
+        dk_ref[0, :, :] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0, :, :] = dv_acc[...].astype(dv_ref.dtype)
         if db_ref is not None:
             db_ref[0, 0, :] = db_acc[0, :].astype(db_ref.dtype)
 
@@ -482,11 +407,9 @@ def _with_seg_cotangents(dq, dk, dv, dbias, qseg, kseg):
     return dq, dk, dv, dbias, dqseg, dkseg
 
 
-def _row_spec1(rows, d, layout, h):
+def _row_spec1(rows, d):
     """Single-grid-axis BlockSpec (the fused single-block backward)."""
-    if layout == "BHSD":
-        return pl.BlockSpec((1, rows, d), lambda g: (g, 0, 0))
-    return pl.BlockSpec((1, rows, 1, d), lambda g: (g // h, 0, g % h, 0))
+    return pl.BlockSpec((1, rows, d), lambda g: (g, 0, 0))
 
 
 def _bwd_fused_kernel(*refs, scale, causal, bq, bk, has_bias, has_seg,
@@ -506,11 +429,11 @@ def _bwd_fused_kernel(*refs, scale, causal, bq, bk, has_bias, has_seg,
     else:
         o_ref, do_ref, dq_ref, dk_ref, dv_ref = tail
         db_ref = None
-    q = _ld(q_ref).astype(jnp.float32)
-    k = _ld(k_ref).astype(jnp.float32)
-    v = _ld(v_ref).astype(jnp.float32)
-    do = _ld(do_ref).astype(jnp.float32)
-    o = _ld(o_ref).astype(jnp.float32)
+    q = q_ref[0].astype(jnp.float32)
+    k = k_ref[0].astype(jnp.float32)
+    v = v_ref[0].astype(jnp.float32)
+    do = do_ref[0].astype(jnp.float32)
+    o = o_ref[0].astype(jnp.float32)
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -530,26 +453,26 @@ def _bwd_fused_kernel(*refs, scale, causal, bq, bk, has_bias, has_seg,
     delta = jnp.sum(do * o, axis=1)  # [bq]
     ds_raw = p * (dp - delta[:, None])
     ds = ds_raw * scale
-    _st(dq_ref, jax.lax.dot_general(
+    dq_ref[0, :, :] = jax.lax.dot_general(
         ds, k, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
-    ).astype(dq_ref.dtype))
-    _st(dk_ref, jax.lax.dot_general(
+    ).astype(dq_ref.dtype)
+    dk_ref[0, :, :] = jax.lax.dot_general(
         ds, q, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
-    ).astype(dk_ref.dtype))
-    _st(dv_ref, dv.astype(dv_ref.dtype))
+    ).astype(dk_ref.dtype)
+    dv_ref[0, :, :] = dv.astype(dv_ref.dtype)
     if db_ref is not None:
         db_ref[0, 0, :] = jnp.sum(ds_raw, axis=0).astype(db_ref.dtype)
 
 
 def _bwd_fused(q, k, v, bias, qseg, kseg, out, g, h, scale, causal,
-               interpret, coff, layout, bq, bk, bh):
+               interpret, coff, bq, bk, bh):
     has_bias, has_seg = bias is not None, qseg is not None
     in_specs = [
-        _row_spec1(bq, q.shape[-1], layout, h),   # q
-        _row_spec1(bk, q.shape[-1], layout, h),   # k
-        _row_spec1(bk, q.shape[-1], layout, h),   # v
+        _row_spec1(bq, q.shape[-1]),   # q
+        _row_spec1(bk, q.shape[-1]),   # k
+        _row_spec1(bk, q.shape[-1]),   # v
     ]
     args = [q, k, v]
     if has_bias:
@@ -562,14 +485,14 @@ def _bwd_fused(q, k, v, bias, qseg, kseg, out, g, h, scale, causal,
             pl.BlockSpec((1, 8, bk), lambda g_: (g_ // h, 0, 0)))
         args.extend([qseg, kseg])
     in_specs += [
-        _row_spec1(bq, q.shape[-1], layout, h),   # o
-        _row_spec1(bq, q.shape[-1], layout, h),   # do
+        _row_spec1(bq, q.shape[-1]),   # o
+        _row_spec1(bq, q.shape[-1]),   # do
     ]
     args += [out, g]   # lse is recomputed in-kernel: no residual input
     out_specs = [
-        _row_spec1(bq, q.shape[-1], layout, h),   # dq
-        _row_spec1(bk, q.shape[-1], layout, h),   # dk
-        _row_spec1(bk, q.shape[-1], layout, h),   # dv
+        _row_spec1(bq, q.shape[-1]),   # dq
+        _row_spec1(bk, q.shape[-1]),   # dk
+        _row_spec1(bk, q.shape[-1]),   # dv
     ]
     out_shape = [
         jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -607,9 +530,9 @@ def _bwd_fused(q, k, v, bias, qseg, kseg, out, g, h, scale, causal,
 def flash_attention(q, k, v, bias=None, segment_ids=None, scale=None,
                     causal=False, interpret=None, layout="BHSD",
                     block_q=None, block_k=None):
-    """q/k/v: [B, H, S, D] (layout="BHSD") or [B, S, H, D] ("BSHD" — no
-    head transpose anywhere).  bias: None or broadcastable
-    [B, 1/H, 1, Sk].
+    """q/k/v: [B, H, S, D] (layout="BHSD") or [B, S, H, D] ("BSHD":
+    transposed to head-major around the kernels).  bias: None or
+    broadcastable [B, 1/H, 1, Sk].
     segment_ids: None, a [B, S] int array (self-attention packing), or a
     (q_seg [B, Sq], kv_seg [B, Sk]) pair — attention is confined to equal
     segment ids.
@@ -618,9 +541,7 @@ def flash_attention(q, k, v, bias=None, segment_ids=None, scale=None,
     (the knob ``paddle_tpu.tune.search_flash_blocks`` searches); they
     must divide the PADDED sequence lengths (multiples of 128) or a
     ValueError is raised.  Default None keeps the built-in heuristic
-    (largest of 512/256/128 that divides), and the
-    ``PADDLE_TPU_FLASH_BLOCKS=bq,bk`` env override still applies when no
-    explicit argument is given.
+    (largest of 512/256/128 that divides).
 
     Sequences not divisible by the 128-lane block are PADDED up to it
     (padded keys masked by bias / a sentinel segment id, padded query
@@ -632,31 +553,23 @@ def flash_attention(q, k, v, bias=None, segment_ids=None, scale=None,
         scale = q.shape[-1] ** -0.5
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    if layout == "BSHD" and not interpret:
+    if layout == "BSHD":
         # Mosaic requires the last-two block dims to divide (8, 128) or
         # equal the array dims — a (1, bq, 1, d) head-sliced block is
-        # illegal, so on real TPU the BSHD API transposes to head-major
-        # around the kernel (XLA fuses these with neighbours; measured
-        # cheaper than strided sublane reads inside the kernel)
+        # illegal, so the BSHD API transposes to head-major around the
+        # kernel (XLA fuses these with neighbours; measured cheaper than
+        # strided sublane reads inside the kernel)
         out = flash_attention(
             q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
             v.transpose(0, 2, 1, 3), bias=bias, segment_ids=segment_ids,
             scale=scale, causal=causal, interpret=interpret, layout="BHSD",
             block_q=block_q, block_k=block_k)
         return out.transpose(0, 2, 1, 3)
-    if layout == "BHSD":
-        b, h, sq, d = q.shape
-        sk = k.shape[2]
-        s_ax = 2
-    else:
-        b, sq, h, d = q.shape
-        sk = k.shape[1]
-        s_ax = 1
+    b, h, sq, d = q.shape
+    sk = k.shape[2]
 
     def _pad_s(x, p):
-        pads = [(0, 0)] * x.ndim
-        pads[s_ax] = (0, p)
-        return jnp.pad(x, pads)
+        return jnp.pad(x, ((0, 0), (0, 0), (0, p), (0, 0)))
 
     # pad seq lengths up to the 128 block so _pick_block always succeeds
     sq_orig, sk_orig = sq, sk
@@ -713,17 +626,11 @@ def flash_attention(q, k, v, bias=None, segment_ids=None, scale=None,
         if segment_ids is not None:
             sb = _segment_bias(segment_ids)
             bias = sb if bias is None else bias + sb
-        from ..attention import naive_attention_with_layout
+        return _naive_attention(q, k, v, bias, scale, causal)
 
-        return naive_attention_with_layout(q, k, v, bias, scale, causal,
-                                           layout)
-
-    if layout == "BHSD":
-        qf = q.reshape(b * h, sq, d)
-        kf = k.reshape(b * h, sk, d)
-        vf = v.reshape(b * h, sk, d)
-    else:
-        qf, kf, vf = q, k, v
+    qf = q.reshape(b * h, sq, d)
+    kf = k.reshape(b * h, sk, d)
+    vf = v.reshape(b * h, sk, d)
     bf = None
     if bias is not None:
         bf = jnp.broadcast_to(bias, (b, h, 1, sk)).reshape(b * h, 1, sk)
@@ -742,63 +649,51 @@ def flash_attention(q, k, v, bias=None, segment_ids=None, scale=None,
 
     coff = sk_orig - sq_orig  # bottom-right causal alignment (original S)
     out = _flash_core(qf, kf, vf, bf, qsegf, ksegf, h, scale, causal,
-                      interpret, coff, layout, block_q, block_k)
-    if layout == "BHSD":
-        out = out.reshape(b, h, sq, d)
-        return out[:, :, :sq_orig] if sq != sq_orig else out
-    return out[:, :sq_orig] if sq != sq_orig else out
+                      interpret, coff, block_q, block_k)
+    out = out.reshape(b, h, sq, d)
+    return out[:, :, :sq_orig] if sq != sq_orig else out
 
 
-@functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(6, 7, 8, 9, 10, 11, 12, 13))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11, 12))
 def _flash_core(q, k, v, bias, qseg, kseg, n_head, scale, causal, interpret,
-                coff, layout="BHSD", block_q=None, block_k=None):
+                coff, block_q=None, block_k=None):
     out, _ = _fwd(q, k, v, bias, qseg, kseg, n_head, scale, causal,
-                  interpret, coff, layout, block_q, block_k)
+                  interpret, coff, block_q, block_k)
     return out
 
 
 def _flash_core_fwd(q, k, v, bias, qseg, kseg, n_head, scale, causal,
-                    interpret, coff, layout="BHSD", block_q=None,
-                    block_k=None):
+                    interpret, coff, block_q=None, block_k=None):
     out, lse = _fwd(q, k, v, bias, qseg, kseg, n_head, scale, causal,
-                    interpret, coff, layout, block_q, block_k)
+                    interpret, coff, block_q, block_k)
     return out, (q, k, v, bias, qseg, kseg, out, lse)
 
 
-def _flash_core_bwd(n_head, scale, causal, interpret, coff, layout,
-                    block_q, block_k, res, g):
+def _flash_core_bwd(n_head, scale, causal, interpret, coff, block_q,
+                    block_k, res, g):
     q, k, v, bias, qseg, kseg, out, lse2d = res
     h = n_head
-    if layout == "BHSD":
-        bh, sq, d = q.shape
-        sk = k.shape[1]
-    else:
-        b_, sq, h_, d = q.shape
-        sk = k.shape[1]
-        bh = b_ * h_
+    bh, sq, d = q.shape
+    sk = k.shape[1]
     bq, bk = _block_sizes(sq, sk, block_q, block_k)
     nq, nk = sq // bq, sk // bk
     has_bias, has_seg = bias is not None, qseg is not None
-    fast = nq == 1 and nk == 1      # lse recomputed in-kernel (see _fwd)
 
-    if fast and os.getenv("PADDLE_TPU_FLASH_FUSED_BWD", "1") != "0":
+    if nq == 1 and nk == 1:     # lse recomputed in-kernel (see _fwd)
         dq, dk, dv, dbias = _bwd_fused(
             q, k, v, bias, qseg, kseg, out, g, h, scale, causal,
-            interpret, coff, layout, bq, bk, bh)
+            interpret, coff, bq, bk, bh)
         return _with_seg_cotangents(dq, dk, dv, dbias, qseg, kseg)
 
     def _lse_spec(order):
-        if fast:
-            return pl.BlockSpec((1, 8, 128), lambda b, a, c: (b, 0, 0))
         if order == "ij":
             return pl.BlockSpec((1, bq, 128), lambda b, i, j: (b, i, 0))
         return pl.BlockSpec((1, bq, 128), lambda b, j, i: (b, i, 0))
 
     dq_specs = [
-        _row_spec(bq, d, layout, h, 1),  # q
-        _row_spec(bk, d, layout, h, 2),  # k
-        _row_spec(bk, d, layout, h, 2),  # v
+        _row_spec(bq, d, 1),  # q
+        _row_spec(bk, d, 2),  # k
+        _row_spec(bk, d, 2),  # v
     ]
     args = [q, k, v]
     if has_bias:
@@ -813,9 +708,9 @@ def _flash_core_bwd(n_head, scale, causal, interpret, coff, layout,
         )
         args.extend([qseg, kseg])
     dq_specs += [
-        _row_spec(bq, d, layout, h, 1),  # o
-        _row_spec(bq, d, layout, h, 1),  # do
-        _lse_spec("ij"),  # lse rows (token buffer on the fast path)
+        _row_spec(bq, d, 1),  # o
+        _row_spec(bq, d, 1),  # do
+        _lse_spec("ij"),  # lse rows
     ]
     args += [out, g, lse2d]
     with jax.named_scope("flash_attention"):
@@ -823,22 +718,21 @@ def _flash_core_bwd(n_head, scale, causal, interpret, coff, layout,
             functools.partial(
                 _bwd_dq_kernel, scale=scale, causal=causal, bq=bq, bk=bk,
                 nk=nk, has_bias=has_bias, has_seg=has_seg, coff=coff,
-                recompute_lse=fast,
             ),
             grid=(bh, nq, nk),
             in_specs=dq_specs,
-            out_specs=_row_spec(bq, d, layout, h, 1),
+            out_specs=_row_spec(bq, d, 1),
             out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-            scratch_shapes=[pltpu.VMEM((bq, d), _acc_dtype())],
+            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
             interpret=interpret,
             name="flash_attention_bwd_dq",
         )(*args)
 
     # column-parallel pass: lse/o/do blocks follow the INNER grid dim (i)
     kv_specs = [
-        _row_spec(bq, d, layout, h, 2),  # q
-        _row_spec(bk, d, layout, h, 1),  # k
-        _row_spec(bk, d, layout, h, 1),  # v
+        _row_spec(bq, d, 2),  # q
+        _row_spec(bk, d, 1),  # k
+        _row_spec(bk, d, 1),  # v
     ]
     if has_bias:
         kv_specs.append(pl.BlockSpec((1, 1, bk), lambda b, j, i: (b, 0, j)))
@@ -850,21 +744,21 @@ def _flash_core_bwd(n_head, scale, causal, interpret, coff, layout,
             pl.BlockSpec((1, 8, bk), lambda b, j, i: (b // h, 0, j))
         )
     kv_specs += [
-        _row_spec(bq, d, layout, h, 2),  # o
-        _row_spec(bq, d, layout, h, 2),  # do
+        _row_spec(bq, d, 2),  # o
+        _row_spec(bq, d, 2),  # do
         _lse_spec("ji"),  # lse
     ]
     out_specs = [
-        _row_spec(bk, d, layout, h, 1),  # dk
-        _row_spec(bk, d, layout, h, 1),  # dv
+        _row_spec(bk, d, 1),  # dk
+        _row_spec(bk, d, 1),  # dv
     ]
     out_shape = [
         jax.ShapeDtypeStruct(k.shape, k.dtype),
         jax.ShapeDtypeStruct(v.shape, v.dtype),
     ]
     scratch = [
-        pltpu.VMEM((bk, d), _acc_dtype()),
-        pltpu.VMEM((bk, d), _acc_dtype()),
+        pltpu.VMEM((bk, d), jnp.float32),
+        pltpu.VMEM((bk, d), jnp.float32),
     ]
     if has_bias:
         out_specs.append(pl.BlockSpec((1, 1, bk), lambda b, j, i: (b, 0, j)))
@@ -875,7 +769,6 @@ def _flash_core_bwd(n_head, scale, causal, interpret, coff, layout,
             functools.partial(
                 _bwd_dkv_kernel, scale=scale, causal=causal, bq=bq, bk=bk,
                 nq=nq, has_bias=has_bias, has_seg=has_seg, coff=coff,
-                recompute_lse=fast,
             ),
             grid=(bh, nk, nq),
             in_specs=kv_specs,

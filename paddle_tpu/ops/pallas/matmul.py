@@ -28,9 +28,9 @@ Residual policy (what the VJP saves besides x/w):
     extra [M, N] write in training, none in inference).
 
 Contraction is strictly 2-D [M, K] x [K, N] with f32 accumulation
-(``preferred_element_type``) over f32 or bf16 operands — the bf16
-tolerance policy mirrors the flash kernels' ``PADDLE_TPU_FLASH_ACC``
-discipline (documented bounds in tests/test_pallas_matmul.py).  Batched
+(``preferred_element_type``) over f32 or bf16 operands — bf16
+operands are held to looser, documented bounds
+(tests/test_pallas_matmul.py).  Batched
 or transposed callers flatten/transpose outside (the ``matmul_bias_act``
 op lowering does; it falls back to the naive jnp composition when a
 transpose flag or non-tileable shape rules the kernel out).

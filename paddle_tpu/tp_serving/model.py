@@ -6,7 +6,7 @@ This mirrors the single-chip lowering op for op (`models.transformer_lm`
 through `fluid/ops`): f32 LayerNorm (eps 1e-5), erf gelu, the flattened
 ``mul`` matmul for Linear, the same attention dispatch
 (`ops.attention.scaled_dot_product_attention` for prefill,
-`ops.pallas.paged_attention.cached_attention` for cached decode),
+`ops.cached_attention.cached_attention` for cached decode),
 and tied-embedding logits.  Each shard holds ``H/tp`` heads and
 ``I/tp`` FFN columns; per-head attention math and column-parallel
 matmuls are bit-exact per shard, and the only place the floating-point
@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.attention import scaled_dot_product_attention
-from ..ops.pallas.paged_attention import cached_attention
+from ..ops.cached_attention import cached_attention
 
 __all__ = ["cached_forward", "prefill_forward"]
 

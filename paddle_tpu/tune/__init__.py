@@ -25,7 +25,7 @@ Front ends:
 * ``search_bucket_ladder(predictor, example, traffic, ...)`` — serving
   batch-bucket ladders (`InferenceServer.autotune` wires it in);
 * ``search_step(build_and_time, variants, ...)`` — opaque jitted-step
-  knobs (``bench.py --autotune``);
+  knobs;
 * ``search_train_step(build_and_time, ...)`` — the distributed-step
   knobs: ZeRO stage x accumulate_steps x gather-chunk-bytes
   (``bench.py --multichip --autotune``);
@@ -41,7 +41,8 @@ Front ends:
 
 Entry points: ``CompiledProgram.with_autotune()`` (Executor applies the
 tuned pipeline on first run), ``InferenceServer.autotune()``,
-``bench.py --autotune``, and the ``tools/autotune.py`` operator CLI.
+``bench.py --multichip --autotune``, and the ``tools/autotune.py``
+operator CLI.
 """
 
 from __future__ import annotations

@@ -708,8 +708,7 @@ def search_flash_blocks(shape, *, kv_len=None, causal=False,
 
     ``shape`` is the q shape in the given layout.  Returns a
     SearchReport whose winner params are ``{"block_q", "block_k"}`` —
-    pass them to ``flash_attention(..., block_q=, block_k=)`` (or set
-    ``PADDLE_TPU_FLASH_BLOCKS=bq,bk`` for code you don't own)."""
+    pass them to ``flash_attention(..., block_q=, block_k=)``."""
     import jax
     import jax.numpy as jnp
 
@@ -1094,7 +1093,7 @@ def search_bucket_ladder(runner, example_inputs, traffic, *, max_batch=32,
 
 
 # ---------------------------------------------------------------------------
-# jitted-step variant search (bench.py --autotune)
+# jitted-step variant search
 # ---------------------------------------------------------------------------
 
 

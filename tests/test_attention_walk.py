@@ -37,7 +37,7 @@ def _walk_case(form, c, h, n_live, seed, max_len=MAX_LEN):
     live [N] bool, start [N], tables or None)``."""
     import jax.numpy as jnp
 
-    from paddle_tpu.ops.pallas.paged_attention import quantize_kv
+    from paddle_tpu.ops.cached_attention import quantize_kv
 
     rng = np.random.RandomState(seed)
     bs = {"paged16": 16, "paged128": 128, "int8": 16, "dense": None}[form]
@@ -97,23 +97,31 @@ def test_walk_over_a_cache_its_chunks_do_not_divide(form, max_len):
     _check_walk(form, 3, 4, 5, max_len)
 
 
-def _check_walk(form, c, h, n_live, max_len):
+def _jitted_cached_attention(q, k_new, v_new, cache, tables):
+    """`cached_attention` under `jit`, a paged cache's block size (the
+    tuple's last entry) static as the engine has it."""
     import jax
+
+    from paddle_tpu.ops.cached_attention import cached_attention
+
+    tail = cache[-1:] if tables is not None else ()
+    dynamic = cache[:-1] if tables is not None else cache
+    return jax.jit(
+        lambda q, k, v, *cc: cached_attention(q, k, v, cc + tail))(
+            q, k_new, v_new, *dynamic)
+
+
+def _check_walk(form, c, h, n_live, max_len):
     import jax.numpy as jnp
 
-    from paddle_tpu.ops.pallas.paged_attention import (
-        cached_attention,
+    from paddle_tpu.ops.cached_attention import (
         merged_attention,
         paged_gather_kv,
     )
 
     q, k_new, v_new, cache, live, pos, tables = _walk_case(
         form, c, h, n_live, seed=n_live + 17 * c + h, max_len=max_len)
-    tail = cache[-1:] if tables is not None else ()    # the block size
-    dynamic = cache[:-1] if tables is not None else cache
-    ctx, arrays = jax.jit(
-        lambda q, k, v, *cc: cached_attention(q, k, v, cc + tail))(
-            q, k_new, v_new, *dynamic)
+    ctx, arrays = _jitted_cached_attention(q, k_new, v_new, cache, tables)
     ctx = np.asarray(ctx)
     assert ctx.shape == (SLOTS, c, h, D)
     if tables is None:
@@ -130,6 +138,61 @@ def _check_walk(form, c, h, n_live, max_len):
     assert ctx[live].any(axis=(1, 2, 3)).all()
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("c", [1, 5], ids=["decode", "verify"])
+@pytest.mark.parametrize("form", ["dense", "paged16", "paged128", "int8"])
+def test_cached_attention_equals_the_split_heads_references(form, c, dtype):
+    """`cached_attention` against the plain references, which share no
+    code with it (split heads, the whole view, one softmax): one token
+    a slot against `decode_attention_reference` /
+    `paged_decode_attention_reference`, five rows against
+    `chunked_attention_reference`, over what the call left in the cache;
+    queries, new rows and a float cache in ``dtype`` (an int8 pool keeps
+    its float32 scales)."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.cached_attention import (
+        chunked_attention_reference,
+        decode_attention_reference,
+        paged_decode_attention_reference,
+        paged_gather_kv,
+    )
+
+    h, n_live = 4, 5
+    q, k_new, v_new, cache, live, pos, tables = _walk_case(
+        form, c, h, n_live, seed=3 + c)
+    q, k_new, v_new = (x.astype(dtype) for x in (q, k_new, v_new))
+    if form != "int8":
+        cache = tuple(a.astype(dtype) for a in cache[:2]) + cache[2:]
+    ctx, arrays = _jitted_cached_attention(q, k_new, v_new, cache, tables)
+    assert ctx.dtype == q.dtype and ctx.shape == (SLOTS, c, h, D)
+    assert all(a.dtype == b.dtype for a, b in zip(arrays, cache))
+
+    def split(a):
+        return a.reshape(a.shape[:2] + (h, D))
+
+    k, v = split(arrays[0]), split(arrays[1])
+    scales = dict(k_scale=arrays[2], v_scale=arrays[3]) \
+        if form == "int8" else {}
+    lengths = jnp.asarray(np.where(live, pos + 1, 0).astype(np.int32))
+    if c == 1 and tables is None:
+        want = decode_attention_reference(q[:, 0], k, v, lengths)[:, None]
+    elif c == 1:
+        want = paged_decode_attention_reference(
+            q[:, 0], k, v, jnp.asarray(tables), lengths, **scales)[:, None]
+    else:
+        if tables is not None:
+            k = paged_gather_kv(k, jnp.asarray(tables), scales.get("k_scale"))
+            v = paged_gather_kv(v, jnp.asarray(tables), scales.get("v_scale"))
+        want = chunked_attention_reference(
+            q, k, v, jnp.asarray(np.where(live, pos, -c).astype(np.int32)))
+    got, want = (np.asarray(x.astype(jnp.float32)) for x in (ctx, want))
+    assert np.isfinite(got).all()
+    tol = 1e-5 if dtype == "float32" else 2e-2    # one bfloat16 rounding
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+    assert not got[~live].any() and got[live].any(axis=(1, 2, 3)).all()
+
+
 @pytest.mark.parametrize("extent", [
     [0] * 16,
     [0, 0, 301, 0, 0, 0, 101, 0, 0, 0, 0, 0, 0, 0, 0, 0],
@@ -143,7 +206,7 @@ def test_walk_plan_is_the_same_on_the_host_and_on_the_device(extent):
     longest first, each group walking to its longest member."""
     import jax.numpy as jnp
 
-    from paddle_tpu.ops.pallas.paged_attention import walk_plan
+    from paddle_tpu.ops.cached_attention import walk_plan
 
     group, chunk = 4, 128
     ext = np.asarray(extent, np.int32)
@@ -166,7 +229,7 @@ def test_walk_plan_is_the_same_on_the_host_and_on_the_device(extent):
 
 
 def test_attention_walk_share_closed_form():
-    from paddle_tpu.ops.pallas.paged_attention import (
+    from paddle_tpu.ops.cached_attention import (
         _BLOCK_DIAGONAL_ROWS,
         attention_walk_share,
         walk_geometry,
@@ -275,7 +338,7 @@ def test_engine_walk_share_is_the_closed_form(lm, paged):
     """`generation_attn_walk_share`, once a decode step: G x chunks x L
     positions over slots x positions, for the lengths the step ran at;
     `engine.stats()` reports its mean."""
-    from paddle_tpu.ops.pallas.paged_attention import walk_geometry
+    from paddle_tpu.ops.cached_attention import walk_geometry
 
     slots, max_len, plen, new = 8, 512, 120, 20
     eng = _engine(lm, slots=slots, max_len=max_len, paged=paged)

@@ -61,7 +61,7 @@ def mixed_requests(n, max_new=6, stop=()):
 
 
 # ---------------------------------------------------------------------------
-# decode-attention kernel
+# decode-attention reference
 # ---------------------------------------------------------------------------
 
 
@@ -74,7 +74,7 @@ class TestDecodeAttention:
         return q, k, v
 
     def test_reference_matches_plain_softmax(self):
-        from paddle_tpu.ops.pallas.decode_attention import (
+        from paddle_tpu.ops.cached_attention import (
             decode_attention_reference,
         )
         import jax.numpy as jnp
@@ -91,59 +91,18 @@ class TestDecodeAttention:
             np.testing.assert_allclose(out[n], ref, rtol=1e-5,
                                        atol=1e-5)
 
-    def test_pallas_interpret_matches_reference(self):
-        from paddle_tpu.ops.pallas.decode_attention import (
-            decode_attention,
-            decode_attention_reference,
-        )
-        import jax.numpy as jnp
-
-        q, k, v = self._data()
-        lens = jnp.asarray([5, 0, 256], jnp.int32)
-        ref = decode_attention_reference(
-            jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), lens)
-        pal = decode_attention(jnp.asarray(q), jnp.asarray(k),
-                               jnp.asarray(v), lens, interpret=True)
-        np.testing.assert_allclose(np.asarray(ref), np.asarray(pal),
-                                   rtol=1e-5, atol=1e-6)
-
-    def test_interpret_mode_handles_undividable_cache_len(self):
-        """A cache length no standard block divides (e.g. 64) runs as a
-        single block in interpret mode instead of crashing — the
-        engine's own test configs use max_len=64."""
-        from paddle_tpu.ops.pallas.decode_attention import (
-            decode_attention,
-            decode_attention_reference,
-        )
-        import jax.numpy as jnp
-
-        q, k, v = self._data(t=64)
-        lens = jnp.asarray([3, 64, 10], jnp.int32)
-        ref = decode_attention_reference(
-            jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), lens)
-        pal = decode_attention(jnp.asarray(q), jnp.asarray(k),
-                               jnp.asarray(v), lens, interpret=True)
-        np.testing.assert_allclose(np.asarray(ref), np.asarray(pal),
-                                   rtol=1e-5, atol=1e-6)
-        with pytest.raises(ValueError, match="does not divide"):
-            decode_attention(jnp.asarray(q), jnp.asarray(k),
-                             jnp.asarray(v), lens, interpret=True,
-                             block_k=48)
-
     def test_empty_slot_emits_zeros(self):
-        from paddle_tpu.ops.pallas.decode_attention import (
-            decode_attention,
+        from paddle_tpu.ops.cached_attention import (
+            decode_attention_reference,
         )
         import jax.numpy as jnp
 
         q, k, v = self._data(n=2)
         lens = jnp.asarray([0, 3], jnp.int32)
-        for interp in (None, True):
-            out = np.asarray(decode_attention(
-                jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), lens,
-                interpret=interp))
-            assert np.all(out[0] == 0.0)
-            assert np.any(out[1] != 0.0)
+        out = np.asarray(decode_attention_reference(
+            jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), lens))
+        assert np.all(out[0] == 0.0)
+        assert np.any(out[1] != 0.0)
 
 
 # ---------------------------------------------------------------------------
@@ -592,10 +551,8 @@ class TestPagedKernels:
     def test_paged_reference_matches_dense_reference(self):
         import jax.numpy as jnp
 
-        from paddle_tpu.ops.pallas.decode_attention import (
+        from paddle_tpu.ops.cached_attention import (
             decode_attention_reference,
-        )
-        from paddle_tpu.ops.pallas.paged_attention import (
             paged_decode_attention_reference,
         )
 
@@ -614,47 +571,12 @@ class TestPagedKernels:
         np.testing.assert_allclose(np.asarray(paged), np.asarray(dense),
                                    rtol=1e-6, atol=1e-6)
 
-    def test_pallas_interpret_matches_reference(self):
-        """The scalar-prefetch kernel through the interpreter, at a
-        TPU-tileable geometry (bs % 128, d % 64), against the jnp
-        oracle — the same pin the dense decode kernel carries."""
-        import jax.numpy as jnp
-
-        from paddle_tpu.ops.pallas.paged_attention import (
-            paged_decode_attention,
-            paged_decode_attention_reference,
-        )
-
-        rng = np.random.RandomState(1)
-        n, h, d, bs, nb_per = 2, 2, 64, 128, 2
-        q = rng.randn(n, h, d).astype(np.float32)
-        k = rng.randn(n, nb_per * bs, h, d).astype(np.float32)
-        v = rng.randn(n, nb_per * bs, h, d).astype(np.float32)
-        k_pool, v_pool, tables = self._pool_from_dense(k, v, bs)
-        lens = jnp.asarray([3, 130], jnp.int32)
-        ref = paged_decode_attention_reference(
-            jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
-            jnp.asarray(tables), lens)
-        pal = paged_decode_attention(
-            jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
-            jnp.asarray(tables), lens, interpret=True)
-        np.testing.assert_allclose(np.asarray(pal), np.asarray(ref),
-                                   rtol=1e-5, atol=1e-6)
-        # empty slot emits exact zeros through the kernel too
-        pal0 = paged_decode_attention(
-            jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
-            jnp.asarray(tables), jnp.asarray([0, 1], jnp.int32),
-            interpret=True)
-        assert np.all(np.asarray(pal0)[0] == 0.0)
-
     def test_chunked_reference_c1_equals_decode_reference(self):
         import jax.numpy as jnp
 
-        from paddle_tpu.ops.pallas.decode_attention import (
-            decode_attention_reference,
-        )
-        from paddle_tpu.ops.pallas.paged_attention import (
+        from paddle_tpu.ops.cached_attention import (
             chunked_attention_reference,
+            decode_attention_reference,
         )
 
         rng = np.random.RandomState(2)
@@ -679,7 +601,7 @@ class TestPagedKernels:
         per-row numpy softmax."""
         import jax.numpy as jnp
 
-        from paddle_tpu.ops.pallas.paged_attention import (
+        from paddle_tpu.ops.cached_attention import (
             chunked_attention_reference,
         )
 
@@ -712,7 +634,7 @@ class TestPagedKernels:
         reference's math, dead rows and empty slots included."""
         import jax.numpy as jnp
 
-        from paddle_tpu.ops.pallas.paged_attention import (
+        from paddle_tpu.ops.cached_attention import (
             chunked_attention_reference,
             merged_attention,
         )
@@ -740,7 +662,7 @@ class TestPagedKernels:
         way in, with their scales), and nothing else moves."""
         import jax.numpy as jnp
 
-        from paddle_tpu.ops.pallas.paged_attention import (
+        from paddle_tpu.ops.cached_attention import (
             dequantize_kv,
             kv_write,
         )
@@ -779,7 +701,7 @@ class TestPagedKernels:
         in its own place, for one token a slot and for three."""
         import jax.numpy as jnp
 
-        from paddle_tpu.ops.pallas.paged_attention import (
+        from paddle_tpu.ops.cached_attention import (
             cached_attention,
             paged_gather_kv,
         )
@@ -816,7 +738,7 @@ class TestPagedKernels:
     def test_int8_roundtrip_and_zero_rows(self):
         import jax.numpy as jnp
 
-        from paddle_tpu.ops.pallas.paged_attention import (
+        from paddle_tpu.ops.cached_attention import (
             dequantize_kv,
             quantize_kv,
         )

@@ -242,62 +242,12 @@ def test_bshd_pad_path():
                                rtol=2e-4, atol=2e-4)
 
 
-def test_bf16_accumulator_flag_tolerance_policy(monkeypatch):
-    """PADDLE_TPU_FLASH_ACC=bf16 trades accumulator precision for VMEM
-    on MULTI-block schedules.  Tolerance policy (the reference AMP
-    white_list pattern — looser, documented bounds for a reduced-
-    precision mode): forward rtol 2e-2 vs the f32-accumulator kernel;
-    gradients rtol 5e-2.  The default (f32) path must be unaffected by
-    the flag machinery."""
-    rng = np.random.RandomState(0)
-    B, H, S, D = 1, 2, 1024, 64    # S=1024, block 512 -> 2x2 blocks
-    q = jnp.asarray(rng.randn(B, H, S, D).astype(np.float32)) * 0.3
-    k = jnp.asarray(rng.randn(B, H, S, D).astype(np.float32)) * 0.3
-    v = jnp.asarray(rng.randn(B, H, S, D).astype(np.float32)) * 0.3
-    scale = D ** -0.5
-
-    def run(acc):
-        if acc:
-            monkeypatch.setenv("PADDLE_TPU_FLASH_ACC", acc)
-        else:
-            monkeypatch.delenv("PADDLE_TPU_FLASH_ACC", raising=False)
-
-        def f(q, k, v):
-            return jnp.sum(
-                flash_attention(q, k, v, scale=scale, causal=True,
-                                interpret=True) * 0.01)
-
-        out = flash_attention(q, k, v, scale=scale, causal=True,
-                              interpret=True)
-        grads = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
-        return out, grads
-
-    out32, g32 = run(None)
-    out16, g16 = run("bf16")
-    # f32 path tracks the oracle tightly
-    ref = _naive_attention(q, k, v, None, scale, True)
-    np.testing.assert_allclose(np.asarray(out32), np.asarray(ref),
-                               rtol=2e-4, atol=2e-5)
-    # the flag must actually take effect: bf16 accumulation noise makes
-    # the outputs differ (a vacuous pass would mean the knob regressed)
-    assert np.abs(np.asarray(out16) - np.asarray(out32)).max() > 0, \
-        "PADDLE_TPU_FLASH_ACC=bf16 had no effect"
-    # bf16 accumulators: documented looser bounds
-    np.testing.assert_allclose(np.asarray(out16), np.asarray(out32),
-                               rtol=2e-2, atol=2e-2)
-    for a, b, name in zip(g16, g32, "qkv"):
-        np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=5e-2, atol=5e-2,
-            err_msg="bf16-acc grad tolerance exceeded for %s" % name)
-
-
-def test_fused_single_block_backward_matches_two_kernel(monkeypatch):
-    """The fused single-block backward (PADDLE_TPU_FLASH_FUSED_BWD,
-    default on) must produce the same gradients as the two-kernel
-    schedule on the shapes it serves (nq == nk == 1), including bias and
-    segment ids."""
+def test_fused_single_block_backward_matches_two_kernel():
+    """One block a side (nq == nk == 1, the flagship's schedule) takes
+    the fused backward, explicit smaller blocks the two-kernel one: both
+    must give the same gradients, including bias and segment ids."""
     rng = np.random.RandomState(3)
-    B, H, S, D = 2, 2, 128, 64
+    B, H, S, D = 2, 2, 256, 64
     q = jnp.asarray(rng.randn(B, H, S, D).astype(np.float32)) * 0.3
     k = jnp.asarray(rng.randn(B, H, S, D).astype(np.float32)) * 0.3
     v = jnp.asarray(rng.randn(B, H, S, D).astype(np.float32)) * 0.3
@@ -310,15 +260,14 @@ def test_fused_single_block_backward_matches_two_kernel(monkeypatch):
         .astype(np.int32))            # 4 packed segments per row
 
     def grads(fused, with_seg):
-        monkeypatch.setenv("PADDLE_TPU_FLASH_FUSED_BWD",
-                           "1" if fused else "0")
+        blocks = {} if fused else {"block_q": 128, "block_k": 128}
 
         def f(q, k, v, bias):
             return jnp.sum(
                 flash_attention(q, k, v, bias=bias,
                                 segment_ids=seg if with_seg else None,
                                 scale=scale, causal=True,
-                                interpret=True) * 0.01)
+                                interpret=True, **blocks) * 0.01)
 
         return jax.grad(f, argnums=(0, 1, 2, 3))(q, k, v, bias)
 
@@ -385,7 +334,7 @@ def test_explicit_block_override_matches_naive_fwd_bwd():
             err_msg="block-override grad mismatch for %s" % name)
 
 
-def test_explicit_block_invalid_raises_and_wins_over_env(monkeypatch):
+def test_explicit_block_invalid_raises():
     from paddle_tpu.ops.pallas.attention import _block_sizes
 
     B, H, S, D = 1, 1, 256, 64
@@ -393,29 +342,19 @@ def test_explicit_block_invalid_raises_and_wins_over_env(monkeypatch):
     # non-divisor: hard error, never a silent fallback
     with pytest.raises(ValueError, match="must divide"):
         flash_attention(q, q, q, interpret=True, block_q=100)
-    # explicit argument beats the env override
-    monkeypatch.setenv("PADDLE_TPU_FLASH_BLOCKS", "256,256")
+    with pytest.raises(ValueError, match="must divide"):
+        _block_sizes(256, 200, 128, None)   # no block divides the other
+    # explicit arguments beat the heuristic, which alone never raises
     assert _block_sizes(256, 256, 128, 128) == (128, 128)
-    # env still applies when no explicit argument is given
     assert _block_sizes(256, 256) == (256, 256)
+    assert _block_sizes(256, 200) == (256, None)
 
 
-def test_partial_explicit_block_keeps_env_for_other_side(monkeypatch):
-    """Precedence holds per side: an explicit block_q plus a fleet-wide
-    env pin means the env still governs block_k (heuristic only when
-    the env side does not divide)."""
+def test_partial_explicit_block_keeps_heuristic_for_other_side():
     from paddle_tpu.ops.pallas.attention import _block_sizes
 
-    monkeypatch.setenv("PADDLE_TPU_FLASH_BLOCKS", "256,256")
-    assert _block_sizes(512, 512, 128, None) == (128, 256)
-    assert _block_sizes(512, 512, None, 128) == (256, 128)
-    # env side that does not divide falls to the heuristic
-    monkeypatch.setenv("PADDLE_TPU_FLASH_BLOCKS", "256,384")
     assert _block_sizes(512, 512, 128, None) == (128, 512)
-    # malformed env still raises, even on the explicit branch
-    monkeypatch.setenv("PADDLE_TPU_FLASH_BLOCKS", "nope")
-    with pytest.raises(ValueError, match="two ints"):
-        _block_sizes(512, 512, 128, None)
+    assert _block_sizes(512, 768, None, 128) == (512, 128)
 
 
 def test_dispatch_choice_is_counted_with_the_rule_that_decided():
@@ -423,10 +362,10 @@ def test_dispatch_choice_is_counted_with_the_rule_that_decided():
     trace lands in `kernel_dispatch_total{op, impl, rule}`."""
     import jax.numpy as jnp
 
+    from paddle_tpu.fluid.core.registry import LowerContext, get_op_def
     from paddle_tpu.ops import dispatch
     from paddle_tpu.ops.attention import scaled_dot_product_attention
-    from paddle_tpu.ops.pallas.decode_attention import decode_attention
-    from paddle_tpu.ops.pallas.paged_attention import paged_decode_attention
+    from paddle_tpu.ops.pallas.matmul import matmul_bias_act
 
     before = dispatch.choices()
 
@@ -437,24 +376,19 @@ def test_dispatch_choice_is_counted_with_the_rule_that_decided():
     scaled_dot_product_attention(q, q, q)
     assert delta(("attention", "naive", "backend is not a TPU")) == 1
 
-    dq = jnp.ones((2, 2, 64), jnp.float32)
-    cache = jnp.ones((2, 128, 2, 64), jnp.float32)
-    lens = jnp.asarray([3, 5], jnp.int32)
-    decode_attention(dq, cache, cache, lens)
-    assert delta(("decode_attention", "reference",
-                  "backend is not a TPU")) == 1
-
-    pool = jnp.ones((3, 16, 2, 64), jnp.int8)
-    scale = jnp.ones((3, 16, 2), jnp.float32)
-    tables = jnp.asarray([[1], [2]], jnp.int32)
-    paged_decode_attention(dq, pool, pool, tables, lens, k_scale=scale,
-                           v_scale=scale)
-    assert delta(("paged_decode_attention", "gather reference",
-                  "int8 pool: the kernel reads float blocks only")) == 1
-    # an explicit interpret= request is the caller's choice, not a dispatch
-    decode_attention(dq, cache, cache, lens, interpret=True)
-    assert delta(("decode_attention", "reference",
-                  "backend is not a TPU")) == 1
+    x = jnp.ones((8, 16), jnp.float32)
+    w = jnp.ones((16, 8), jnp.float32)
+    get_op_def("matmul_bias_act").lower(
+        LowerContext(), {"X": [x], "Y": [w], "Bias": [jnp.ones((8,))]},
+        {"act_type": "relu", "x_num_col_dims": 1})
+    key = ("matmul_bias_act", "xla composition", "backend is not a TPU")
+    assert delta(key) == 1
+    # the kernel called directly is the caller's choice, not a dispatch
+    matmul_bias_act(jnp.ones((128, 128)), jnp.ones((128, 128)),
+                    jnp.ones((128,)), interpret=True)
+    assert delta(key) == 1 and not any(
+        k[0] == "matmul_bias_act" and k != key and delta(k)
+        for k in dispatch.choices())
 
 
 def test_dispatch_names_the_shape_rule_on_a_tpu(monkeypatch):
@@ -462,8 +396,6 @@ def test_dispatch_names_the_shape_rule_on_a_tpu(monkeypatch):
     import jax.numpy as jnp
 
     from paddle_tpu.ops.attention import _naive_reason
-    from paddle_tpu.ops.pallas import decode_attention as da
-    from paddle_tpu.ops.pallas import paged_attention as pa
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     q = jax.ShapeDtypeStruct((48, 512, 12, 64), jnp.bfloat16)
@@ -471,17 +403,31 @@ def test_dispatch_names_the_shape_rule_on_a_tpu(monkeypatch):
     short = jax.ShapeDtypeStruct((1, 8, 12, 64), jnp.float32)
     assert "shorter than 192" in _naive_reason(short, short, None, "BSHD")
 
-    pool16 = jax.ShapeDtypeStruct((9, 16, 12, 64), jnp.float32)
-    assert pa._reference_reason(pool16, False) == \
-        "block_size 16 is not a multiple of 128"
-    pool128 = jax.ShapeDtypeStruct((9, 128, 12, 64), jnp.float32)
-    assert pa._reference_reason(pool128, False) is None
-    pool1024 = jax.ShapeDtypeStruct((9, 1024, 12, 64), jnp.float32)
-    assert "MiB of VMEM" in pa._reference_reason(pool1024, False)
 
-    assert da._reference_reason(
-        jax.ShapeDtypeStruct((4, 1024, 12, 64), jnp.float32)) is None
-    assert "not a multiple of 64" in da._reference_reason(
-        jax.ShapeDtypeStruct((4, 1024, 12, 48), jnp.float32))
-    assert "cache length 1000" in da._reference_reason(
-        jax.ShapeDtypeStruct((4, 1000, 12, 64), jnp.float32))
+def test_no_environment_switch_picks_code_on_the_hot_paths():
+    """Kernels, models and the generation engine take their choices
+    from arguments and from what they can observe (the backend, the
+    shapes), never from the environment.  The one exception is named
+    debt: `PADDLE_TPU_GEMM_BLOCKS` in `ops/pallas/matmul.py`, on no
+    benchmark cell's path (ROADMAP D5)."""
+    import os
+    import re
+
+    import paddle_tpu
+
+    root = os.path.dirname(os.path.abspath(paddle_tpu.__file__))
+    reads = re.compile(r"os\.(environ|getenv|putenv)|from os import")
+    found = {}
+    for sub in ("ops", "models", "generation"):
+        for folder, _, files in os.walk(os.path.join(root, sub)):
+            for name in files:
+                if not name.endswith(".py"):
+                    continue
+                path = os.path.join(folder, name)
+                with open(path) as f:
+                    lines = [ln.strip() for ln in f if reads.search(ln)]
+                if lines:
+                    found[os.path.relpath(path, root)] = lines
+    assert set(found) <= {os.path.join("ops", "pallas", "matmul.py")}, found
+    assert all("PADDLE_TPU_GEMM_BLOCKS" in ln
+               for lines in found.values() for ln in lines), found

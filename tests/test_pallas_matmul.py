@@ -1,7 +1,6 @@
 """Pallas fused-epilogue GEMM (`ops.pallas.matmul`) vs the naive jnp
 composition (interpret mode on CPU): forward + gradients for every
-activation, the bf16-operand tolerance policy (mirrors the flash
-kernels' PADDLE_TPU_FLASH_ACC discipline), the explicit-block-size
+activation, the bf16-operand tolerance policy, the explicit-block-size
 contract (explicit beats env, non-divisors raise), the naive fallback
 for untileable shapes, and the op-level lowering.
 """
@@ -100,9 +99,8 @@ def test_approximate_gelu_fwd_and_grad():
 
 
 def test_bf16_operand_tolerance_policy():
-    """bf16 operands with f32 accumulation: the documented bound
-    mirrors the flash PADDLE_TPU_FLASH_ACC policy — forward within
-    2e-2, gradients within 5e-2 of the f32 oracle."""
+    """bf16 operands with f32 accumulation: the documented bound —
+    forward within 2e-2, gradients within 5e-2 of the f32 oracle."""
     x, w, b = _operands()
     xb, wb, bb = (x.astype(jnp.bfloat16), w.astype(jnp.bfloat16),
                   b.astype(jnp.bfloat16))

@@ -217,25 +217,43 @@ def test_replicated_gradient_lint_gate():
     assert dirty[0].fix == "zero_stage>=2"
 
 
-def test_zoo_bert_bhsd_layout_folds_clean(monkeypatch):
-    """The head-major (BHSD) BERT build materializes the exact
+def test_zoo_bert_bhsd_layout_folds_clean():
+    """A head-major (BHSD) attention block, as a model ported from a
+    framework that keeps heads major writes it, materializes the exact
     [B,S,H,D]<->[B,H,S,D] transpose pairs the hazard rule flags;
     TransposeFoldPass must cancel every one (flash layout attr flip)
     and survive verification."""
     from paddle_tpu.fluid import ir
+    from paddle_tpu.fluid.layers.common import append_simple_op
 
-    monkeypatch.setenv("PADDLE_TPU_BERT_HEAD_LAYOUT", "BHSD")
+    b, s, h, d = 4, 64, 4, 32
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup):
-        _GATE["bert_small"][0]()
+        x = layers.data("x", shape=[b, s, h * d], append_batch_size=False)
+
+        def heads():
+            proj = layers.fc(x, h * d, num_flatten_dims=2)
+            return layers.transpose(
+                layers.reshape(proj, [0, s, h, d]), [0, 2, 1, 3])
+
+        ctx = append_simple_op(
+            "flash_attention", {"Q": heads(), "K": heads(), "V": heads()},
+            {"scale": d ** -0.5, "causal": False, "layout": "BHSD"})
+        ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
+                             [0, s, h * d])
+        layers.fc(layers.gelu(layers.fc(ctx, 4 * h * d, num_flatten_dims=2)),
+                  h * d, num_flatten_dims=2)
     hazards = _perf_findings(main, ("layout-transpose-hazard",))
     assert hazards, "BHSD build emitted no transpose hazard: gate vacuous"
+    assert _perf_findings(main, ("unfused-epilogue",))
     folded = ir.clone_and_apply(
         main, ["transpose_fold", "matmul_bias_act_fuse"], verify=True)
     assert not _perf_findings(
         folded, ("layout-transpose-hazard", "unfused-epilogue"))
-    types = [op.type for op in folded.global_block.ops]
-    assert "transpose2" not in types
+    ops = folded.global_block.ops
+    assert "transpose2" not in [op.type for op in ops]
+    assert [op.attrs["layout"] for op in ops
+            if op.type == "flash_attention"] == ["BSHD"]
 
 
 # ---------------------------------------------------------------------------

@@ -5,7 +5,7 @@ that is described, not attached: what Mosaic or XLA:TPU refuses on the
 chip it refuses here too (a `dot_general` with no non-contracting
 dimension, a primitive without a TPU lowering, a block over the VMEM
 limit), at no chip time.  Interpret-mode tests cannot see any of that —
-both decode kernels passed every one of them and compiled for no chip
+two decode kernels passed every one of them and compiled for no chip
 until PR 22.  Nothing runs: a compile that passes says nothing about
 results or times.
 
@@ -29,10 +29,8 @@ from jax.sharding import SingleDeviceSharding
 
 CUSTOM_CALL = 'custom_call_target="tpu_custom_call"'
 
-# BERT-base attention shapes (bench.py: B=48, S=512) and the
-# GPT-2-small decode shapes of benchmarks/generation_bench.py
+# BERT-base attention shapes (bench.py: B=48, S=512)
 B, S, H, D = 48, 512, 12, 64
-SLOTS, T, BS = 8, 1024, 128
 
 
 @pytest.fixture(scope="module")
@@ -101,47 +99,6 @@ def test_flash_attention_causal_prefill(compile_for_chip, seq):
         lambda q, k, v: flash_attention(q, k, v, causal=True, layout="BSHD",
                                         interpret=False), qkv, qkv, qkv)
     assert CUSTOM_CALL in hlo
-
-
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_paged_decode_kernel(compile_for_chip, dtype):
-    from paddle_tpu.ops.pallas.paged_attention import paged_decode_attention
-
-    nb = T // BS
-    pool = ((SLOTS * nb + 1, BS, H, D), dtype)
-    hlo = compile_for_chip(
-        lambda q, kp, vp, tab, ln: paged_decode_attention(
-            q, kp, vp, tab, ln, interpret=False),
-        ((SLOTS, H, D), dtype), pool, pool,
-        ((SLOTS, nb), jnp.int32), ((SLOTS,), jnp.int32))
-    assert CUSTOM_CALL in hlo
-
-
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_dense_decode_kernel(compile_for_chip, dtype):
-    from paddle_tpu.ops.pallas.decode_attention import decode_attention
-
-    cache = ((SLOTS, T, H, D), dtype)
-    hlo = compile_for_chip(
-        lambda q, k, v, ln: decode_attention(q, k, v, ln, interpret=False),
-        ((SLOTS, H, D), dtype), cache, cache, ((SLOTS,), jnp.int32))
-    assert CUSTOM_CALL in hlo
-
-
-def test_decode_block_choice_fits_vmem():
-    """The dense kernel's 512-row float32 block compiled alone and ran
-    out of scoped VMEM inside the whole GPT-2-small decode step (16.02 of
-    16.00 MiB): the block is now chosen against a VMEM budget."""
-    from paddle_tpu.ops.pallas.decode_attention import (
-        _KV_VMEM_BUDGET,
-        _pick_block_k,
-        kv_block_vmem_bytes,
-    )
-
-    assert kv_block_vmem_bytes(512, H, D, jnp.float32) > _KV_VMEM_BUDGET
-    assert _pick_block_k(T, H, D, jnp.float32) == 256
-    assert _pick_block_k(T, H, D, jnp.bfloat16) == 512
-    assert _pick_block_k(T + 64, H, D, jnp.float32) is None
 
 
 # the BERT-base FFN GEMM: [B*S, 768] x [768, 3072]
@@ -321,3 +278,47 @@ def test_cached_steps_write_the_kv_cache_in_place(
         # and the temporaries are a few chunks, not a view (the parent's
         # step held 25.5 MB of them at these widths and two layers)
         assert mem.temp_size_in_bytes < 16 << 20, mem.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("form", ["bfloat16", "int8", "block128"])
+def test_decode_step_compiles_for_the_other_cache_forms(
+        cache_width_lm, one_chip, no_persistent_cache, form):
+    """The engine's decode step for the cache forms no cell runs,
+    compiled for the described chip (interpret mode and the CPU pass
+    what Mosaic and XLA:TPU refuse): a bfloat16 cache (the step function
+    takes the cache's type from its operands), an int8 pool with its
+    scales, and blocks of 128, one a chunk of the walk.  As for the
+    cell's form: the outputs alias the whole cache, two `while` loops a
+    layer, no kernel, temporaries of a few chunks."""
+    import numpy as np
+
+    from paddle_tpu import generation
+
+    engine = generation.GenerationEngine(
+        cache_width_lm, slots=16, max_len=1024,
+        block_size=128 if form == "block128" else 16,
+        kv_dtype="int8" if form == "int8" else None,
+        donate=True, logprobs=True)
+    operands = engine._decode_operands()
+    nc = engine._nc
+    assert nc == (4 if form == "int8" else 2) * CACHE_LAYERS
+    held = {a.dtype.name for a in operands[1:1 + nc]}
+    assert held == ({"int8", "float32"} if form == "int8" else {"float32"})
+
+    def spec(a, dtype=None):
+        return jax.ShapeDtypeStruct(np.shape(a), dtype or a.dtype,
+                                    sharding=one_chip)
+
+    as_cache = jnp.bfloat16 if form == "bfloat16" else None
+    specs = (jax.tree_util.tree_map(spec, operands[0]),
+             *(spec(a, as_cache) for a in operands[1:1 + nc]),
+             *jax.tree_util.tree_map(spec, operands[1 + nc:]))
+    compiled = engine._decode_step_fn.lower(*specs).compile()
+    cache_bytes = sum(int(np.prod(s.shape)) * s.dtype.itemsize
+                      for s in specs[1:1 + nc])
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < 16 << 20, mem.temp_size_in_bytes
+    hlo = compiled.as_text()
+    assert hlo.count(" while(") == 2 * CACHE_LAYERS
+    assert CUSTOM_CALL not in hlo

@@ -424,7 +424,7 @@ def test_inference_server_autotune_adopts_winner_ladder(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# step-variant search (the bench.py --autotune front end)
+# step-variant search
 # ---------------------------------------------------------------------------
 
 
@@ -556,53 +556,6 @@ def test_autotune_cli_flash_mode(tmp_path, capsys):
     # malformed shape -> rc 1
     assert at.main(["--flash", "1,2,128"]) == 1
     capsys.readouterr()
-
-
-# ---------------------------------------------------------------------------
-# bench.py --autotune: conventions survive, tuned vs default reported
-# ---------------------------------------------------------------------------
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def test_bench_autotune_preserves_skip_convention():
-    """--autotune must not break the driver contract: an infra failure
-    still yields ONE {"skipped": true} line and rc 0."""
-    import subprocess
-    import sys
-
-    env = dict(os.environ, BENCH_FORCE_BACKEND_FAIL="init",
-               JAX_PLATFORMS="cpu")
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "--autotune"],
-        capture_output=True, text=True, timeout=300, env=env)
-    assert r.returncode == 0, r.stderr[-2000:]
-    out = json.loads(r.stdout.strip().splitlines()[-1])
-    assert out["skipped"] is True
-
-
-@pytest.mark.slow
-def test_bench_autotune_reports_tuned_vs_default(tmp_path):
-    """Real CPU smoke run: the output JSON carries tuned vs default step
-    time, the winner, and the platform/smoke_config fields that keep a
-    CPU capture from impersonating TPU tuning numbers."""
-    import subprocess
-    import sys
-
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               PADDLE_TPU_TUNE_CACHE=str(tmp_path))
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "--autotune"],
-        capture_output=True, text=True, timeout=550, env=env)
-    assert r.returncode == 0, r.stderr[-2000:]
-    out = json.loads(r.stdout.strip().splitlines()[-1])
-    assert out["platform"] == "cpu" and out["smoke_config"] is True
-    at = out["autotune"]
-    assert at["cache_hit"] is False
-    assert at["tuned_step_ms"] <= at["default_step_ms"] + 1e-9
-    assert at["winner"]["status"] in ("timed", "cached")
-    assert at["counts"]["timed"] >= 1
-    assert at["platform"] == "cpu"
 
 
 def test_autotune_cli_reports_excluded_pass_by_name(tmp_path, capsys):
