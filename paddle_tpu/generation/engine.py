@@ -20,7 +20,10 @@ The execution model, and why the executable set stays enumerable:
   Attention walks only the live slots, each as far as it reaches: who
   is live is data too (the table rows `_decode_tables` zeroes; a dense
   engine sends its active mask), and `generation_attn_walk_share`
-  says each step what part of slots x positions that was.
+  says each step what part of slots x positions that was.  Sampling
+  (`sampling.sample_tokens`) is one argmax in a step whose live rows
+  are all greedy, a parked slot reading as greedy (`_park`);
+  `generation_sampling_step_share` says how many steps were not.
 * **paged KV** (the PR-17 rebuild) — the store is a block pool, one
   ``[num_blocks, block_size, H*D]`` array per layer for K and for V
   (`kv_cache` says why that shape), plus a host per-slot block table
@@ -585,6 +588,12 @@ class GenerationEngine:
             "over slots x positions", labelnames=lbl,
             buckets=(0.01, 0.02, 0.05, 0.1, 0.2, 0.5, 1.0)
         ).labels(self._engine)
+        self._m_sampling = reg.histogram(
+            "generation_sampling_step_share",
+            "1 for a decode/verify step with a live sampling row (the "
+            "sampler's selections run), 0 for an all-greedy one (one "
+            "argmax)", labelnames=lbl, buckets=(0.0, 1.0)
+        ).labels(self._engine)
         self._m_sched_host = reg.histogram(
             "generation_sched_host_ms",
             "A step() that decoded, less its time in device calls (ms)",
@@ -922,7 +931,7 @@ class GenerationEngine:
             cs = self._chunking[slot]
             st = _Slot(cs.request, cs.handle)
             self._chunking[slot] = None
-        self._active[slot] = False
+        self._park(slot)
         self._release_blocks(slot)
         self._free.append(slot)
         st.handle._restart()
@@ -956,11 +965,28 @@ class GenerationEngine:
     def _fail_slot(self, slot, msg):
         st = self._slot_state[slot]
         self._slot_state[slot] = None
-        self._active[slot] = False
+        self._park(slot)
         if self.paged:
             self._release_blocks(slot)
         self._free.append(slot)
         st.handle._fail(msg)
+
+    def _park(self, slot):
+        """Nobody decodes in ``slot`` any more, and it reads as greedy:
+        a step whose live rows are all greedy is then all greedy, and
+        the sampler's one argmax."""
+        self._active[slot] = False
+        self._temp[slot] = 0.0
+        self._top_k[slot] = 0
+        self._top_p[slot] = 1.0
+
+    def _step_shares(self, rows):
+        """The `generation.decode_dispatch` arguments of the step about
+        to run, ``rows`` new tokens a slot."""
+        sampling = float((self._temp[self._active] > 0.0).any())
+        self._m_sampling.observe(sampling)
+        return {"attn_walk_share": self._walk_share(rows),
+                "sampling_step_share": sampling}
 
     def _walk_share(self, rows):
         """`generation_attn_walk_share` of the step about to run, its
@@ -1385,7 +1411,7 @@ class GenerationEngine:
         operands = self._decode_operands()
         t0 = time.perf_counter()
         with _DeviceCall(self, "generation.decode_dispatch",
-                         args={"attn_walk_share": self._walk_share(1)}):
+                         args=self._step_shares(1)):
             with _TRACE_LOCK:
                 out = self._decode_step_fn(*operands)
         # the host waits here while the device works
@@ -1453,7 +1479,7 @@ class GenerationEngine:
             [self._last_tokens[:, None], drafts], axis=1).astype(np.int32)
         tables = self._decode_tables()
         with _DeviceCall(self, "generation.decode_dispatch",
-                         args={"attn_walk_share": self._walk_share(k + 1)}):
+                         args=self._step_shares(k + 1)):
             with _TRACE_LOCK:
                 out = self._verify_fn(
                     self._params, *self.cache.arrays(), self._lengths,
@@ -1508,7 +1534,7 @@ class GenerationEngine:
         st = self._slot_state[slot]
         st.handle._finish(reason)
         self._slot_state[slot] = None
-        self._active[slot] = False
+        self._park(slot)
         if self.paged:
             self._release_blocks(slot)
         self._free.append(slot)
@@ -1529,7 +1555,7 @@ class GenerationEngine:
                 self._chunking[slot] = None
             if self.paged and self._slot_blocks[slot]:
                 self._release_blocks(slot)
-        self._active[:] = False
+        self._park(slice(None))
         for _, handle in self._pending:
             affected.append(handle)
         self._pending = []
@@ -1879,6 +1905,7 @@ class GenerationEngine:
             "preempted": int(self._m_preempt.value),
             # mean over the decode/verify steps so far (None before one)
             "attn_walk_share": self._m_walk.summary().get("mean"),
+            "sampling_step_share": self._m_sampling.summary().get("mean"),
         })
         ex = {
             "decode_step": self._decode_cache_size(),

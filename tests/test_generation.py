@@ -166,6 +166,164 @@ class TestSampling:
         assert len(set(outs)) == 1
 
 
+def sort_sampler(logits, keys, steps, temperature, top_k, top_p):
+    """The plain reference: the sampler as it stood before PR 32, which
+    sorts the vocabulary once for the top-k cut and again for top-p.
+    Returns what top-k left, what top-p then left (dropped entries at
+    -1e30) and the tokens."""
+    import jax
+    import jax.numpy as jnp
+
+    neg = -1e30
+    logits = jnp.asarray(logits).astype(jnp.float32)
+    n, v = logits.shape
+    greedy = temperature <= 0.0
+    safe_t = jnp.where(greedy, 1.0, temperature)
+    scaled = logits / safe_t[:, None]
+
+    sorted_desc = jnp.sort(scaled, axis=-1)[:, ::-1]
+    k_idx = jnp.clip(top_k - 1, 0, v - 1)
+    kth = jnp.take_along_axis(sorted_desc, k_idx[:, None], axis=-1)
+    after_k = jnp.where((top_k > 0)[:, None] & (scaled < kth), neg, scaled)
+
+    sorted2 = jnp.sort(after_k, axis=-1)[:, ::-1]
+    probs = jax.nn.softmax(sorted2, axis=-1)
+    cum = jnp.cumsum(probs, axis=-1)
+    keep = (cum - probs) < top_p[:, None]      # mass BEFORE the token
+    keep = keep.at[:, 0].set(True)             # argmax always survives
+    thresh = jnp.min(jnp.where(keep, sorted2, jnp.inf), axis=-1)
+    after_p = jnp.where(
+        (top_p < 1.0)[:, None] & (after_k < thresh[:, None]), neg, after_k)
+
+    step_keys = jax.vmap(jax.random.fold_in)(keys, steps)
+    sampled = jax.vmap(jax.random.categorical)(step_keys, after_p)
+    tokens = jnp.where(greedy, jnp.argmax(logits, axis=-1), sampled)
+    return after_k, after_p, tokens.astype(jnp.int32)
+
+
+def selection_sampler(logits, keys, steps, temperature, top_k, top_p):
+    """The same three results from the sampler under test."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.generation import sampling
+
+    samples = temperature > 0.0
+    scaled = (jnp.asarray(logits).astype(jnp.float32)
+              / jnp.where(samples, temperature, 1.0)[:, None])
+    return (*sampling._cut(scaled, samples, top_k, top_p),
+            sampling.sample_tokens(logits, keys, steps, temperature,
+                                   top_k, top_p))
+
+
+def grid_policies(v):
+    """(temperature, top_k, top_p) of the grid's 16 rows; row 14's
+    logits are all equal."""
+    return [(0.0, 0, 1.0), (0.0, 3, 0.5), (0.7, 0, 1.0), (1.0, 1, 1.0),
+            (1.0, 3, 1.0), (1.0, 40, 1.0), (1.0, v, 1.0), (1.0, v + 5, 1.0),
+            (1.0, 0, 1e-9), (1.0, 0, 0.5), (1.0, 0, 0.95),
+            (0.8, 40, 0.95), (1.3, 3, 0.5), (1.0, v + 5, 0.95),
+            (1.0, 3, 0.5), (0.8, 40, 0.0)]
+
+
+def compare_samplers(v, n, dtype, seed=0):
+    """The selection sampler against the sort sampler on the grid's 16
+    rows, ``n`` of them a call, with continuous logits and with logits
+    rounded to 1/4 (ties).  Asserts: the top-k survivors equal, ties
+    included; the sets top-p keeps equal but for tokens whose mass of
+    strictly larger survivors is within 1e-5 of ``top_p``; the tokens
+    equal wherever those sets are.  Returns how many rows had such a
+    token."""
+    import jax
+    import jax.numpy as jnp
+
+    policies = grid_policies(v)
+    rng = np.random.RandomState(seed)
+    old, new = jax.jit(sort_sampler), jax.jit(selection_sampler)
+    boundary_rows = 0
+    for tied in (False, True):
+        logits = 3.0 * rng.randn(len(policies), v)
+        if tied:
+            logits = np.round(logits * 4) / 4
+        logits[14] = 0.5
+        logits = jnp.asarray(logits.astype(np.float32)).astype(dtype)
+        keys = rng.randint(0, 2 ** 31, (len(policies), 2)).astype(np.uint32)
+        steps = rng.randint(0, 200, len(policies)).astype(np.int32)
+        temp, top_k, top_p = (
+            np.asarray(c, t) for c, t in zip(
+                zip(*policies), (np.float32, np.int32, np.float32)))
+        for lo in range(0, len(policies), n):
+            rows = slice(lo, lo + n)
+            args = (logits[rows], keys[rows], steps[rows], temp[rows],
+                    top_k[rows], top_p[rows])
+            want = [np.asarray(a) for a in old(*args)]
+            got = [np.asarray(a) for a in new(*args)]
+            for r in range(n):
+                where = (v, n, str(dtype), tied, lo + r)
+                if temp[lo + r] <= 0.0:
+                    assert got[2][r] == want[2][r] == np.argmax(
+                        np.asarray(logits[lo + r], np.float32)), where
+                    continue
+                np.testing.assert_array_equal(
+                    got[0][r], want[0][r], err_msg=str(where))
+                differs = np.nonzero(got[1][r] != want[1][r])[0]
+                if len(differs):
+                    boundary_rows += 1
+                    vals = want[0][r].astype(np.float64)
+                    live = vals > -1e29
+                    p = np.where(live, np.exp(vals - vals[live].max()), 0.0)
+                    p /= p.sum()
+                    for j in differs:
+                        before = p[vals > vals[j]].sum()
+                        assert abs(before - top_p[lo + r]) <= 1e-5, (
+                            where, j, before)
+                else:
+                    assert got[2][r] == want[2][r], where
+    return boundary_rows
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("n", [1, 16])
+@pytest.mark.parametrize("v", [33, 1000, 50257])
+def test_selection_sampler_equals_the_sort_sampler(v, n, dtype):
+    compare_samplers(v, n, dtype)
+
+
+def test_select_is_the_kth_largest_and_the_mass_cut():
+    """`_select` alone, against numpy: the k-th largest of a row for any
+    k, negative values, both zeros and ties included, and the largest
+    key whose mass at or above reaches a target; no round for a call
+    whose rows are all off."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.generation import sampling
+
+    rng = np.random.RandomState(7)
+    x = np.round(rng.randn(6, 97) * 8).astype(np.float32) / 4
+    x[0, :5] = [0.0, -0.0, 0.0, -0.0, 1e-38]
+    x[1] = -np.abs(x[1]) - 1.0
+    x[2] = 2.5
+    keys = sampling._ordered(jnp.asarray(x))
+    np.testing.assert_array_equal(
+        np.asarray(sampling._unordered(keys)).view(np.uint32),
+        x.view(np.uint32))
+    on = np.ones(6, bool)
+    for k in (1, 2, 17, 96, 97):
+        got = np.asarray(sampling._unordered(sampling._select(
+            keys, None, np.full(6, k, np.int32), on)))
+        np.testing.assert_array_equal(got, np.sort(x, axis=-1)[:, -k])
+    w = rng.rand(6, 97).astype(np.float32)
+    target = np.full(6, 0.3 * 97 / 2, np.float32)
+    got = np.asarray(sampling._unordered(sampling._select(
+        keys, jnp.asarray(w), target, on)))
+    for r in range(6):
+        reach = [t for t in np.unique(x[r])
+                 if w[r][x[r] >= t].sum(dtype=np.float64) >= target[r]]
+        assert got[r] == max(reach)
+    off = np.asarray(sampling._select(keys, None, np.ones(6, np.int32),
+                                      np.zeros(6, bool)))
+    np.testing.assert_array_equal(off, np.zeros(6, np.uint32))
+
+
 # ---------------------------------------------------------------------------
 # model: decode path == full forward
 # ---------------------------------------------------------------------------
@@ -1118,3 +1276,143 @@ def test_tune_generation_block_and_draft_axes():
     legacy = generation_config_candidates(slot_counts=(4,), max_len=128)
     assert legacy[0].label == "slots4"
     assert "block_size" not in legacy[0].params
+
+
+# ---------------------------------------------------------------------------
+# the sampler inside the step functions: no sort, and one argmax for a
+# step whose live rows are all greedy
+# ---------------------------------------------------------------------------
+
+
+def _sampled(seed, **kw):
+    kw.setdefault("temperature", 0.8)
+    kw.setdefault("top_k", 40)
+    kw.setdefault("top_p", 0.95)
+    return gen.SamplingParams(seed=seed, **kw)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill", "chunk", "verify",
+                                     "the sort sampler"])
+def test_generation_programs_sort_nothing(lm, program):
+    """Lowered at the tiny size, no generation program holds a `sort`
+    (or a `top_k`): the policies are operands, so this is the program
+    for the cell's policies and for any others.  The reference sampler's
+    text, lowered the same way, is what the search would find."""
+    import jax
+
+    if program == "the sort sampler":
+        n, v = 3, CFG.vocab_size
+        text = jax.jit(sort_sampler).lower(
+            np.zeros((n, v), np.float32), np.zeros((n, 2), np.uint32),
+            np.zeros(n, np.int32), np.ones(n, np.float32),
+            np.ones(n, np.int32), np.ones(n, np.float32)).as_text()
+        assert "stablehlo.sort" in text
+        return
+    with dygraph.guard():
+        np.random.seed(1)
+        draft = models.TransformerLM(CFG)
+    eng = make_engine(lm, prefill_chunk=4, draft_model=draft, draft_len=2)
+    arrays = eng.cache.arrays()
+    table = eng.cache.table_row(0)[None].astype(np.int32)
+    policy = (np.float32(0.8), np.int32(40), np.float32(0.95))
+    key = np.zeros(2, np.uint32)
+    fn, operands = {
+        "decode": (eng._decode_step_fn, eng._decode_operands()),
+        "prefill": (eng._prefill_fns[8], (
+            eng._params, *arrays, np.zeros((1, 8), np.int32), np.int32(8),
+            table, key, *policy)),
+        "chunk": (jax.jit(eng._make_chunk_fn(4)), (     # jitted lazily
+            eng._params, *arrays, np.zeros((1, 4), np.int32), np.int32(0),
+            table, np.int32(3), key, *policy)),
+        "verify": (eng._verify_fn, (
+            eng._params, *arrays, eng._lengths,
+            np.zeros((eng.slots, 3), np.int32), eng._keys, eng._steps,
+            eng._temp, eng._top_k, eng._top_p, eng._decode_tables())),
+    }[program]
+    text = fn.lower(*operands).as_text()
+    assert "stablehlo.sort" not in text and "top_k" not in text
+    assert "stablehlo.while" in text and "stablehlo.case" in text
+
+
+@pytest.mark.parametrize("how", ["finish", "preempt", "fail", "death"])
+def test_a_parked_slot_reads_as_greedy(lm, how):
+    """However a sampled request leaves its slot, the slot's policy goes
+    back to greedy (0, 0, 1): a free slot must not make a step of greedy
+    requests take the sampling branch."""
+    eng = make_engine(lm, slots=2)
+    h = eng.submit(gen.GenerationRequest(
+        [5, 6, 7], max_new_tokens=6, sampling=_sampled(3)))
+    eng.step()
+    slot = int(np.nonzero(eng._active)[0][0])
+    assert eng._temp[slot] == np.float32(0.8) and eng._top_k[slot] == 40
+    if how == "finish":
+        eng.run_until_idle()
+        assert len(h.result()) == 6
+    elif how == "preempt":
+        with eng._lock:
+            eng._preempt_slot(slot, "test")
+    elif how == "fail":
+        with eng._lock:
+            eng._fail_slot(slot, "test")
+    else:
+        eng._die("test")
+    assert not eng._active.any()
+    np.testing.assert_array_equal(eng._temp, np.zeros(2, np.float32))
+    np.testing.assert_array_equal(eng._top_k, np.zeros(2, np.int32))
+    np.testing.assert_array_equal(eng._top_p, np.ones(2, np.float32))
+    if how == "preempt":        # restarted from the queue, same stream
+        eng.run_until_idle()
+        fresh = make_engine(lm, slots=2).generate(
+            [[5, 6, 7]], max_new_tokens=6, sampling=_sampled(3))
+        assert h.result() == fresh[0]
+
+
+@pytest.mark.parametrize("mix,share", [("greedy", 0.0), ("sampled", 1.0),
+                                       ("both", None)])
+def test_sampling_step_share_counts_steps_with_a_sampling_row(lm, mix,
+                                                              share):
+    """`generation_sampling_step_share`: 1 for a decode step with a live
+    sampling row, 0 for an all-greedy one; its mean in `stats()`, the
+    family on /metrics.  In the mixed run the sampled request ends
+    first, so the steps after it are greedy again."""
+    from paddle_tpu.observability.metrics import MetricsRegistry
+
+    reg = MetricsRegistry()
+    eng = make_engine(lm, metrics_registry=reg)
+    assert eng.stats()["sampling_step_share"] is None
+    policies = {"greedy": [None, None], "sampled": [_sampled(1), _sampled(2)],
+                "both": [None, _sampled(1)]}[mix]
+    news = [9, 4] if mix == "both" else [5, 5]
+    handles = [eng.submit(gen.GenerationRequest(
+        [3 + i, 4, 5], max_new_tokens=n, sampling=sp))
+        for i, (sp, n) in enumerate(zip(policies, news))]
+    eng.run_until_idle()
+    assert [len(h.result()) for h in handles] == news
+    got = eng.stats()["sampling_step_share"]
+    summary = eng._m_sampling.summary()
+    assert summary["count"] == eng.stats()["decode_steps"]
+    if share is None:
+        # the prefill gave token 0: 3 more steps with the sampled
+        # request live, then 5 with the greedy one alone
+        assert got == pytest.approx(3 / 8)
+    else:
+        assert got == share
+    text = reg.prometheus_text()
+    assert "generation_sampling_step_share_count" in text
+    assert "generation_sampling_step_share_sum" in text
+
+
+def test_a_greedy_stream_does_not_depend_on_its_neighbours_policy(lm):
+    """A greedy request decodes the same tokens whether the other slots
+    are greedy (every step the argmax branch), sampling (every step the
+    selection branch) or empty."""
+    prompt, new = [9, 8, 7, 6], 8
+    alone = make_engine(lm).generate([prompt], max_new_tokens=new)[0]
+    for neighbours in ([None, None], [_sampled(4), _sampled(5)]):
+        eng = make_engine(lm)
+        out = eng.generate(
+            [prompt, [1, 2, 3], [4, 5]], max_new_tokens=new,
+            sampling=[None] + neighbours)
+        assert out[0] == alone
+        assert eng.stats()["sampling_step_share"] == float(
+            neighbours[0] is not None)

@@ -225,6 +225,8 @@ HISTOGRAMS = [      # family, registry, observations the script makes
     ("generation_sched_host_ms", "served", lambda r: r["decode_steps"]),
     ("generation_itl_ms", "served", lambda r: r["decode_steps"]),
     ("generation_attn_walk_share", "served", lambda r: r["decode_steps"]),
+    ("generation_sampling_step_share", "served",
+     lambda r: r["decode_steps"]),
     ("generation_prefill_ms", "served", lambda r: REQUESTS),
     ("train_step_dispatch_ms", "train", lambda r: TRAIN_STEPS),
     ("io_step_wait_ms", "train", lambda r: TRAIN_STEPS),
@@ -254,6 +256,10 @@ def test_decode_dispatch_spans_carry_the_walk_share(scripted):
     assert all(0.0 < x <= 1.0 for x in shares)
     series = scripted["served"]["generation_attn_walk_share"]["series"]
     assert sum(s["sum"] for s in series) == pytest.approx(sum(shares))
+    # and whether a live row sampled: the script's requests are greedy
+    assert [float(s.args["sampling_step_share"]) for s in scripted["spans"]
+            if s.name == "generation.decode_dispatch"
+            and s.thread in loop] == [0.0] * len(shares)
 
 
 def test_a_step_function_is_known_by_its_name():

@@ -259,7 +259,9 @@ def test_cached_steps_write_the_kv_cache_in_place(
         assert not bad, (name, bad)
         if name != "decode":
             continue
-        assert hlo.count(" while(") == 2 * CACHE_LAYERS
+        # the walk's two loops a layer, and the sampler's two selections
+        assert hlo.count(" while(") == 2 * CACHE_LAYERS + 2
+        assert " sort(" not in hlo
         # slots x max_len x H*D elements: the gathered view, in either
         # order of its first two dimensions.  A dense cache IS such an
         # array, so there only its own in-place scatters may make one.
@@ -289,7 +291,8 @@ def test_decode_step_compiles_for_the_other_cache_forms(
     takes the cache's type from its operands), an int8 pool with its
     scales, and blocks of 128, one a chunk of the walk.  As for the
     cell's form: the outputs alias the whole cache, two `while` loops a
-    layer, no kernel, temporaries of a few chunks."""
+    layer and the sampler's two, no kernel, temporaries of a few
+    chunks."""
     import numpy as np
 
     from paddle_tpu import generation
@@ -320,5 +323,5 @@ def test_decode_step_compiles_for_the_other_cache_forms(
     assert mem.alias_size_in_bytes >= cache_bytes
     assert mem.temp_size_in_bytes < 16 << 20, mem.temp_size_in_bytes
     hlo = compiled.as_text()
-    assert hlo.count(" while(") == 2 * CACHE_LAYERS
+    assert hlo.count(" while(") == 2 * CACHE_LAYERS + 2
     assert CUSTOM_CALL not in hlo
