@@ -7,18 +7,24 @@ import math
 import numpy as np
 
 from chipbench import flops
-from chipbench.common import need, say
+from chipbench.common import say
 from chipbench.references import bert as reference
 
 # The program's float32 forward (flash kernels, fused projections) and
 # the plain reference sum in different orders: they agree to ~1e-5 on a
 # loss near 11.  bfloat16 products would miss by 1e-2 or more.
 REFERENCE_LOSS_ATOL = 2e-3
-# The timed step runs bf16 AMP with dropout 0.1, the reference neither:
-# its first loss can only be held to the reference's loosely (the
-# reference is taken on 4 of the batch's 48 sequences besides: over 17
-# chip runs the two differed by 0.01 to 0.11, PR 25).
-TRAIN_FIRST_LOSS_ATOL = 0.25
+# The timed step runs bf16 AMP with dropout 0.1, the reference neither,
+# and the reference is taken on 4 of the batch's 48 sequences (or 192):
+# most of what this number reads is those 4 rows' own scatter.  Over 17
+# chip runs the two differed by 0.01 to 0.11 (PR 25), over 17 more seeds
+# by 0.008 to 0.184 (root mean square 0.082; PR 34), and a limit of 0.25
+# would fail one sound run in 400.  It has no upper reading: a model
+# that predicts nothing reads 0.04 to 0.3 (the first loss is 0.27 above
+# chance).  Until the gradient-level check replaces it (PERF.md section
+# 7) the limit stands four times the root mean square above nought and
+# says only that the timed step's loss is a loss.
+TRAIN_FIRST_LOSS_ATOL = 0.35
 
 
 def build(config, seed):
@@ -87,9 +93,10 @@ def chance_loss(config):
 def reference_check(model, config, job, batch):
     """The program's deterministic float32 forward and loss against the
     plain reference, on the first ``reference_rows`` sequences of
-    ``batch`` and the initial weights.  Returns (ok, reference loss); the
-    runner holds the timed step's first loss (bf16, dropout) to the
-    latter within `TRAIN_FIRST_LOSS_ATOL`.  Run it before the first
+    ``batch`` and the initial weights.  Returns both losses; the runner
+    holds them together within `REFERENCE_LOSS_ATOL`, and the timed
+    step's first loss (bf16, dropout) to the reference's within
+    `TRAIN_FIRST_LOSS_ATOL`.  Run it before the first
     training step, which donates the parameters' buffers."""
     import jax
 
@@ -124,7 +131,4 @@ def reference_check(model, config, job, batch):
         want = float(plain(params, sample))
     say("reference", rows=rows, program_loss=got, reference_loss=want,
         abs_diff=abs(got - want), atol=REFERENCE_LOSS_ATOL)
-    ok = need(abs(got - want) <= REFERENCE_LOSS_ATOL,
-              "program forward loss %r differs from the plain reference's "
-              "%r by more than %g" % (got, want, REFERENCE_LOSS_ATOL))
-    return ok, want
+    return got, want

@@ -9,14 +9,20 @@ from chipbench.references import transformer_lm as reference
 # The engine multiplies float32 operands at the chip's default precision
 # (one bfloat16 pass); the reference multiplies them in float32.  With
 # random weights the logits have a standard deviation near 0.64, and the
-# rounding of the products moves a token's log-probability by about 1e-2
-# over 24 layers (measured on the chip, PERF.md).  A token scored at the
-# wrong position, a missing layer or a cache row out of place moves it by
-# 0.3 and more.  The tolerance is about twice the largest difference
-# measured (0.6e-2 to 1.4e-2 over 17 runs, PR 25); weights or a cache
-# held in another type than the configuration states are refused by
-# `holds_stated_precision`, not by this number.
-LOGPROB_ATOL = 3e-2
+# rounding of the products moves a token's log-probability by up to 2e-2
+# over 24 layers: the largest difference over the 6 requests (600-1,000
+# served tokens) a run compares read 1.2e-2 to 1.7e-2 over 13 seeds on the
+# chip, and 2.0e-2 once at a higher rate: the lower reading (PERF.md,
+# PR 34).  The upper reading is a fault's: a token altered where it is
+# emitted moves it by 0.65 at the least and 2.6 at the median (the chip,
+# PR 34), a token scored at the wrong position, a missing layer or a
+# cache row out of place by 0.3 and more (PR 25).  The limit stands 2.5
+# times above the one and 6 times below the other.  The reference
+# computed in bfloat16 throughout reads 1.5e-2 to 1.8e-2, the same as
+# the engine, which is arithmetically such a program: weights or a cache
+# *held* in another type than the configuration states are refused by
+# `holds_stated_precision`, exactly, not by this number.
+LOGPROB_ATOL = 5e-2
 
 
 def build(config, seed):

@@ -19,6 +19,16 @@ def need(cond, msg):
     return bool(cond)
 
 
+def held(checks, name, value, limit, msg):
+    """A check that compares a number with its limit (``value <= limit``):
+    kept in the run's ``checks`` under ``name``, for the result line and
+    the last lines of standard error, and recorded like `need` where it
+    fails."""
+    checks[name] = {"value": float(value), "limit": float(limit)}
+    return need(value <= limit, "%s: %s %r against the limit %r"
+                % (msg, name, value, limit))
+
+
 def snapshot():
     """The program's metrics registry as a JSON-able dict."""
     from paddle_tpu.observability import default_registry

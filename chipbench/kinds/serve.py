@@ -3,13 +3,15 @@
 PR 22).
 
 The parent holds the chip: it builds the model, the `GenerationFleet` and
-the HTTP front, checks the engine against the plain reference, warms the
-shapes the traffic can reach, and then lets `chipbench.loadgen`, a child
-process without JAX, send the schedule `chipbench.traffic` drew from the
-seed.  Arrivals start ``ramp_s`` before the window, longer than the
-longest request lives, so that the window opens on steady occupancy; the
-requests due inside the window are the sample."""
+the HTTP front, warms the shapes the traffic can reach, and then lets
+`chipbench.loadgen`, a child process without JAX, send the schedule
+`chipbench.traffic` drew from the seed.  Arrivals start ``ramp_s`` before
+the window, longer than the longest request lives, so that the window
+opens on steady occupancy; the requests due inside the window are the
+sample.  Once the window has closed and the server is down, a sample of
+what it served is held to the plain reference."""
 
+import gc
 import http.client
 import importlib
 import json
@@ -21,7 +23,7 @@ import threading
 import time
 
 from chipbench import common, stats, traffic
-from chipbench.common import need, say
+from chipbench.common import held, need, say
 
 
 def post(port, body, timeout=900.0):
@@ -37,38 +39,44 @@ def post(port, body, timeout=900.0):
         conn.close()
 
 
-def check_against_reference(builder, model, config, mix, port, rng):
-    """Greedy requests through the whole served path, then the engine's
-    log-probability of each token it emitted against the plain
-    reference's, teacher-forced on the same tokens."""
+def served_length(pair):
+    """Prompt and served tokens of a ``(prompt, record)`` pair."""
+    return len(pair[0]) + len(pair[1]["tokens"])
+
+
+def check_against_reference(builder, model, config, mix, finished, seed,
+                            checks):
+    """What the timed path served, against the plain reference: a sample
+    of the window's finished greedy requests, drawn from the seed with
+    the longest in it, each run once through the reference with its
+    served tokens (teacher-forced), and the engine's log-probability of
+    every served token compared with the reference's.  ``finished``:
+    ``(prompt, load generator's record)`` of the greedy requests that
+    came back whole.  Run once the window has closed."""
+    import random
+
     chk = mix["check"]
-    sequences, got = [], []
-    for i, plen in enumerate(chk["prompt_tokens"]):
-        prompt = [rng.randrange(config["vocab_size"]) for _ in range(plen)]
-        status, records = post(port, {
-            "request_id": "check-%d" % i, "prompt": prompt,
-            "max_new_tokens": chk["new_tokens"], "stream": True,
-            "timeout": 900.0})
-        toks = [r["token"] for r in records if "token" in r]
-        if not need(status == 200 and len(toks) == chk["new_tokens"]
-                    and records[-1].get("done")
-                    and "error" not in records[-1],
-                    "check request %d answered %s %r"
-                    % (i, status, records[-1:])):
-            return False
-        sequences.append((prompt, toks))
-        got.append([r["logprob"] for r in records if "token" in r])
-    want = builder.reference_logprobs(model, config, sequences,
-                                      chk["pad_to"])
-    worst = max(abs(a - b) for g, w in zip(got, want)
-                for a, b in zip(g, w))
-    say("reference", requests=len(sequences), new_tokens=chk["new_tokens"],
+    if not need(finished, "the window finished no greedy request to "
+                          "compare with the reference"):
+        return False
+    longest = max(finished, key=served_length)
+    rest = [pair for pair in finished if pair is not longest]
+    picked = [longest] + random.Random(seed).sample(
+        rest, min(chk["requests"] - 1, len(rest)))
+    got = [rec["logprobs"] for _, rec in picked]
+    want = [builder.reference_logprobs(
+        model, config, [(prompt, rec["tokens"])],
+        -(-served_length((prompt, rec)) // chk["pad_multiple"])
+        * chk["pad_multiple"])[0] for prompt, rec in picked]
+    worst = max(abs(a - b) for g, w in zip(got, want) for a, b in zip(g, w))
+    say("reference", requests=len(picked), of_finished_greedy=len(finished),
+        served_tokens=sum(len(w) for w in want),
+        longest_tokens=served_length(longest),
         max_logprob_diff=worst, atol=builder.LOGPROB_ATOL,
         engine_logprobs=got[0][:4], reference_logprobs=want[0][:4])
-    return need(worst <= builder.LOGPROB_ATOL,
-                "the engine's token log-probabilities differ from the "
-                "plain reference's by %g (tolerance %g)"
-                % (worst, builder.LOGPROB_ATOL))
+    return held(checks, "served_logprob_diff_max", worst, builder.LOGPROB_ATOL,
+                "the engine's log-probabilities of the tokens it served "
+                "in the window differ from the plain reference's")
 
 
 def run(ctx):
@@ -96,11 +104,8 @@ def run(ctx):
     ctx["mark"]("fleet-and-front")
     child = sampler_stop = None
     try:
-        ok = check_against_reference(builder, model, config, mix, port, rng)
-        ok &= builder.holds_stated_precision(config,
-                                             engine.stats()["cache"])
-        ctx["mark"]("reference-check")
-        # warm the other prefill shapes this traffic can reach
+        ok = builder.holds_stated_precision(config, engine.stats()["cache"])
+        # warm the prefill shapes this traffic can reach
         for i, plen in enumerate(mix["warmup_prompt_tokens"]):
             status, records = post(port, {
                 "request_id": "warm-%d" % i, "max_new_tokens": 2,
@@ -170,6 +175,8 @@ def run(ctx):
         except subprocess.TimeoutExpired:
             ok &= need(False, "the load generator did not finish")
         engine_stats = engine.stats()
+        peak = max(common.memory_peak(d.memory_stats() or {})
+                   for d in ctx["devices"])
     finally:
         if sampler_stop is not None:
             sampler_stop.set()
@@ -185,7 +192,7 @@ def run(ctx):
     records = result["records"]
     sample_ = [r for r in records if r["id"].startswith("w")]
     by_id = {p["body"]["request_id"]: p["body"] for p in plan}
-    ttft, gaps, per_token, failed, reasons = [], [], [], 0, {}
+    ttft, gaps, per_token, failed, reasons, whole = [], [], [], 0, {}, []
     for r in sample_:
         want = by_id[r["id"]]["max_new_tokens"]
         why = None
@@ -203,6 +210,7 @@ def run(ctx):
             failed += 1
             reasons[why] = reasons.get(why, 0) + 1
             continue
+        whole.append(r)
         ttft.append(1e3 * (r["token_times"][0] - r["due"]))
         gaps.extend(1e3 * (b - a) for a, b in
                     zip(r["token_times"], r["token_times"][1:]))
@@ -227,10 +235,22 @@ def run(ctx):
                                    seconds_into_window=traced)
     ok &= need("token-out-of-range" not in reasons,
                "a stream held a token outside the vocabulary")
+    # the server is down, its peak is read and its state is let go: now
+    # the reference, over what the window served (no part of ``setup_s``)
+    del server, fleet, engine
+    gc.collect()
+    t_ref, checks = time.perf_counter(), {}
+    ok &= check_against_reference(
+        builder, model, config, mix,
+        [(by_id[r["id"]]["prompt"], r) for r in whole
+         if "temperature" not in by_id[r["id"]]
+         and None not in r["logprobs"]], seed, checks)
+    say("after-window", reference_check_s=time.perf_counter() - t_ref)
     return {
-        "correct": bool(ok), "attempted": len(sample_), "failed": failed,
+        "correct": bool(ok), "checks": checks, "attempted": len(sample_),
+        "failed": failed,
         "setup_s": setup_s, "counters_before": before,
-        "counters_after": after, "trace": red,
+        "counters_after": after, "trace": red, "memory_peak_bytes": peak,
         "window_s": ctx["seconds"], "records": sample_,
         "tokens_in_window": in_window,
         "samples": {"occupancy": occupancy, "kv_pool_live": pool_live,

@@ -15,7 +15,7 @@ import time
 import numpy as np
 
 from chipbench import common, stats
-from chipbench.common import need, say
+from chipbench.common import held, need, say
 
 
 def run(ctx):
@@ -32,16 +32,18 @@ def run(ctx):
     rng = np.random.RandomState(seed % 2 ** 32)
     pool = [builder.host_batch(config, job, rng)
             for _ in range(job["batch_pool"])]
-    ok = True
+    ok, checks = True, {}
 
     with dygraph.guard():
         model = builder.build(config, seed)
         shapes = {k: tuple(v.data.shape)
                   for k, v in model.state_dict().items()}
         ctx["mark"]("batches-and-model")
-        ref_ok, ref_loss = builder.reference_check(model, config, job,
-                                                   pool[0])
-        ok &= ref_ok
+        got, ref_loss = builder.reference_check(model, config, job, pool[0])
+        ok &= held(checks, "forward_loss_diff", abs(got - ref_loss),
+                   builder.REFERENCE_LOSS_ATOL,
+                   "the program's float32 forward loss %r differs from "
+                   "the plain reference's %r" % (got, ref_loss))
         ctx["mark"]("reference-check")
         opt = AdamWOptimizer(learning_rate=job["learning_rate"],
                              weight_decay=job["weight_decay"])
@@ -65,13 +67,14 @@ def run(ctx):
             dispatch=common.dispatch_lines())
         ok &= need(all(math.isfinite(x) for x in warm),
                    "a warm-up loss is not finite: %r" % (warm,))
-        ok &= need(abs(warm[0] - ref_loss) <= builder.TRAIN_FIRST_LOSS_ATOL,
-                   "first training loss %r is not within %g of the "
-                   "reference's %r" % (warm[0],
-                                       builder.TRAIN_FIRST_LOSS_ATOL,
-                                       ref_loss))
+        ok &= held(checks, "first_loss_diff", abs(warm[0] - ref_loss),
+                   builder.TRAIN_FIRST_LOSS_ATOL,
+                   "first training loss %r is not near the reference's %r"
+                   % (warm[0], ref_loss))
         ok &= need(warm[-1] < warm[0], "the loss did not fall on a repeated "
                    "batch: %r" % (warm,))
+        checks["warmup_loss_change"] = {"value": warm[-1] - warm[0],
+                                        "limit": 0.0}
         ok &= _state_spread(state, chips, job)
 
         feed = iter(io.DevicePrefetcher(itertools.cycle(pool),
@@ -141,8 +144,10 @@ def run(ctx):
             step_ms_p50_untraced=stats.summary(outside)["p50"],
             profiler_start_stop_s=profiler_s,
             note="compare tokens_per_s above with a --trace 0 run's")
+    checks["non_finite_losses"] = {"value": float(bad), "limit": 0.0}
     return {
-        "correct": ok and bad == 0, "attempted": steps, "failed": bad,
+        "correct": ok and bad == 0, "checks": checks, "attempted": steps,
+        "failed": bad,
         "setup_s": setup_s, "counters_before": before,
         "counters_after": after, "trace": red, "steps": steps,
         "tokens_in_window": tokens, "window_s": window_s,

@@ -43,7 +43,7 @@ def one_request(port, item, start_at, give_up_at, records):
 
 def parse(rec):
     """Stamps and values of the token lines, after the run."""
-    tokens, stamps, done, err = [], [], False, rec["error"]
+    tokens, logprobs, stamps, done, err = [], [], [], False, rec["error"]
     for t, line in zip(rec["stamps"], rec["lines"]):
         try:
             obj = json.loads(line)
@@ -51,6 +51,7 @@ def parse(rec):
             continue
         if "token" in obj:
             tokens.append(obj["token"])
+            logprobs.append(obj.get("logprob"))
             stamps.append(t)
         elif obj.get("done"):
             done = "error" not in obj
@@ -59,7 +60,8 @@ def parse(rec):
             err = err or obj.get("error")
     return {"id": rec["id"], "due": rec["due"], "send": rec["send"],
             "end": rec["end"], "status": rec["status"], "error": err,
-            "done": done, "tokens": tokens, "token_times": stamps}
+            "done": done, "tokens": tokens, "logprobs": logprobs,
+            "token_times": stamps}
 
 
 def main(argv=None):

@@ -20,7 +20,7 @@ import glob
 import os
 import re
 
-from chipbench import common, reduce_xplane, stats
+from chipbench import collectives, common, reduce_xplane, stats
 from chipbench.reduce_xplane import HLO, attribute, gaps, self_times, union
 
 # the checkout's root, as `chipbench.run.ROOT` has it (that module is the
@@ -33,7 +33,7 @@ SPAN_PREFIXES = ("http.", "generation.", "train.", "io.", "bench.")
 # spans of the threads that hand work to the device.  An HTTP handler never
 # does, and its ``http.generate`` lives as long as its request: it covers
 # every gap and explains none, so it names no idle time.
-DRIVER_PREFIXES = ("generation.", "train.", "io.", "bench.")
+DRIVER_PREFIXES = reduce_xplane.HOST_PREFIXES
 # idle under this span is the traffic's doing: the scheduler had no work
 IDLE_WAIT = "generation.idle_wait"
 SCOPES = ("optimizer_update", "loss_and_grad", "flash_attention")
@@ -236,10 +236,11 @@ def load(path):
 def summarize(trace):
     """The numbers the readers use, times in seconds, means over the
     devices traced: the window and the idle time in it by span, each
-    span's count and seconds, each program's executions, and device self
-    time by kernel and by scope."""
+    span's count and seconds, each program's executions, device self
+    time by kernel and by scope, and the collectives' seconds in flight
+    and exposed (`collectives.seconds`; empty on one chip)."""
     n = len(trace["ops"])
-    idle, kernels, scopes, window = {}, {}, {}, 0.0
+    idle, kernels, scopes, flying, window = {}, {}, {}, {}, 0.0
     paths, roots = trace["paths"], trace.get("roots", {})
 
     def scope(event_name):
@@ -256,6 +257,8 @@ def summarize(trace):
             kernels[name] = kernels.get(name, 0) + t / 1e9 / n
         for name, t in grouped(own, scope).items():
             scopes[name] = scopes.get(name, 0) + t / 1e9 / n
+        for name, t in collectives.seconds(events).items():
+            flying[name] = flying.get(name, 0) + t / 1e9 / n
     by_module = {}
     for events in trace["modules"].values():
         for name, ns in executions(events).items():
@@ -266,7 +269,8 @@ def summarize(trace):
         by_span[sp.name] = (count + 1, seconds + (sp.end - sp.start) / 1e9)
     return {"devices": n, "window_s": window, "idle_s": idle,
             "span_seconds": by_span, "module_ms": by_module,
-            "kernel_s": kernels, "scope_s": scopes}
+            "kernel_s": kernels, "scope_s": scopes,
+            "collective_s": flying}
 
 
 @functools.lru_cache(maxsize=1)
@@ -292,7 +296,8 @@ def _newest_session():
         programs_count_and_median_ms={
             k: [len(v), sorted(v)[len(v) // 2]]
             for k, v in sorted(red["module_ms"].items())},
-        kernel_s=red["kernel_s"], scope_s=red["scope_s"])
+        kernel_s=red["kernel_s"], scope_s=red["scope_s"],
+        collective_s=red["collective_s"])
     return red
 
 
@@ -317,9 +322,10 @@ def module_ms_p50(obs, prefix):
 
 def ms_per_step(obs, group, prefix):
     """Device milliseconds a train step spends in the kernels
-    (``group="kernel_s"``) or scopes (``"scope_s"``) whose name begins
-    with ``prefix``: their share of the traced stretch times the median
-    step time, as `common.kernel_ms_per_step` reckons it."""
+    (``group="kernel_s"``), scopes (``"scope_s"``) or collectives
+    (``"collective_s"``) whose name begins with ``prefix``: their share
+    of the traced stretch times the median step time, as
+    `common.kernel_ms_per_step` reckons it."""
     red = session(obs)
     if not red or not red["window_s"]:
         return None

@@ -13,7 +13,11 @@ import re
 
 DEVICE_PLANE = re.compile(r"^/device:(TPU|GPU):(\d+)$")
 OPS_LINE = "XLA Ops"            # one event per executed HLO op
-HOST_PREFIX = "bench."          # the benchmark's own TraceAnnotations
+# host spans that name an idle gap: the benchmark's own TraceAnnotations
+# and the program's spans on the threads that hand work to the device
+# (an ``http.`` span lives as long as its request, covers every gap and
+# explains none)
+HOST_PREFIXES = ("bench.", "generation.", "train.", "io.")
 # an op's event is named by its whole HLO instruction:
 #   %fusion.406 = (bf16[48,512,3072]{2,1,0:T(8,128)(2,1)}, ...) fusion(...
 HLO = re.compile(r"^%?(?P<name>\S+) = (?P<type>.*?) (?P<op>[a-z][a-z0-9_-]*)\(")
@@ -44,7 +48,7 @@ def load(trace_dir):
             elif not m:
                 host.extend(
                     (e.name, e.start_ns, e.start_ns + e.duration_ns)
-                    for e in events if e.name.startswith(HOST_PREFIX))
+                    for e in events if e.name.startswith(HOST_PREFIXES))
     return {"devices": devices, "host": host, "layout": layout}
 
 
@@ -138,11 +142,12 @@ def attribute(gap_list, spans, default="unattributed"):
     nest, the shortest one covering an instant names it."""
     out = {}
     for gs, ge in gap_list:
-        cuts = sorted({gs, ge} | {t for _, s, e in spans for t in (s, e)
+        near = [sp for sp in spans if sp[1] < ge and sp[2] > gs]
+        cuts = sorted({gs, ge} | {t for _, s, e in near for t in (s, e)
                                   if gs < t < ge})
         for a, b in zip(cuts, cuts[1:]):
             mid = (a + b) / 2.0
-            covering = [(e - s, name) for name, s, e in spans
+            covering = [(e - s, name) for name, s, e in near
                         if s <= mid < e]
             name = min(covering)[1] if covering else default
             out[name] = out.get(name, 0) + b - a

@@ -84,9 +84,34 @@ def load_reader(root, bench, group, name):
     return mod.read
 
 
+def attach_trace(record, red, *, on_chip, workload, kind):
+    """Put the reduced trace of a traced run into its record:
+    ``device.busy_s``, ``device.window_s`` and ``breakdown``.  On the chip
+    a line without them is refused as malformed and says nothing of why,
+    so a kind that hands back no reduced trace raises here with the
+    reason.  (The CPU rehearsal's session has no device plane; its line
+    goes without them.)"""
+    if not red:
+        if on_chip:
+            raise RuntimeError(
+                "the traced run of %s has no device.busy_s and "
+                "device.window_s to report: kind %r handed back no reduced "
+                "trace (obs['trace'] = %r), so no profiler session ran in "
+                "the window or it holds no operation on a device plane; "
+                "the line [trace] above lists the planes it found"
+                % (workload, kind, red))
+        return
+    record["device"].update(busy_s=red["busy_s"], window_s=red["window_s"])
+    record["breakdown"] = {"device_ops": red["device_ops"],
+                           "idle_gaps": red["idle_gaps"]}
+
+
 def run_cell(workload, seed, seconds, trace, *, require_chip=True,
-             root=ROOT, benchmark="BENCHMARK.json"):
-    """Run the cell and return the result record (the last line)."""
+             root=ROOT, benchmark="BENCHMARK.json", groups=None):
+    """Run the cell and return the result record (the last line).
+    ``groups`` names the metric groups to read, by default the one the
+    contract gives ``--trace``; `tools/sweep_rate.py` asks for both of an
+    untraced run, whose trace readers then find nothing and say nothing."""
     import jax
 
     from chipbench import common, peaks
@@ -127,28 +152,32 @@ def run_cell(workload, seed, seconds, trace, *, require_chip=True,
         "trace_dir": os.path.join(root, ".chipbench_trace", workload),
     })
 
-    metrics, group = {}, "per_layer" if trace else "end_to_end"
-    for m in metrics_of(bench, group, workload):
-        value = load_reader(root, bench, group, m["name"])(obs)
-        # a per-layer reader that finds nothing leaves its metric out; an
-        # end-to-end metric has to be there
-        if value is None and not trace:
-            raise RuntimeError("the run gave no %s" % m["name"])
-        if value is not None:
-            metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
+    metrics = {}
+    for group in groups or (["per_layer"] if trace else ["end_to_end"]):
+        for m in metrics_of(bench, group, workload):
+            value = load_reader(root, bench, group, m["name"])(obs)
+            # a per-layer reader that finds nothing leaves its metric out;
+            # an end-to-end metric has to be there
+            if value is None and group == "end_to_end":
+                raise RuntimeError("the run gave no %s" % m["name"])
+            if value is not None:
+                metrics[m["name"]] = {"value": float(value),
+                                      "unit": m["unit"]}
 
     for d in devices[:cell["chips"]]:
         common.say("memory", device=str(d), stats=d.memory_stats())
     device = common.device_record(devices, cell["chips"])
+    # a runner that computes a reference on the device after its window
+    # reads the peak before it: a process's peak never falls again
+    device["memory_peak_bytes"] = int(obs.get(
+        "memory_peak_bytes", device["memory_peak_bytes"]))
     record = {"correct": bool(obs["correct"]),
               "attempted": int(obs["attempted"]),
               "failed": int(obs["failed"]), "metrics": metrics,
               "device": device}
-    red = obs.get("trace")
-    if trace and red:
-        device["busy_s"], device["window_s"] = red["busy_s"], red["window_s"]
-        record["breakdown"] = {"device_ops": red["device_ops"],
-                               "idle_gaps": red["idle_gaps"]}
+    if trace:
+        attach_trace(record, obs.get("trace"), on_chip=require_chip,
+                     workload=workload, kind=traffic["kind"])
     if cache_dir:
         size = sum(os.path.getsize(os.path.join(cache_dir, f))
                    for f in os.listdir(cache_dir)
@@ -161,6 +190,13 @@ def run_cell(workload, seed, seconds, trace, *, require_chip=True,
                        snap, "xla_compile_cache_misses_total"),
                    compilations=common.counter_value(
                        snap, "xla_compilations_total"))
+    # each number compared beside its limit: the line's last key, and the
+    # last lines of standard error
+    record["checks"] = obs["checks"]
+    for name, c in record["checks"].items():
+        print("[compared] %s = %r (limit %r)" % (name, c["value"],
+                                                 c["limit"]),
+              file=sys.stderr, flush=True)
     return record
 
 

@@ -26,7 +26,8 @@ def one(args, rate):
         "    bench, cell, config, traffic = load(*a, **k)\n"
         "    return bench, cell, config, dict(traffic, rate_per_s=%r)\n"
         "run.load_cell = at_rate\n"
-        "rec = run.run_cell(%r, %d, %r, 0)\n"
+        "rec = run.run_cell(%r, %d, %r, 0,\n"
+        "                   groups=['end_to_end', 'per_layer'])\n"
         "print(json.dumps(rec))\n" % (rate, args.workload, args.seed,
                                       args.seconds))
     proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
@@ -38,7 +39,7 @@ def one(args, rate):
     if proc.returncode == 0:
         rec = json.loads(lines[-1])
         row.update(correct=rec["correct"], attempted=rec["attempted"],
-                   failed=rec["failed"],
+                   failed=rec["failed"], checks=rec["checks"],
                    **{k: v["value"] for k, v in rec["metrics"].items()})
     else:
         row["stderr"] = proc.stderr[-2000:]

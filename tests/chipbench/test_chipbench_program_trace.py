@@ -1,5 +1,6 @@
 """`chipbench.program_trace` on hand-made intervals and event names (no
-profiler, no chip), and the twelve per-layer metrics that read it: each
+profiler, no chip), and the per-layer metrics that read it or the
+program's counters (twelve of PR 26, four of PR 34): each
 resolves to its reader, reads its number where the program gives one, and
 returns None, without raising, where the program gives none (the parent
 of the PR that brought them)."""
@@ -269,6 +270,12 @@ NEW = {     # metric -> (cell, what a full observation reads)
     "flash_attn_bwd_ms_per_step.train": ("bert-base-pretrain-1chip", 40.5),
     "optimizer_update_ms_per_step.train": ("bert-base-pretrain-1chip",
                                            63.0),
+    # PR 34: the engine's two step shares, and the collectives of a step
+    "attn_walk_share.serve": ("gpt2-medium-chat-steady", 9.0),
+    "sampling_step_share.serve": ("gpt2-medium-chat-steady", 60.0),
+    "collective_ms_per_step.train": ("bert-base-pretrain-zero2-4chip", 18.0),
+    "collective_exposed_share.train": ("bert-base-pretrain-zero2-4chip",
+                                       2.0),
 }
 
 
@@ -287,6 +294,8 @@ def full_obs(monkeypatch):
     red["idle_s"] = {"generation.step": 0.5, "generation.emit": 0.3,
                      "generation.idle_wait": 0.1, pt.UNATTRIBUTED: 0.1}
     red["span_seconds"]["generation.step"] = (30, 2.9)
+    assert red["collective_s"] == {}        # one chip: no collective
+    red["collective_s"] = {"in_flight": 0.02, "exposed": 0.004}
     monkeypatch.setattr(pt, "_newest_session", lambda: red)
     return {
         "trace": {"window_s": 0.2, "busy_s": 0.19},
@@ -297,6 +306,8 @@ def full_obs(monkeypatch):
             "generation_queue_wait_ms": histogram_series(p95=120.0),
             "generation_sched_host_ms": histogram_series(p50=5.0, max=41.0),
             "train_step_dispatch_ms": histogram_series(p50=2.5),
+            "generation_attn_walk_share": histogram_series(sum=0.9),
+            "generation_sampling_step_share": histogram_series(sum=6.0),
         }}
 
 
@@ -317,7 +328,8 @@ def test_each_new_metric_resolves_and_reads_or_keeps_silent(
     monkeypatch.setattr(pt, "_newest_session", lambda: {
         "window_s": 0.2, "idle_s": {pt.UNATTRIBUTED: 0.2}, "module_ms": {
             "decode": [91.0], "step": [181.0]}, "kernel_s": {},
-        "scope_s": {}, "span_seconds": {"bench.step": (10, 0.05)}})
+        "scope_s": {}, "collective_s": {},
+        "span_seconds": {"bench.step": (10, 0.05)}})
     assert read(dict(full_obs, counters_after={})) is None
 
 
@@ -345,8 +357,6 @@ PR25 = [    # name, unit, better, source, layer, moves, its first cell
      "scheduler", "latency_ms_per_token", W2),
     ("prefill_ms_p50.serve", "ms", "lower", "program_counter", "scheduler",
      "itl_ms_p95", W2),
-    ("decode_attn_kernel_share.serve", "%", "higher", "program_counter",
-     "kernel dispatch", "itl_ms_p95", W2),
     ("shed_share.serve", "%", "lower", "host_clock", "HTTP front",
      "latency_ms_per_token", W2),
     ("gen_lateness_ms_p95.serve", "ms", "lower", "host_clock",

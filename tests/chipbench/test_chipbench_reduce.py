@@ -63,3 +63,51 @@ def test_idle_share_reader_uses_the_reduction():
 
     assert idle_share({"trace": {"busy_s": 7.5, "window_s": 10.0}}) == 25.0
     assert idle_share({"trace": None}) is None
+
+
+# An XSpace serialized by the protobuf library: a device plane with two
+# operations 4 us apart, and a host plane whose one line holds the
+# scheduler's spans (`generation.step` 0.5-4.5 us around `.decode_fetch`
+# 1-2.5 and `.decode_dispatch` 2.5-3, `.idle_wait` 4.5-4.9), an
+# ``http.generate`` and a foreign event that both cover the whole gap, and
+# the three other prefixes' spans before the gap.
+HOST_XSPACE = bytes.fromhex(
+    "0a6f0801120d2f6465766963653a5450553a301a2208011207584c41204f7073"
+    "22080801100018c0843d220b080110c096b10218c0843d223808011234080112"
+    "3025667573696f6e2e31203d206633325b385d20667573696f6e286633325b38"
+    "5d202578292c206b696e643d6b4c6f6f700af802080212092f686f73743a4350"
+    "551a74080712097363686564756c6572220b080110a0c21e188092f401220a08"
+    "0210c0843d18e0c65b220b080310a0cb980118a0c21e220b080410a0d4920218"
+    "80b51822090805100018809bee0222070806100018904e2208080710904e1890"
+    "4e2209080810a09c0118904e22090809100018809bee02221808091214080912"
+    "10536f6d654f746865723a3a5468696e672215080812110808120d696f2e6e65"
+    "78745f6261746368221b0807121708071213747261696e2e737465705f646973"
+    "706174636822120806120e0806120a62656e63682e7374657022150805121108"
+    "05120d687474702e67656e6572617465221c080412180804121467656e657261"
+    "74696f6e2e69646c655f7761697422220803121e0803121a67656e6572617469"
+    "6f6e2e6465636f64655f6469737061746368221f0802121b0802121767656e65"
+    "726174696f6e2e6465636f64655f66657463682217080112130801120f67656e"
+    "65726174696f6e2e73746570")
+
+
+def test_load_admits_the_program_s_spans_and_the_gaps_take_their_names(
+        tmp_path):
+    session = tmp_path / "plugins" / "profile" / "2026_10_04"
+    session.mkdir(parents=True)
+    (session / "host.xplane.pb").write_bytes(HOST_XSPACE)
+    trace = rx.load(str(tmp_path))
+    assert {name for name, _, _ in trace["host"]} == {
+        "generation.step", "generation.decode_fetch",
+        "generation.decode_dispatch", "generation.idle_wait",
+        "bench.step", "train.step_dispatch", "io.next_batch"}
+    # a handler's span lives as long as its request: it names no gap
+    assert not "http.generate".startswith(rx.HOST_PREFIXES)
+    red = rx.reduce(trace, default="between-decode-steps")
+    named = dict(red["idle_gaps"])
+    assert set(named) == {
+        "generation.decode_fetch", "generation.decode_dispatch",
+        "generation.step", "generation.idle_wait", "between-decode-steps"}
+    # the default label keeps only what no span of the loop thread covers
+    assert named["between-decode-steps"] < named["generation.decode_fetch"]
+    assert sum(named.values()) == pytest.approx(
+        red["window_s"] - red["busy_s"])

@@ -65,6 +65,29 @@ def test_every_seed_offers_the_same_work_in_another_order():
         window(a)) - 4
 
 
+@pytest.mark.parametrize("seed", [3, 2 ** 31 + 11, 2147480001])
+def test_at_the_cell_s_rate_every_seed_holds_one_multiset_of_work(seed):
+    """Prompt lengths, output lengths, gaps and ``attempted`` of the
+    re-based cell's 50 s window: one multiset whatever the seed."""
+    def work(s):
+        reqs = window(traffic.requests(MIX, 50257, s, 50.0))
+        due = [r["due"] for r in reqs]
+        return (sorted(len(r["body"]["prompt"]) for r in reqs),
+                sorted(r["body"]["max_new_tokens"] for r in reqs),
+                sorted(round(b - a, 9) for a, b in zip(due, due[1:])),
+                sum(1 for r in reqs if "temperature" in r["body"]))
+    prompts, outputs, gaps, sampled = work(seed)
+    want = work(1)
+    assert len(prompts) == round(MIX["rate_per_s"] * 50.0) >= 200
+    assert (prompts, outputs, sampled) == (want[0], want[1], want[3])
+    # the gaps are one set, but for the one that joins the halves of the
+    # first gap (the stretch begins half-way through it)
+    common_gaps = collections.Counter(gaps) & collections.Counter(want[2])
+    assert sum(common_gaps.values()) >= len(gaps) - 2
+    # the longest request of the mix lives well inside the ramp and drain
+    assert MIX["ramp_s"] >= 5.0 and MIX["drain_s"] >= MIX["ramp_s"]
+
+
 def test_the_window_s_requests_are_due_inside_it_and_the_ramp_s_before():
     reqs = traffic.requests(MIX, 50257, 7, 50.0)
     ramp = MIX["ramp_s"]
