@@ -62,6 +62,31 @@ The execution model, and why the executable set stays enumerable:
   streams stay per-request-PRNG exact.  Acceptance counters live in
   the PR-4 metrics registry.
 
+* **block diffusion** — a model whose ``cfg.block_length`` B is over 1
+  generates by diffusion over blocks of B tokens (Arriola et al.,
+  arXiv:2503.09573) and a step is no longer one token a slot.  The
+  prompt's whole blocks go into the cache through the chunk path
+  (``prefill_chunk`` rows a scheduler iteration, default
+  `BLOCK_PREFILL_CHUNK`, the rows of one block seeing each other; no
+  token is sampled there), so a long prompt stalls the live streams
+  for one chunk at a time; the ``len(prompt) % B`` tokens left
+  open the first block as positions already revealed.  A block starts
+  as B mask tokens; ONE jitted ``generation_block_step`` over all
+  slots runs the model over each slot's B positions against the cache
+  (a *pass*), draws a token for every still-masked position, and
+  reveals ``B / denoising_steps`` of them by the ``remasking`` rule
+  (``sequential``: the leftmost; ``low_confidence_static``: the most
+  confident; ``low_confidence_dynamic``: all above
+  ``confidence_threshold``, at least the static number).  Once none
+  is masked one more pass over the clean block leaves its K/V in the
+  cache (the *commit*) and the next block begins; slots at different
+  passes share the call.  Which positions are revealed is the
+  engine's own state, never read off token ids (a prompt may hold the
+  mask id).  A token is streamed once it and every position before
+  it is revealed, with the raw log-probability it had at the pass
+  that revealed it; a request ends on the token that meets
+  ``max_new_tokens``, its last block cut and not committed.
+
 Exactness: scheduling is invisible in the tokens.  Per-request PRNG
 streams (`sampling.py`) + row-independent slot math make the engine's
 output token-for-token identical to serving the same requests one at a
@@ -223,6 +248,9 @@ class RequestHandle:
         self._done = threading.Event()
         self._tokens = []
         self._logprobs = []            # filled only on logprob engines
+        # block-diffusion engines: for each token, the denoise pass of
+        # its block that revealed it (0 = the block's first)
+        self.reveal_passes = []
         self.finish_reason = None
         self.error = None
         self.requeued = False          # fleet's requeue-once latch
@@ -260,6 +288,7 @@ class RequestHandle:
     def _restart(self):
         self._tokens = []
         self._logprobs = []
+        self.reveal_passes = []
         tr = _trace.default_tracer()
         if tr.enabled:
             tr.async_instant("restart", self.trace.trace_id,
@@ -376,6 +405,40 @@ class _Slot:
         self.generated = 0
 
 
+REMASKING_RULES = ("sequential", "low_confidence_static",
+                   "low_confidence_dynamic")
+
+# Rows of a block-diffusion prompt's prefill chunk unless the engine is
+# told another width: as many as a 32-slot step of blocks of 4 runs, so
+# that a chunk costs about a pass (both read every weight once) and a
+# prompt of any length holds the live streams up by one pass's worth
+# at a time.
+BLOCK_PREFILL_CHUNK = 128
+
+
+def choose_reveals(masked, logprobs, count, rule, threshold):
+    """Which still-masked positions of each block a denoise pass reveals:
+    masked [N, B] bool, logprobs [N, B] (of the token drawn for each
+    position, raw softmax), ``count`` the static number a pass.
+    ``sequential``: the ``count`` leftmost masked; ``low_confidence_
+    static``: the ``count`` most confident (ties: leftmost);
+    ``low_confidence_dynamic``: those and every one whose probability
+    is above ``threshold``.  A block with nothing masked (a commit
+    pass) reveals nothing."""
+    b = masked.shape[1]
+    if rule == "sequential":
+        return masked & (jnp.cumsum(masked, axis=1) <= count)
+    idx = jnp.arange(b)
+    score = jnp.where(masked, logprobs, -jnp.inf)
+    ahead = (score[:, None, :] > score[:, :, None]) | (
+        (score[:, None, :] == score[:, :, None])
+        & (idx[None, None, :] < idx[None, :, None]))
+    chosen = masked & (jnp.sum(ahead, axis=-1) < count)
+    if rule == "low_confidence_dynamic":
+        chosen |= masked & (logprobs > np.log(threshold))
+    return chosen
+
+
 class _ChunkState:
     """A slot mid-way through chunked prefill (not yet decoding)."""
 
@@ -410,7 +473,17 @@ class GenerationEngine:
     full-block prefix reuse; ``prefill_chunk`` chunk-prefills prompts
     C tokens per scheduler iteration; ``kv_dtype="int8"`` quantizes
     the pool (documented-tolerance opt-in); ``draft_model`` +
-    ``draft_len`` enable speculative decoding."""
+    ``draft_len`` enable speculative decoding.
+
+    Block diffusion: a model with a mask granule (``cfg.block_length``
+    over 1) makes every decode step a ``generation_block_step``;
+    ``denoising_steps`` (a divisor of the block, default as many as
+    positions) and ``remasking`` (`REMASKING_RULES`) say how a block is
+    revealed, ``confidence_threshold`` is the dynamic rule's.  It needs
+    the paged cache and ``max_len`` a multiple of the block, prefills
+    every prompt in chunks (``prefill_chunk``, a multiple of the block;
+    default `BLOCK_PREFILL_CHUNK`), and has no prefix cache, draft
+    model or prefill hand-off."""
 
     tp = 1      # head shards a step runs over (`tp_serving`'s engine: more)
 
@@ -420,7 +493,8 @@ class GenerationEngine:
                  logprobs=False, paged=True, block_size=16,
                  kv_blocks=None, prefix_cache=False, prefill_chunk=None,
                  kv_dtype=None, draft_model=None, draft_len=0,
-                 request_sink=None):
+                 request_sink=None, denoising_steps=None,
+                 remasking="sequential", confidence_threshold=0.9):
         cfg = model.cfg
         self.model = model
         self.cfg = cfg
@@ -446,9 +520,24 @@ class GenerationEngine:
         self.prefill_chunk = int(prefill_chunk) if prefill_chunk else None
         if self.prefill_chunk is not None and self.prefill_chunk < 1:
             raise ValueError("prefill_chunk must be >= 1")
+        # a mask granule over 1 is a model that generates by diffusion
+        # over blocks; 0 here: an autoregressive one
+        granule = int(getattr(cfg, "block_length", 1))
+        self.block_length = granule if granule > 1 else 0
+        if self.block_length:
+            self._init_block_diffusion(
+                denoising_steps, remasking, confidence_threshold,
+                prefix_cache or draft_model is not None)
+        elif denoising_steps is not None:
+            raise ValueError("denoising_steps given for a model without "
+                             "a block mask (cfg.block_length)")
         self._params = {k: jnp.asarray(v.data)
                         for k, v in model.state_dict().items()}
         n = self.slots
+        # a row of the cache is the model's K/V heads (fewer than its
+        # query heads where they are grouped), in the weights' type
+        kv_heads = getattr(cfg, "num_kv_heads", cfg.num_heads)
+        cache_dtype = getattr(cfg, "dtype", "float32")
         if self.paged:
             self.block_size = int(block_size)
             mbps = -(-self.max_len // self.block_size)
@@ -456,15 +545,15 @@ class GenerationEngine:
                 kv_blocks = n * mbps + 1        # dense-parity capacity
             self.cache = PagedKVCache(
                 cfg.num_layers, int(kv_blocks), self.block_size,
-                cfg.num_heads, cfg.head_dim, n, self.max_len,
-                kv_dtype=kv_dtype)
+                kv_heads, cfg.head_dim, n, self.max_len,
+                dtype=cache_dtype, kv_dtype=kv_dtype)
             self._slot_blocks = [[] for _ in range(n)]
             self._prefix = (PrefixCache(self.cache.pool, self.block_size)
                             if prefix_cache else None)
         else:
             self.block_size = None
             self.cache = KVCache(cfg.num_layers, n, self.max_len,
-                                 cfg.num_heads, cfg.head_dim)
+                                 kv_heads, cfg.head_dim, dtype=cache_dtype)
             self._slot_blocks = None
             self._prefix = None
         self._nc = len(self.cache.arrays())    # donated cache operands
@@ -512,10 +601,16 @@ class GenerationEngine:
         self._donate = bool(donate)
         donate_kv = tuple(range(1, 1 + self._nc)) if donate else ()
         self._donate_kv = donate_kv
+        # a block-diffusion engine's step is a program of its own, and
+        # its prompts take the chunk path alone; every other engine
+        # builds the programs it always has
+        make_step, step_name = (
+            (self._make_block_step_fn, "generation_block_step")
+            if self.block_length else
+            (self._make_decode_fn, "generation_decode"))
         self._decode_step_fn = jax.jit(
-            _named(self._make_decode_fn(), "generation_decode"),
-            donate_argnums=donate_kv)
-        self._prefill_fns = {
+            _named(make_step(), step_name), donate_argnums=donate_kv)
+        self._prefill_fns = {} if self.block_length else {
             b: jax.jit(_named(self._make_prefill_fn(b),
                               "generation_prefill_%d" % b),
                        donate_argnums=donate_kv)
@@ -629,6 +724,29 @@ class GenerationEngine:
                 "generation_prefix_hit_tokens_total",
                 "Prompt tokens served from the prefix cache",
                 labelnames=lbl).labels(self._engine)
+        if self.block_length:
+            self._m_block_passes = reg.counter(
+                "generation_block_passes_total",
+                "Passes of the model over a slot's block (denoise and "
+                "commit), one a live slot a step",
+                labelnames=lbl).labels(self._engine)
+            self._m_block_commits = reg.counter(
+                "generation_block_commits_total",
+                "Passes that wrote a finished block's K/V (commits)",
+                labelnames=lbl).labels(self._engine)
+            self._m_revealed = reg.counter(
+                "generation_tokens_revealed_total",
+                "Masked positions revealed by denoise passes",
+                labelnames=lbl).labels(self._engine)
+            self._m_block_rows = reg.counter(
+                "generation_block_cache_rows_total",
+                "Cache rows the live slots of a block step attend over",
+                labelnames=lbl).labels(self._engine)
+            # a model may hand back small arrays of its own with a step
+            # (``aux``) and say what they count (`step_observer`)
+            self._observe_aux = (
+                self.model.step_observer(reg, self._engine)
+                if hasattr(self.model, "step_observer") else None)
         if self.draft_model is not None:
             self._m_spec_proposed = reg.counter(
                 "generation_spec_proposed_total",
@@ -670,23 +788,24 @@ class GenerationEngine:
             framework._dygraph_tracer = old
 
     def _run_cached(self, model, params, ids, pos, arrays,
-                    cache_positions, where):
+                    cache_positions, where, aux=False):
         """``model``'s cached forward (decode / chunk / verify): ids and
         pos ``[B, S]``, the S tokens of row b written at
         ``cache_positions[b]..+S-1`` of each layer's own cache arrays.
         ``where`` says which rows are live: a paged cache's block
         tables ``[B, max_blocks]`` (a zeroed row is a dead slot), a
         dense cache's ``[B]`` bool.  Returns ``(logits [B, S, V],
-        updated arrays)``."""
+        updated arrays)``, and with ``aux`` the small arrays the model
+        hands back beside them (its forward's ``aux=True``)."""
         from ..fluid.dygraph import to_variable
 
         def run(m):
-            logits, layers = m(
+            logits, layers, *rest = m(
                 to_variable(ids), to_variable(pos),
                 caches=group_layers(arrays, len(m.blocks)),
                 cache_positions=cache_positions,
-                **self._cache_index(where))
-            return logits.data, flatten_layers(layers)
+                **self._cache_index(where), **({"aux": True} if aux else {}))
+            return (logits.data, flatten_layers(layers), *rest)
 
         return self._apply_model(params, run, model=model)
 
@@ -716,9 +835,9 @@ class GenerationEngine:
     # the three hooks a tensor-parallel engine overrides: the served
     # model's two forwards, and what wraps a step function's body
     def _forward_cached(self, params, ids, pos, arrays, cache_positions,
-                        where):
+                        where, aux=False):
         return self._run_cached(self.model, params, ids, pos, arrays,
-                                cache_positions, where)
+                                cache_positions, where, aux=aux)
 
     def _forward_prefill(self, params, tokens, bucket):
         return self._run_prefill(self.model, params, tokens, bucket)
@@ -879,6 +998,282 @@ class GenerationEngine:
             return self._write_prefill(arrays, kvs, slot)
 
         return dprefill
+
+    # -- block diffusion ---------------------------------------------------
+    def _init_block_diffusion(self, denoising_steps, remasking, threshold,
+                              other_paths):
+        """Check and keep the block-diffusion knobs, and make the
+        per-slot state of the block in flight (host mirrors, like the
+        rest of a slot's state)."""
+        b, n = self.block_length, self.slots
+        steps = int(denoising_steps or b)
+        if steps < 1 or b % steps:
+            raise ValueError("denoising_steps %d does not divide "
+                             "block_length %d" % (steps, b))
+        if remasking not in REMASKING_RULES:
+            raise ValueError("remasking %r is none of %s"
+                             % (remasking, REMASKING_RULES))
+        if self.prefill_chunk is None:
+            self.prefill_chunk = min(BLOCK_PREFILL_CHUNK, self.max_len)
+        # chunks tile the slot's positions: a prompt's last chunk is
+        # padded to its width, and must not run past ``max_len``
+        if (not self.paged or self.prefill_chunk % b
+                or self.max_len % self.prefill_chunk or other_paths):
+            raise ValueError(
+                "block diffusion needs paged=True, prefill_chunk a "
+                "multiple of the model's block_length and max_len one of "
+                "prefill_chunk, and has no prefix_cache or draft_model")
+        self.denoising_steps = steps
+        self.remasking = remasking
+        self.confidence_threshold = float(threshold)
+        self._mask_id = int(self.cfg.mask_token_id)
+        self._blk_tokens = np.full((n, b), self._mask_id, np.int32)
+        self._blk_revealed = np.zeros((n, b), bool)
+        # positions past a request's last token: never drawn or revealed
+        self._blk_beyond = np.zeros((n, b), bool)
+        self._blk_streamed = np.zeros(n, np.int32)  # positions sent so far
+        self._blk_pass = np.zeros(n, np.int32)      # denoise passes done
+        self._blk_reveal_pass = np.zeros((n, b), np.int32)
+        self._blk_logprobs = np.zeros((n, b), np.float32)
+
+    def _make_block_step_fn(self):
+        """ONE pass over every slot's block (see module docstring):
+        tokens ``[N, B]`` at positions ``lengths..lengths+B-1``, written
+        to the cache and attended under the model's block mask;
+        position j of a block draws with the slot's key at ``steps +
+        j``, its generated index, wherever and whenever it is drawn."""
+        nc, b = self._nc, self.block_length
+        count = b // self.denoising_steps
+
+        def block_step(params, *args):
+            arrays = args[:nc]
+            (lengths, tokens, revealed, keys, steps, temp, top_k, top_p,
+             tables) = args[nc:]
+            offs = jnp.arange(b, dtype=jnp.int32)[None]
+            logits, new_arrays, *aux = self._forward_cached(
+                params, tokens, lengths[:, None] + offs, arrays, lengths,
+                tables, **({"aux": True} if self._observe_aux else {}))
+
+            def each(a, k):         # a slot's value for k of its rows
+                return jnp.repeat(a, k, axis=0)
+
+            def draw(rows, at, wanted):
+                """A token and its raw log-probability for rows ``[N, K,
+                V]`` at block positions ``at [N, K]``; a row that is not
+                ``wanted`` reads as greedy, so a step whose wanted rows
+                are all greedy is one argmax."""
+                k = at.shape[1]
+                flat = rows.reshape((-1, rows.shape[-1]))
+                tok = sample_tokens(
+                    flat, each(keys, k), (steps[:, None] + at).ravel(),
+                    jnp.where(wanted.ravel(), each(temp, k), 0.0),
+                    each(top_k, k), each(top_p, k))
+                return (tok.reshape(at.shape),
+                        token_logprobs(flat, tok).reshape(at.shape))
+
+            masked = ~revealed
+            if self.remasking == "sequential":
+                # which positions a pass reveals follows from the mask
+                # alone, so only they are drawn: ``count`` rows a slot
+                # through the sampler, not every masked one
+                reveal = choose_reveals(masked, None, count, "sequential",
+                                        None)
+                # hit[n, c, j]: position j is the c-th one slot n reveals
+                hit = reveal[:, None, :] & (
+                    jnp.cumsum(reveal, axis=1)[:, None, :] - 1
+                    == jnp.arange(count)[None, :, None])
+                at = jnp.sum(jnp.where(hit, offs, 0), axis=-1)
+                tok, lp = draw(jnp.take_along_axis(logits, at[:, :, None],
+                                                   axis=1),
+                               at, jnp.any(hit, axis=-1))
+                drawn = jnp.sum(jnp.where(hit, tok[:, :, None], 0), axis=1)
+                lps = jnp.sum(jnp.where(hit, lp[:, :, None], 0.0), axis=1)
+            else:
+                # a confidence rule ranks every masked position's draw
+                drawn, lps = draw(logits, jnp.broadcast_to(offs, tokens.shape),
+                                  masked)
+                reveal = choose_reveals(masked, lps, count, self.remasking,
+                                        self.confidence_threshold)
+            return (*new_arrays, jnp.where(reveal, drawn, tokens),
+                    revealed | reveal, jnp.where(reveal, lps, 0.0), *aux)
+
+        return self._wrap_step(block_step)
+
+    def _make_block_chunk_fn(self, width):
+        """One prefill chunk of a block-diffusion prompt, ONE slot:
+        ``width`` tokens (whole blocks) written at ``start..`` through
+        the slot's table row, each row seeing the cache before it and
+        its own block (the model's mask).  Nothing is sampled from a
+        prompt, so no logits are computed (the head is dead code)."""
+        nc = self._nc
+
+        def chunk(params, *args):
+            tokens, start, table = args[nc:]
+            pos = start + jnp.arange(width, dtype=jnp.int32)[None]
+            return self._forward_cached(
+                params, tokens, pos, args[:nc], jnp.reshape(start, (1,)),
+                table)[1]
+
+        return self._wrap_step(chunk)
+
+    def _block_prefill_into(self, slot, request, handle):
+        """Claim the prompt's blocks and start its whole blocks down
+        the chunk path (`_block_chunk_step`, the first chunk now); a
+        prompt shorter than one block opens its first generated block
+        at once.  False when the pool is dry."""
+        if self._claim_blocks(slot, request) is None:
+            return False
+        if len(request.prompt_ids) < self.block_length:
+            self._block_begin(slot, request, handle)
+            return True
+        self._chunking[slot] = _ChunkState(
+            request, handle, 0, None, time.perf_counter())
+        self._block_chunk_step(slot)
+        return True
+
+    def _block_chunk_step(self, slot):
+        """One chunk of a prompt's whole blocks into the cache (one
+        call of ``generation_prefill_chunk_<width>``; rows past the
+        prompt's last whole block are padding nobody attends: a row
+        sees no further than its own block).  The host waits for the
+        device on a prompt's last chunk only."""
+        cs = self._chunking[slot]
+        request, handle = cs.request, cs.handle
+        b, width = self.block_length, self.prefill_chunk
+        whole = len(request.prompt_ids) - len(request.prompt_ids) % b
+        c_real = min(width, whole - cs.pos)
+        if width not in self._chunk_fns:
+            self._chunk_fns[width] = jax.jit(
+                _named(self._make_block_chunk_fn(width),
+                       "generation_prefill_chunk_%d" % width),
+                donate_argnums=self._donate_kv)
+        tokens = np.zeros((1, width), np.int32)
+        tokens[0, :c_real] = request.prompt_ids[cs.pos:cs.pos + c_real]
+        last = cs.pos + c_real >= whole
+        with _DeviceCall(self, "generation.prefill_chunk",
+                         args={"width": width, "slot": int(slot),
+                               "pos": cs.pos,
+                               "request_id": request.request_id},
+                         trace_id=handle.trace.trace_id):
+            with _TRACE_LOCK:
+                out = self._chunk_fns[width](
+                    self._params, *self.cache.arrays(), tokens,
+                    np.int32(cs.pos),
+                    self.cache.table_row(slot)[None].astype(np.int32))
+            if last:
+                jax.block_until_ready(out[0])
+        self.cache.update(*out)
+        cs.pos += c_real
+        if last:
+            self._chunking[slot] = None
+            self._m_prefill_ms.observe((time.perf_counter() - cs.t0) * 1e3)
+            self._block_begin(slot, request, handle)
+
+    def _block_begin(self, slot, request, handle):
+        """The prompt's whole blocks are in the cache: arm the slot and
+        open its first generated block with what is left of the
+        prompt."""
+        b = self.block_length
+        n_prompt = len(request.prompt_ids)
+        whole = n_prompt - n_prompt % b
+        sp = request.sampling
+        self._slot_state[slot] = _Slot(request, handle)
+        self._lengths[slot] = whole
+        self._steps[slot] = -(n_prompt % b)     # block position 0's index
+        self._keys[slot] = make_base_key(sp.seed).astype(np.uint32)
+        self._temp[slot] = sp.temperature
+        self._top_k[slot] = sp.top_k
+        self._top_p[slot] = sp.top_p
+        self._open_block(slot, request.prompt_ids[whole:])
+        self._active[slot] = True
+        return True
+
+    def _open_block(self, slot, given=()):
+        """A new block in ``slot``: ``given`` tokens (what a prompt left
+        over) revealed and not to be streamed, mask tokens after them;
+        those past the request's last token stay mask tokens (its last
+        block is cut: a pass reveals only what will be streamed)."""
+        self._blk_tokens[slot] = self._mask_id
+        self._blk_tokens[slot, :len(given)] = given
+        self._blk_revealed[slot] = False
+        self._blk_revealed[slot, :len(given)] = True
+        self._blk_streamed[slot] = len(given)
+        self._blk_pass[slot] = 0
+        request = self._slot_state[slot].request
+        self._blk_beyond[slot] = (
+            self._lengths[slot] + np.arange(self.block_length)
+            >= len(request.prompt_ids) + request.max_new_tokens)
+
+    def _block_once(self):
+        """One `generation_block_step` over the active slots and what the
+        host does with it: a slot whose block was clean has committed
+        it and opens the next; any other adopts what the pass revealed
+        and streams every token that has none masked before it."""
+        b = self.block_length
+        with _trace.span("generation.grow", cat="generation"):
+            for slot in list(np.nonzero(self._active)[0]):
+                if self._active[slot] and not self._grow_or_preempt(
+                        slot, int(self._lengths[slot]) + b):
+                    self._fail_slot(
+                        slot, "kv pool exhausted: no preemptable slot "
+                        "left to make room")
+            if not self._active.any():
+                return
+        live = np.nonzero(self._active)[0]
+        committing = self._blk_revealed[live].all(axis=1)
+        t0 = time.perf_counter()
+        with _trace.span("generation.block_step", cat="generation",
+                         args={"passes": len(live),
+                               "committing": int(committing.sum())}) as sp:
+            with _DeviceCall(self, "generation.decode_dispatch",
+                             args=self._step_shares(b)):
+                with _TRACE_LOCK:
+                    out = self._decode_step_fn(*self._decode_operands())
+            # the host waits here while the device works
+            with _DeviceCall(self, "generation.decode_fetch"):
+                tokens, revealed, lps, *aux = jax.device_get(
+                    out[self._nc:])
+            revealed = revealed & ~self._blk_beyond
+            newly = int((revealed[live] & ~self._blk_revealed[live]).sum())
+            sp.add_args(revealed=newly)
+        self.cache.update(*out[:self._nc])
+        self._decode_steps += 1
+        self._m_itl.observe((time.perf_counter() - t0) * 1e3)
+        self._m_block_passes.inc(len(live))
+        self._m_block_commits.inc(int(committing.sum()))
+        self._m_revealed.inc(newly)
+        self._m_block_rows.inc(int(self._lengths[live].sum()) + b * len(live))
+        if aux:
+            self._observe_aux(aux[0])
+        with _trace.span("generation.emit", cat="generation"):
+            for slot, commit in zip(live, committing):
+                if commit:
+                    self._lengths[slot] += b
+                    self._steps[slot] += b
+                    self._open_block(slot)
+                    continue
+                new = revealed[slot] & ~self._blk_revealed[slot]
+                # a token keeps the pass and the log-probability of its
+                # reveal until the positions before it let it stream
+                self._blk_reveal_pass[slot, new] = self._blk_pass[slot]
+                self._blk_logprobs[slot, new] = lps[slot, new]
+                self._blk_pass[slot] += 1
+                self._blk_tokens[slot] = tokens[slot]
+                self._blk_revealed[slot] = revealed[slot]
+                st = self._slot_state[slot]
+                while self._active[slot] and self._blk_streamed[slot] < b \
+                        and revealed[slot, self._blk_streamed[slot]]:
+                    j = int(self._blk_streamed[slot])
+                    self._blk_streamed[slot] += 1
+                    st.handle.reveal_passes.append(
+                        int(self._blk_reveal_pass[slot, j]))
+                    self._emit(slot, st, int(tokens[slot, j]),
+                               float(self._blk_logprobs[slot, j])
+                               if self.return_logprobs else None)
+                    if st.generated == 1:
+                        self._m_ttft.observe(
+                            (time.perf_counter() - st.handle.t_submit)
+                            * 1e3)
 
     # -- block accounting (paged) -----------------------------------------
     def _set_block_gauges(self):
@@ -1135,9 +1530,11 @@ class GenerationEngine:
         self._device_s = 0.0
         decoded = self._decode_steps
         progressed = False
+        chunk_step = (self._block_chunk_step if self.block_length
+                      else self._chunk_step)
         for slot in range(self.slots):
             if self._chunking[slot] is not None:
-                self._chunk_step(slot)
+                chunk_step(slot)
                 progressed = True
         while self._free and self._pending:
             entry, handle = self._pending[0]
@@ -1217,6 +1614,8 @@ class GenerationEngine:
         the SAME executable and logits as the dense engine.  A prefix
         hit or ``prefill_chunk`` routes through the chunked path.
         Returns False (nothing claimed) when the pool is dry."""
+        if self.block_length:
+            return self._block_prefill_into(slot, request, handle)
         sp = request.sampling
         n_prompt = len(request.prompt_ids)
         key = make_base_key(sp.seed).astype(np.uint32)
@@ -1390,6 +1789,8 @@ class GenerationEngine:
                 self._die("injected death at decode step %d"
                           % self._decode_steps)
                 raise
+        if self.block_length:
+            return self._block_once()
         if self.draft_model is not None:
             with _trace.span("generation.grow", cat="generation"):
                 viable = self._spec_viable()
@@ -1651,8 +2052,9 @@ class GenerationEngine:
         the handoff carries the context to the decode worker."""
         from ..tp_serving.disagg import KVHandoff
 
-        if not self.paged:
-            raise ValueError("prefill_extract requires paged=True")
+        if not self.paged or self.block_length:
+            raise ValueError("prefill_extract requires paged=True and no "
+                             "block diffusion")
         if not isinstance(request, GenerationRequest):
             request = GenerationRequest(request)
         tc = _trace.TraceContext.from_wire(trace)
@@ -1731,8 +2133,9 @@ class GenerationEngine:
         ``max_queue``.  ``_handle`` re-attaches an existing handle on
         the fleet requeue path."""
         t_enter = time.perf_counter()
-        if not self.paged:
-            raise ValueError("inject_prefilled requires paged=True")
+        if not self.paged or self.block_length:
+            raise ValueError("inject_prefilled requires paged=True and no "
+                             "block diffusion")
         if handoff.block_size != self.block_size:
             raise ValueError("handoff block_size %d != engine %d"
                              % (handoff.block_size, self.block_size))
@@ -1873,6 +2276,12 @@ class GenerationEngine:
     def _decode_operands(self):
         """The decode executable's live operands; the last one says who
         is live: a paged engine's block tables, a dense one's mask."""
+        if self.block_length:
+            return (self._params, *self.cache.arrays(), self._lengths,
+                    self._blk_tokens,
+                    self._blk_revealed | self._blk_beyond, self._keys,
+                    self._steps, self._temp, self._top_k, self._top_p,
+                    self._decode_tables())
         return (self._params, *self.cache.arrays(), self._lengths,
                 self._last_tokens, self._keys, self._steps, self._temp,
                 self._top_k, self._top_p,
@@ -1924,6 +2333,17 @@ class GenerationEngine:
         occ["executables"] = ex
         if self._prefix is not None:
             occ["prefix_cache"] = self._prefix.stats()
+        if self.block_length:
+            # ``decode_steps`` counts calls of the block step; a call is
+            # one pass for each of its live slots
+            occ["block_diffusion"] = {
+                "block_length": self.block_length,
+                "denoising_steps": self.denoising_steps,
+                "remasking": self.remasking,
+                "passes": int(self._m_block_passes.value),
+                "commits": int(self._m_block_commits.value),
+                "tokens_streamed": int(self._m_tokens.value),
+            }
         if self.draft_model is not None:
             proposed = int(self._m_spec_proposed.value)
             accepted = int(self._m_spec_accepted.value)

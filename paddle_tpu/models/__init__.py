@@ -11,6 +11,11 @@ stack dispatches per mode), so one definition serves both executors.
 
 from .bert import BertConfig, BertForPretraining, BertModel  # noqa: F401
 from .moe import MoEFFN  # noqa: F401
+from .moe_decoder import (  # noqa: F401
+    GatedExperts,
+    MoEDecoderConfig,
+    MoEDecoderLM,
+)
 from .lenet import LeNet5  # noqa: F401
 from .mobilenet import MobileNetV1, mobilenet_v1  # noqa: F401
 from .resnet import ResNet, resnet18, resnet34, resnet50, resnet101  # noqa: F401

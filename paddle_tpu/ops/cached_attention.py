@@ -153,10 +153,25 @@ def paged_decode_attention_reference(q, k_pool, v_pool, tables, lengths,
     return decode_attention_reference(q, k, v, lengths, scale)
 
 
+def _row_limits(start, c, granule=1):
+    """Last cache position each of a call's C rows attends, [N, C]: row
+    i, at position ``start + i``, sees ``t <= start + i`` (causal), or
+    with a mask ``granule`` B > 1 to the end of its own block of B,
+    ``t < ((start + i) // B + 1) * B`` (the rows of one block see each
+    other: a block-diffusion model's mask)."""
+    at = start[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
+    if granule == 1:
+        return at
+    return (at // granule + 1) * granule - 1
+
+
 def chunked_attention_reference(q, k_cache, v_cache, start, n_real=None,
-                                scale=None):
+                                scale=None, granule=1):
     """C query rows per slot over a dense cache view with per-row
-    causal limits: row i attends cache positions ``t <= start + i``.
+    causal limits: row i attends cache positions ``t <= start + i``
+    (`_row_limits`; a ``granule`` widens that to the row's mask block).
+    A cache of G < H heads is read grouped: query head j reads head
+    ``j // (H / G)``.
 
     q [N, C, H, D]; k/v_cache [N, T, H, D]; start [N] int32 (position
     of row 0 — its K/V must already be IN the cache, like the decode
@@ -167,11 +182,19 @@ def chunked_attention_reference(q, k_cache, v_cache, start, n_real=None,
     if scale is None:
         scale = float(q.shape[-1]) ** -0.5
     n, c, h, d = q.shape
-    t = k_cache.shape[1]
-    s = jnp.einsum("nchd,nthd->nhct", q.astype(jnp.float32),
-                   k_cache.astype(jnp.float32)) * scale
+    t, g = k_cache.shape[1:3]
+    if g != h:
+        # the H / G query heads of a group against their one cache head
+        # (head j = group j // (H / G)): no copy of the cache a head
+        s = jnp.einsum("ncgrd,ntgd->ngrct",
+                       q.astype(jnp.float32).reshape(n, c, g, h // g, d),
+                       k_cache.astype(jnp.float32)).reshape(n, h, c, t)
+        s = s * scale
+    else:
+        s = jnp.einsum("nchd,nthd->nhct", q.astype(jnp.float32),
+                       k_cache.astype(jnp.float32)) * scale
     pos = jnp.arange(t, dtype=jnp.int32)
-    limit = start[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
+    limit = _row_limits(start, c, granule)
     valid = pos[None, None, :] <= limit[:, :, None]      # [N, C, T]
     s = jnp.where(valid[:, None], s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
@@ -179,8 +202,13 @@ def chunked_attention_reference(q, k_cache, v_cache, start, n_real=None,
     p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - safe_m))
     l = jnp.sum(p, axis=-1, keepdims=True)
     p = p / jnp.maximum(l, 1e-30)
-    out = jnp.einsum("nhct,nthd->nchd", p,
-                     v_cache.astype(jnp.float32))
+    if g != h:
+        out = jnp.einsum("ngrct,ntgd->ncgrd",
+                         p.reshape(n, g, h // g, c, t),
+                         v_cache.astype(jnp.float32)).reshape(n, c, h, d)
+    else:
+        out = jnp.einsum("nhct,nthd->nchd", p,
+                         v_cache.astype(jnp.float32))
     dead = jnp.transpose(m <= NEG_INF / 2, (0, 2, 1, 3))   # [N, C, H, 1]
     return jnp.where(dead, 0.0, out).astype(q.dtype)
 
@@ -190,24 +218,37 @@ def chunked_attention_reference(q, k_cache, v_cache, start, n_real=None,
 _BLOCK_DIAGONAL_ROWS = 128
 
 
-def _spread_heads(q):
-    """q [N, C, H, D] -> the block-diagonal queries [N, C*H, H*D]: row
-    (c, h) holds ``q[c, h]`` in head h's D columns, zeros elsewhere."""
+def _head_of(h, g):
+    """[H, G] float32, 1 where query head j reads cache head ``j // (H /
+    G)``: the identity for a cache of as many heads as the queries."""
+    if g == h:
+        return jnp.eye(h, dtype=jnp.float32)
+    return (jnp.arange(h)[:, None] // (h // g)
+            == jnp.arange(g)[None, :]).astype(jnp.float32)
+
+
+def _spread_heads(q, g=None):
+    """q [N, C, H, D] -> the block-diagonal queries [N, C*H, G*D] over a
+    cache of G heads (default H): row (c, h) holds ``q[c, h]`` in the D
+    columns of the cache head it reads, zeros elsewhere."""
     n, c, h, d = q.shape
-    eye = jnp.eye(h, dtype=jnp.float32)
+    g = h if g is None else g
+    eye = _head_of(h, g)
     return (q.astype(jnp.float32)[:, :, :, None, :]
-            * eye[None, None, :, :, None]).reshape(n, c * h, h * d)
+            * eye[None, None, :, :, None]).reshape(n, c * h, g * d)
 
 
 def _own_heads(full, c, h, d):
     """The diagonal blocks of a block-diagonal product: full
-    [N, C*H, H*D] -> [N, C, H, D], row (c, h) keeping head h's columns."""
-    eye = jnp.eye(h, dtype=jnp.float32)
-    return jnp.sum(full.reshape(-1, c, h, h, d)
+    [N, C*H, G*D] -> [N, C, H, D], row (c, h) keeping the columns of the
+    cache head it reads."""
+    g = full.shape[-1] // d
+    eye = _head_of(h, g)
+    return jnp.sum(full.reshape(-1, c, h, g, d)
                    * eye[None, None, :, :, None], axis=3)
 
 
-def merged_attention(q, k_view, v_view, start, scale=None):
+def merged_attention(q, k_view, v_view, start, scale=None, granule=1):
     """`chunked_attention_reference` over cache views that keep the
     heads MERGED: q [N, C, H, D]; k/v_view [N, T, H*D] (a dense cache
     as it is held, or `paged_gather_kv` of a merged pool);
@@ -239,18 +280,19 @@ def merged_attention(q, k_view, v_view, start, scale=None):
     speculative verify ((k+1)*H) stay merged."""
     n, c, h, d = q.shape
     t = k_view.shape[1]
+    g = k_view.shape[2] // d
     if c * h > _BLOCK_DIAGONAL_ROWS:
         return chunked_attention_reference(
-            q, k_view.reshape(n, t, h, d), v_view.reshape(n, t, h, d),
-            start, scale=scale)
+            q, k_view.reshape(n, t, g, d), v_view.reshape(n, t, g, d),
+            start, scale=scale, granule=granule)
     if scale is None:
         scale = float(d) ** -0.5
     exact = jax.lax.Precision.HIGHEST
-    s = jnp.einsum("nrm,ntm->nrt", _spread_heads(q),
+    s = jnp.einsum("nrm,ntm->nrt", _spread_heads(q, g),
                    k_view.astype(jnp.float32),
                    precision=exact).reshape(n, c, h, t) * scale
     pos = jnp.arange(t, dtype=jnp.int32)
-    limit = start[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
+    limit = _row_limits(start, c, granule)
     valid = pos[None, None, :] <= limit[:, :, None]      # [N, C, T]
     s = jnp.where(valid[:, :, None], s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)               # [N, C, H, 1]
@@ -344,12 +386,15 @@ def attention_walk_share(extent, rows, positions, block_size=None):
     return float(group * chunk * chunks.sum()) / (slots * positions)
 
 
-def _attend_live(q, start, live, pools, tables, group, chunk, scale):
+def _attend_live(q, start, live, pools, tables, group, chunk, scale,
+                 granule=1):
     """`merged_attention` without the view: q [N, C, H, D] (C*H within
     `_BLOCK_DIAGONAL_ROWS`); start [N] int32, row i of slot n attends
-    ``t <= start[n] + i``; live [N] bool, a dead slot attends nothing
-    and returns 0.  ``pools`` is ``(k, v)`` of ``[NB, bs, H*D]``, or
-    ``(k, v, k_scale, v_scale)`` with int8 pools and ``[NB, bs, H]``
+    ``t <= start[n] + i`` (with a ``granule``, to the end of its mask
+    block: `_row_limits`); live [N] bool, a dead slot attends nothing
+    and returns 0.  ``pools`` is ``(k, v)`` of ``[NB, bs, G*D]`` (G
+    cache heads, each read by H / G query heads), or
+    ``(k, v, k_scale, v_scale)`` with int8 pools and ``[NB, bs, G]``
     scales; ``tables [N, max_blocks]`` int32 maps a slot's positions to
     blocks.
 
@@ -368,17 +413,18 @@ def _attend_live(q, start, live, pools, tables, group, chunk, scale):
     change nothing (corr = 1, p = 0), so a slot's result does not
     depend on who shares its group."""
     n, c, h, d = q.shape
-    r, hd = c * h, h * d
+    r, hd = c * h, pools[0].shape[-1]
+    g = hd // d
     exact = jax.lax.Precision.HIGHEST
-    q_bd = _spread_heads(q)
+    q_bd = _spread_heads(q, g)
     start = jnp.where(live, start, -c)
     bs, blocks = pools[0].shape[1], tables.shape[1]
     cb = chunk // bs
     order, rank, chunks = walk_plan(
         jnp.clip(start + c, 0, blocks * bs), group, chunk)
     pad = order.shape[0] - n
-    limit = jnp.repeat(start[:, None] + jnp.arange(c, dtype=jnp.int32),
-                       h, axis=1)                           # [N, C*H]
+    # a dead slot's rows start at -c: every limit is below 0
+    limit = jnp.repeat(_row_limits(start, c, granule), h, axis=1)  # [N, C*H]
     # the padding slots: dead rows with nothing to attend
     q_bd = jnp.pad(q_bd, ((0, pad), (0, 0), (0, 0)))[order]
     limit = jnp.pad(limit, ((0, pad), (0, 0)), constant_values=-1)[order]
@@ -392,8 +438,8 @@ def _attend_live(q, start, live, pools, tables, group, chunk, scale):
         if scales is None:
             return x.astype(jnp.float32)
         return dequantize_kv(
-            x.reshape(group, chunk, h, d),
-            scales[ids].reshape(group, chunk, h)).reshape(x.shape)
+            x.reshape(group, chunk, g, d),
+            scales[ids].reshape(group, chunk, g)).reshape(x.shape)
 
     def walk_group(g, out):
         at = g * group
@@ -464,15 +510,20 @@ def kv_write(arrays, i0, i1, k_rows, v_rows):
             *scales)
 
 
-def cached_attention(q, k_new, v_new, cache, scale=None):
+def cached_attention(q, k_new, v_new, cache, scale=None, granule=1):
     """Decode/chunk attention over one layer's cache: write the C new
     tokens' K/V at positions ``pos..pos+C-1``, then attend row i over
     positions ``<= pos+i`` (C == 1 is the classic decode step; C > 1 is
-    a chunked-prefill / speculative-verify call).  Fixed shapes
-    throughout — each (C,) config compiles once.
+    a chunked-prefill / speculative-verify call).  With a mask
+    ``granule`` B > 1 row i attends to the end of its own block of B
+    positions (`_row_limits`): the C = B rows of a block-diffusion
+    model's step, written first, then each seeing all of them.  Fixed
+    shapes throughout — each (C,) config compiles once.
 
-    q, k_new, v_new [B, C, H, D] (H the local heads under tensor
-    parallelism).  Cache tuple forms, arrays merged as
+    q [B, C, H, D] (H the local heads under tensor parallelism); k_new,
+    v_new [B, C, G, D], G = H or, for grouped K/V heads, a divisor of
+    it (query head j reads cache head ``j // (H / G)``; the cache
+    arrays are then G*D wide).  Cache tuple forms, arrays merged as
     `generation.kv_cache` holds them:
 
     * dense  — ``(k_cache, v_cache, pos)`` with ``[B, T, H*D]`` arrays,
@@ -523,9 +574,10 @@ def cached_attention(q, k_new, v_new, cache, scale=None):
         logical = jnp.clip(p // bs, 0, tables.shape[1] - 1)
         i0 = jnp.take_along_axis(tables, logical, axis=1)
         i1 = p % bs
+    g = k_new.shape[2]
     arrays = kv_write(arrays, i0.ravel(), i1.ravel(),
-                      k_new.reshape(b * c, h, d),
-                      v_new.reshape(b * c, h, d))
+                      k_new.reshape(b * c, g, d),
+                      v_new.reshape(b * c, g, d))
     if c == 1:
         # nothing is chosen here: the count is what
         # `decode_attn_kernel_share.serve` reads (0% with it, null
@@ -540,7 +592,8 @@ def cached_attention(q, k_new, v_new, cache, scale=None):
             k_scale, v_scale = arrays[2:] if n_arr == 4 else (None, None)
             k_view = paged_gather_kv(k_view, tables, k_scale)
             v_view = paged_gather_kv(v_view, tables, v_scale)
-        return merged_attention(q, k_view, v_view, pos, scale=scale), arrays
+        return merged_attention(q, k_view, v_view, pos, scale=scale,
+                                granule=granule), arrays
 
     if dense:
         live = cache[3] if len(cache) == 4 else None
@@ -551,11 +604,12 @@ def cached_attention(q, k_new, v_new, cache, scale=None):
         # a dense cache is a pool whose blocks are its chunks, in order
         # (the same bytes: `walk_geometry` cuts it in whole tiles)
         per = positions // chunk
-        pools = tuple(a.reshape(b * per, chunk, h * d) for a in arrays)
+        pools = tuple(a.reshape(b * per, chunk, g * d) for a in arrays)
         tables = jnp.arange(b * per, dtype=jnp.int32).reshape(b, per)
     else:
         live = jnp.any(tables != 0, axis=1)
         group, chunk = walk_geometry(b, tables.shape[1] * bs, bs)
         pools = arrays
-    ctx = _attend_live(q, pos, live, pools, tables, group, chunk, scale)
+    ctx = _attend_live(q, pos, live, pools, tables, group, chunk, scale,
+                       granule)
     return ctx, arrays
