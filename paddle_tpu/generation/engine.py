@@ -24,6 +24,17 @@ The execution model, and why the executable set stays enumerable:
   (`sampling.sample_tokens`) is one argmax in a step whose live rows
   are all greedy, a parked slot reading as greedy (`_park`);
   `generation_sampling_step_share` says how many steps were not.
+  The plain step keeps ONE step in flight (`_decode_once`): step t+1
+  is dispatched on the device's own copy of step t's sampled tokens,
+  and only then are step t's fetched (one transfer) and delivered, so
+  the host's work between steps runs while the device computes.
+  Lengths, steps and the cache advance at dispatch.  A request that
+  ends by length is known at dispatch and computes no extra row; one
+  that ends on a stop token, is preempted or fails with a row in
+  flight has that row dropped at delivery
+  (`generation_decode_rows_discarded_total`), matched by the slot's
+  state and not its index.  `generation_decode_overlapped_total` over
+  the decode steps says how often the step before was un-fetched.
 * **paged KV** (the PR-17 rebuild) — the store is a block pool, one
   ``[num_blocks, block_size, H*D]`` array per layer for K and for V
   (`kv_cache` says why that shape), plus a host per-slot block table
@@ -397,12 +408,13 @@ class RequestHandle:
 
 
 class _Slot:
-    __slots__ = ("request", "handle", "generated")
+    __slots__ = ("request", "handle", "generated", "in_flight")
 
     def __init__(self, request, handle):
         self.request = request
         self.handle = handle
-        self.generated = 0
+        self.generated = 0             # tokens delivered to the stream
+        self.in_flight = 0             # decode rows dispatched, not fetched
 
 
 REMASKING_RULES = ("sequential", "low_confidence_static",
@@ -564,10 +576,27 @@ class GenerationEngine:
             raise ValueError("draft_model needs draft_len >= 1")
         if self.draft_model is not None and not self.paged:
             raise ValueError("speculative decoding requires paged=True")
-        # host mirrors of per-slot state (device state is ONLY the cache)
+        # host mirrors of per-slot state.  Device state is the cache and
+        # the plain decode step's token operand: step t+1 reads step t's
+        # sampled tokens where they are, so ``_last_tokens`` is a device
+        # array from the start (one jit signature).  ``_tok_host`` holds
+        # what the host knows of it, and ``_fresh`` marks the slots whose
+        # token the host set since the last dispatch (`_set_token`: an
+        # admission's first token, a verify step's last), which
+        # `_dispatch_decode` writes over the device's copy
         self._lengths = np.zeros(n, np.int32)
-        self._last_tokens = np.zeros(n, np.int32)
         self._steps = np.zeros(n, np.int32)
+        self._last_tokens = jnp.zeros(n, jnp.int32)
+        self._tok_host = np.zeros(n, np.int32)
+        self._fresh = np.zeros(n, bool)
+        self._merge_tokens = jax.jit(_named(
+            lambda tokens, fresh, mine: jnp.where(fresh, mine, tokens),
+            "generation_token_merge"))
+        # the plain decode step dispatched and not yet fetched:
+        # ``(its outputs after the cache's, [(slot, _Slot), ...])``
+        self._in_flight = None
+        self._t_fetch = 0.0            # when the last step's fetch returned
+        self._prefill_since = False    # a prompt went in since then
         self._keys = np.zeros((n, 2), np.uint32)
         self._temp = np.zeros(n, np.float32)
         self._top_k = np.zeros(n, np.int32)
@@ -668,7 +697,9 @@ class GenerationEngine:
             "generation_ttft_ms", "Submit -> first token (ms)",
             labelnames=lbl).labels(self._engine)
         self._m_itl = reg.histogram(
-            "generation_itl_ms", "Decode step wall time, one a step (ms)",
+            "generation_itl_ms",
+            "What a live stream waits for its next token while no prompt "
+            "goes in: one decode step's fetch to the next one's (ms)",
             labelnames=lbl).labels(self._engine)
         self._m_prefill_ms = reg.histogram(
             "generation_prefill_ms", "Prefill call wall time (ms)",
@@ -703,6 +734,16 @@ class GenerationEngine:
         self._m_preempt = reg.counter(
             "generation_preempt_total",
             "Slots preempted on KV pool exhaustion",
+            labelnames=lbl).labels(self._engine)
+        self._m_overlapped = reg.counter(
+            "generation_decode_overlapped_total",
+            "Plain decode steps dispatched while the step before was "
+            "un-fetched (over decode steps: the overlap share)",
+            labelnames=lbl).labels(self._engine)
+        self._m_discarded = reg.counter(
+            "generation_decode_rows_discarded_total",
+            "Decode rows computed for a slot that had stopped, was "
+            "preempted or failed while the row was in flight",
             labelnames=lbl).labels(self._engine)
         if self.paged:
             self._m_blocks_used = reg.gauge(
@@ -1513,8 +1554,12 @@ class GenerationEngine:
     def step(self):
         """One scheduler iteration: advance every mid-flight chunked
         prefill by ONE chunk, refill free slots (prefill), then one
-        decode step over the active batch.  Returns True when any work
-        happened."""
+        decode step over the active batch.  A plain decode step is
+        dispatched here and its tokens are delivered by the NEXT
+        iteration, after that one's dispatch (`_decode_once`); an
+        iteration that only delivers counts as work.  Returns True when
+        any work happened: False means nothing is queued, live or
+        un-fetched."""
         t_step = time.perf_counter()
         with _trace.span("generation.step", cat="generation"):
             with _trace.span("generation.lock_wait", cat="generation"):
@@ -1546,7 +1591,7 @@ class GenerationEngine:
             if not admitted:
                 break
             progressed = True
-        if self._active.any():
+        if self._active.any() or self._in_flight is not None:
             self._decode_once()
             progressed = True
         self._m_occupancy.set(
@@ -1579,7 +1624,7 @@ class GenerationEngine:
         # stamp) until a running request frees blocks — unless nothing
         # is running, in which case it never will
         self._free.insert(0, slot)
-        if self._active.any() or any(
+        if self._active.any() or self._in_flight is not None or any(
                 c is not None for c in self._chunking):
             self._pending.insert(0, (entry, handle))
             self._m_queue.set(len(self._pending))
@@ -1703,6 +1748,7 @@ class GenerationEngine:
         sp = request.sampling
         n_prompt = len(request.prompt_ids)
         remaining = n_prompt - cs.pos
+        self._prefill_since = True
         width = (self.prefill_chunk if self.prefill_chunk is not None
                  else self._bucket_for(remaining))
         c_real = min(width, remaining)
@@ -1769,72 +1815,132 @@ class GenerationEngine:
         st = _Slot(request, handle)
         self._slot_state[slot] = st
         self._lengths[slot] = n_prompt
-        self._last_tokens[slot] = tok0
+        self._set_token(slot, tok0)
         self._steps[slot] = 1
         self._keys[slot] = key
         self._temp[slot] = sp.temperature
         self._top_k[slot] = sp.top_k
         self._top_p[slot] = sp.top_p
         self._active[slot] = True
+        self._prefill_since = True
         self._emit(slot, st, tok0, lp0)
         self._m_ttft.observe(
             (time.perf_counter() - handle.t_submit) * 1e3)
 
     # -- decode ------------------------------------------------------------
     def _decode_once(self):
-        if self._step_hook is not None:
-            try:
-                self._step_hook(self._decode_steps)
-            except EngineDeadError:
-                self._die("injected death at decode step %d"
-                          % self._decode_steps)
-                raise
-        if self.block_length:
-            return self._block_once()
-        if self.draft_model is not None:
-            with _trace.span("generation.grow", cat="generation"):
-                viable = self._spec_viable()
-            if viable and self._spec_once():
-                return
-        # plain step: make room for ONE new row per active slot
-        if self.paged:
-            with _trace.span("generation.grow", cat="generation"):
-                for slot in list(np.nonzero(self._active)[0]):
-                    if not self._active[slot]:
-                        continue       # preempted as an earlier victim
-                    if not self._grow_or_preempt(
-                            slot, int(self._lengths[slot]) + 1):
-                        self._fail_slot(
-                            slot, "kv pool exhausted: no preemptable "
-                            "slot left to make room")
-                if not self._active.any():
+        """One decode step over the active slots.  The plain step keeps
+        one step in flight: step t+1 is dispatched on the device's own
+        copy of step t's tokens, and only then are step t's fetched and
+        delivered, so that the fetch's round trip, the streams'
+        wake-ups and the loop's own Python run while the device
+        computes.  A block step and a verify step decide on the host
+        from what they fetch, and stay synchronous."""
+        if self._active.any():
+            if self._step_hook is not None:
+                try:
+                    self._step_hook(self._decode_steps)
+                except EngineDeadError:
+                    self._die("injected death at decode step %d"
+                              % self._decode_steps)
+                    raise
+            if self.block_length:
+                return self._block_once()
+            if self.draft_model is not None:
+                with _trace.span("generation.grow", cat="generation"):
+                    viable = self._spec_viable()
+                if viable and self._spec_once():
                     return
-        operands = self._decode_operands()
+            # plain step: make room for ONE new row per active slot
+            if self.paged:
+                with _trace.span("generation.grow", cat="generation"):
+                    for slot in list(np.nonzero(self._active)[0]):
+                        if not self._active[slot]:
+                            continue   # preempted as an earlier victim
+                        if not self._grow_or_preempt(
+                                slot, int(self._lengths[slot]) + 1):
+                            self._fail_slot(
+                                slot, "kv pool exhausted: no preemptable "
+                                "slot left to make room")
+        before, self._in_flight = self._in_flight, None
+        if self._active.any():
+            self._dispatch_decode(overlapped=before is not None)
+        if before is not None:
+            self._deliver(*before)
+        if self.draft_model is not None and self._in_flight is not None:
+            # a verify step proposes from the tokens on the host
+            flight, self._in_flight = self._in_flight, None
+            self._deliver(*flight)
+
+    def _set_token(self, slot, token):
+        """The host decides ``slot``'s next input token (its prefill's
+        sample, a verify step's last): the next plain dispatch writes
+        it over the device's copy."""
+        self._tok_host[slot] = token
+        self._fresh[slot] = True
+
+    def _dispatch_decode(self, overlapped):
+        """Launch one plain decode step over the active slots and count
+        it as done on the host: lengths, steps and the cache advance
+        here, not at delivery, so the next step can be built before
+        this one's tokens are known.  A slot whose row in flight is its
+        last by length is not in the next step."""
+        rows = np.nonzero(self._active)[0]
+        args = self._step_shares(1)
+        args["overlapped"] = int(overlapped)
         t0 = time.perf_counter()
-        with _DeviceCall(self, "generation.decode_dispatch",
-                         args=self._step_shares(1)):
+        with _DeviceCall(self, "generation.decode_dispatch", args=args):
             with _TRACE_LOCK:
-                out = self._decode_step_fn(*operands)
-        # the host waits here while the device works
-        with _DeviceCall(self, "generation.decode_fetch"):
-            nxt = np.asarray(out[self._nc])
-            lps = (np.asarray(out[self._nc + 1]) if self.return_logprobs
-                   else None)
+                if self._fresh.any():
+                    self._last_tokens = self._merge_tokens(
+                        self._last_tokens, self._fresh, self._tok_host.copy())
+                    self._fresh = np.zeros(self.slots, bool)
+                out = self._decode_step_fn(*self._decode_operands())
         self.cache.update(*out[:self._nc])
+        self._last_tokens = out[self._nc]
         self._decode_steps += 1
-        self._m_itl.observe((time.perf_counter() - t0) * 1e3)
-        # the cache write in the step put every ACTIVE slot's new token
-        # at lengths; advance those counters (inactive rows computed
-        # garbage nobody reads — their writes went to the garbage block)
+        if overlapped:
+            self._m_overlapped.inc()
+        else:                          # nothing to measure its gap from
+            self._t_fetch, self._prefill_since = t0, False
+        # the step writes every ACTIVE slot's new token at its length
+        # (inactive rows compute garbage nobody reads: their writes go
+        # to the garbage block)
+        self._lengths[rows] += 1
+        self._steps[rows] += 1
+        flight = []
+        for slot in rows:
+            st = self._slot_state[slot]
+            st.in_flight += 1
+            flight.append((slot, st))
+            if self._length_reason(st, st.generated + st.in_flight):
+                self._park(slot)       # ends when this row is delivered
+        self._in_flight = (out[self._nc:], flight)
+
+    def _deliver(self, outputs, flight):
+        """Fetch a dispatched step's tokens (and log-probabilities) in
+        one transfer and hand each to its stream.  A row whose slot no
+        longer holds the `_Slot` it was computed for (the request
+        stopped on a token, was preempted or failed since the dispatch)
+        is dropped: a restarted stream never sees a token of its former
+        life."""
+        # the host waits here, while the device works on the next step
+        with _DeviceCall(self, "generation.decode_fetch"):
+            nxt, *lps = jax.device_get(outputs)
+        now = time.perf_counter()
+        if not self._prefill_since:    # that wait is generation_prefill_ms's
+            self._m_itl.observe((now - self._t_fetch) * 1e3)
+        self._t_fetch, self._prefill_since = now, False
         with _trace.span("generation.emit", cat="generation"):
-            for slot in np.nonzero(self._active)[0]:
-                self._lengths[slot] += 1
-                self._steps[slot] += 1
-                st = self._slot_state[slot]
-                st_tok = int(nxt[slot])
-                self._last_tokens[slot] = st_tok
-                self._emit(slot, st, st_tok,
-                           float(lps[slot]) if lps is not None else None)
+            for slot, st in flight:
+                st.in_flight -= 1
+                if self._slot_state[slot] is not st:
+                    self._m_discarded.inc()
+                    continue
+                token = int(nxt[slot])
+                self._tok_host[slot] = token
+                self._emit(slot, st, token,
+                           float(lps[0][slot]) if lps else None)
 
     # -- speculative decoding ----------------------------------------------
     def _spec_viable(self):
@@ -1864,7 +1970,7 @@ class GenerationEngine:
         k = self.draft_len
         n = self.slots
         drafts = np.zeros((n, k), np.int32)
-        cur = self._last_tokens.copy()
+        cur = self._tok_host.copy()
         t0 = time.perf_counter()
         for i in range(k):
             with _DeviceCall(self, "generation.decode_dispatch"):
@@ -1877,7 +1983,7 @@ class GenerationEngine:
                 cur = np.asarray(nxt)
             drafts[:, i] = cur
         tok_in = np.concatenate(
-            [self._last_tokens[:, None], drafts], axis=1).astype(np.int32)
+            [self._tok_host[:, None], drafts], axis=1).astype(np.int32)
         tables = self._decode_tables()
         with _DeviceCall(self, "generation.decode_dispatch",
                          args=self._step_shares(k + 1)):
@@ -1907,7 +2013,7 @@ class GenerationEngine:
                     self._lengths[slot] += 1
                     self._steps[slot] += 1
                     t = int(toks[slot, i])
-                    self._last_tokens[slot] = t
+                    self._set_token(slot, t)
                     self._emit(slot, st, t,
                                float(lps[slot, i]) if lps is not None
                                else None)
@@ -1921,15 +2027,22 @@ class GenerationEngine:
         st.handle._emit(st.generated, token, logprob)
         st.generated += 1
         self._m_tokens.inc()
-        reason = None
-        if token in st.request.stop_token_ids:
-            reason = "stop_token"
-        elif st.generated >= st.request.max_new_tokens:
-            reason = "max_new_tokens"
-        elif self._lengths[slot] + 1 >= self.max_len:
-            reason = "cache_full"
+        reason = ("stop_token" if token in st.request.stop_token_ids
+                  else self._length_reason(st, st.generated))
         if reason is not None:
             self._finish_slot(slot, reason)
+
+    def _length_reason(self, st, count):
+        """Why a request ends with its ``count``-th token whatever that
+        token is, or None: what a dispatch knows of a row still in
+        flight.  (An autoregressive slot's cache holds its prompt and
+        all but the newest of its tokens; a block-diffusion request
+        always meets ``max_new_tokens`` first.)"""
+        if count >= st.request.max_new_tokens:
+            return "max_new_tokens"
+        if len(st.request.prompt_ids) + count >= self.max_len:
+            return "cache_full"
+        return None
 
     def _finish_slot(self, slot, reason):
         st = self._slot_state[slot]
@@ -1946,6 +2059,7 @@ class GenerationEngine:
     # -- death (drills / fleet) -------------------------------------------
     def _die(self, why):
         self._dead = True
+        self._in_flight = None         # abandoned: its streams restart
         affected = []
         for slot, st in enumerate(self._slot_state):
             if st is not None:
@@ -2012,6 +2126,7 @@ class GenerationEngine:
                 if self._stop or self._dead:
                     return
                 busy = (bool(self._pending) or bool(self._active.any())
+                        or self._in_flight is not None
                         or any(c is not None for c in self._chunking))
                 if not busy:
                     # no work: an idle device here is the traffic's doing
@@ -2282,10 +2397,12 @@ class GenerationEngine:
                     self._blk_revealed | self._blk_beyond, self._keys,
                     self._steps, self._temp, self._top_k, self._top_p,
                     self._decode_tables())
-        return (self._params, *self.cache.arrays(), self._lengths,
-                self._last_tokens, self._keys, self._steps, self._temp,
-                self._top_k, self._top_p,
-                self._decode_tables() if self.paged else self._active)
+        # the host's arrays are copies: the step may still be reading
+        # them when a slot's state is next written
+        return (self._params, *self.cache.arrays(), self._lengths.copy(),
+                self._last_tokens, self._keys.copy(), self._steps.copy(),
+                self._temp.copy(), self._top_k.copy(), self._top_p.copy(),
+                self._decode_tables() if self.paged else self._active.copy())
 
     def _decode_cache_size(self):
         """Jit-cache entries of the decode step — the compile-once pin."""
@@ -2312,6 +2429,10 @@ class GenerationEngine:
             "cache": self.cache.describe(),
             "decode_executables": self._decode_cache_size(),
             "preempted": int(self._m_preempt.value),
+            # over ``decode_steps``: the share of plain steps dispatched
+            # while the step before was un-fetched
+            "decode_overlapped": int(self._m_overlapped.value),
+            "decode_rows_discarded": int(self._m_discarded.value),
             # mean over the decode/verify steps so far (None before one)
             "attn_walk_share": self._m_walk.summary().get("mean"),
             "sampling_step_share": self._m_sampling.summary().get("mean"),

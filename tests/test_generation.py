@@ -1261,6 +1261,194 @@ class TestPagedEngine:
                         draft_len=2)
 
 
+def _alone(lm, reqs, **kw):
+    """Tokens and log-probabilities of each request served alone in a
+    fresh engine: `sequential_oracle` with the log-probabilities."""
+    toks, lps = [], []
+    for r in reqs:
+        eng = make_engine(lm, **kw)
+        h = eng.submit(gen.GenerationRequest(
+            r.prompt_ids, max_new_tokens=r.max_new_tokens,
+            sampling=r.sampling, stop_token_ids=r.stop_token_ids))
+        eng.run_until_idle()
+        toks.append(h.result())
+        lps.append(h.logprobs())
+    return toks, lps
+
+
+class TestStepInFlight:
+    """The plain decode step keeps one step in flight: step t+1 is
+    dispatched on the device's copy of step t's tokens before those are
+    fetched and delivered.  Same tokens, same order; what the host
+    learns a step late (a stop token, a preemption) costs a discarded
+    row and never a wrong token."""
+
+    @pytest.mark.parametrize("kw", [{}, {"paged": False},
+                                    {"kv_dtype": "int8"}],
+                             ids=["paged", "dense", "int8"])
+    def test_streams_and_logprobs_equal_the_oracle(self, lm, kw):
+        reqs = mixed_requests(7)
+        eng = make_engine(lm, logprobs=True, **kw)
+        handles = [eng.submit(r) for r in reqs]
+        in_flight = 0
+        while eng.step():
+            in_flight += eng._in_flight is not None
+        assert in_flight > 10 and eng._in_flight is None
+        toks, lps = _alone(lm, reqs, logprobs=True, **kw)
+        assert [h.result() for h in handles] == toks
+        for h, want in zip(handles, lps):
+            np.testing.assert_allclose(h.logprobs(), want, rtol=0,
+                                       atol=1e-6)
+        st = eng.stats()
+        assert st["decode_overlapped"] >= st["decode_steps"] - 1
+        # every request ends by length: known at dispatch, no row wasted
+        assert st["decode_rows_discarded"] == 0
+        assert st["executables"]["decode_step"] == 1
+
+    def test_a_stop_token_costs_one_row_and_the_slot_is_clean(self, lm):
+        """The stop token is known only at the fetch, so its slot rides
+        one more step; that row is dropped, and the request admitted
+        into the freed slot and blocks is exact."""
+        from paddle_tpu.observability.metrics import MetricsRegistry
+
+        long = gen.GenerationRequest([9, 8, 7, 6], max_new_tokens=14,
+                                     sampling=_sampled(5), request_id="c")
+        probe = make_engine(lm).generate(
+            [[5, 7, 9]], max_new_tokens=8, sampling=_sampled(4))[0]
+        # the first token from the third on that the stream has not held
+        k = next(i for i in range(2, 8) if probe[i] not in probe[:i])
+        stopper = gen.GenerationRequest([5, 7, 9], max_new_tokens=8,
+                                        sampling=_sampled(4),
+                                        stop_token_ids=(probe[k],),
+                                        request_id="a")
+        after = gen.GenerationRequest([11, 12, 13, 14, 15],
+                                      max_new_tokens=7,
+                                      sampling=_sampled(6), request_id="b")
+        reqs = [long, stopper, after]
+        reg = MetricsRegistry()
+        eng = make_engine(lm, slots=2, metrics_registry=reg)
+        handles = [eng.submit(r) for r in reqs]
+        eng.run_until_idle()
+        assert handles[1].result() == probe[:k + 1]
+        assert handles[1].finish_reason == "stop_token"
+        assert [h.result() for h in handles] == gen.sequential_oracle(
+            lambda: make_engine(lm, slots=2), reqs)
+        assert eng.stats()["decode_rows_discarded"] == 1
+        assert eng.cache.pool.used_blocks == 0
+        text = reg.prometheus_text()
+        assert "generation_decode_rows_discarded_total" in text
+        assert "generation_decode_overlapped_total" in text
+
+    def test_a_preempted_slots_row_in_flight_is_dropped(self, lm):
+        """Preempted with a row in flight and admitted again into the
+        SAME slot before that row is fetched: the restarted stream
+        holds no token of its former life (rows are matched by the
+        slot's state object, not by its index)."""
+        req = gen.GenerationRequest([3, 1, 4, 1, 5], max_new_tokens=9,
+                                    sampling=_sampled(8))
+        eng = make_engine(lm, slots=1)
+        h = eng.submit(req)
+        for _ in range(3):
+            assert eng.step()
+        (slot, before), = eng._in_flight[1]
+        with eng._lock:
+            eng._preempt_slot(slot, "test")
+        assert eng.step()               # admits it again, same slot
+        assert eng._slot_state[slot] is not before
+        assert eng._slot_state[slot].generated == 1    # its token 0 only
+        eng.run_until_idle()
+        events = list(h.events(timeout=1))
+        last = max(i for i, e in enumerate(events) if e[0] == "restart")
+        assert [e[1] for e in events[last + 1:-1]] == list(range(9))
+        assert h.result() == gen.sequential_oracle(
+            lambda: make_engine(lm, slots=1), [req])[0]
+        assert eng.stats()["decode_rows_discarded"] == 1
+        assert eng.stats()["preempted"] == 1
+
+    def test_death_with_a_step_in_flight_requeues_everyone_once(self, lm):
+        """The death drill while a step is un-fetched: the in-flight
+        step is abandoned, and every handle (decoding, waiting for its
+        last row, queued) is handed to `on_death` exactly once."""
+        seen = {}
+
+        def hook(step_no):
+            if step_no >= 2:
+                seen["in_flight"] = eng._in_flight is not None
+                seen["waiting"] = [
+                    st is not None and not eng._active[s]
+                    for s, st in enumerate(eng._slot_state)]
+                raise gen.EngineDeadError("drill")
+
+        eng = make_engine(lm, slots=3, step_hook=hook)
+        requeued = []
+        eng.on_death = lambda engine, affected: requeued.extend(affected)
+        # the second request's last row (its third token) is in flight
+        # when the engine dies
+        handles = [eng.submit(gen.GenerationRequest(
+            [2 + i, 3, 4], max_new_tokens=n))
+            for i, n in enumerate([8, 3, 8, 8])]
+        with pytest.raises(gen.EngineDeadError):
+            while eng.step():
+                pass
+        assert seen["in_flight"] and seen["waiting"] == [False, True, False]
+        assert eng.dead and eng._in_flight is None
+        assert sorted(map(id, requeued)) == sorted(map(id, handles))
+        assert not any(h.done for h in handles)
+        assert eng.cache.pool.used_blocks == 0
+
+    def test_step_by_hand_delivers_the_last_step(self, lm):
+        """`step()` is True while anything is queued, live or
+        un-fetched: the iteration that only delivers counts."""
+        eng = make_engine(lm)
+        h = eng.submit(gen.GenerationRequest([5, 6, 7], max_new_tokens=3))
+        assert eng.step()               # prefill (token 0), dispatch
+        assert len(h._tokens) == 1 and eng._in_flight is not None
+        assert eng.step()               # dispatch the last row, deliver
+        assert len(h._tokens) == 2 and not eng._active.any()
+        assert eng._in_flight is not None and not h.done
+        assert eng.step()               # nothing to dispatch: deliver
+        assert h.done and len(h.result()) == 3
+        assert eng._in_flight is None and not eng.step()
+        more = [eng.submit(r) for r in mixed_requests(4)]
+        eng.run_until_idle()
+        assert all(m.done for m in more) and eng._in_flight is None
+        assert not eng.step()
+
+    def test_a_steady_batch_overlaps_every_step_but_the_first(self, lm):
+        """Four long requests, all admitted by the first iteration:
+        every later step is dispatched while the one before is
+        un-fetched, in one executable, and `generation_itl_ms` has one
+        observation a step."""
+        eng = make_engine(lm, slots=4)
+        handles = [eng.submit(gen.GenerationRequest(
+            [7 + i, 8, 9], max_new_tokens=40,
+            sampling=None if i % 2 else _sampled(20 + i)))
+            for i in range(4)]
+        eng.run_until_idle()
+        assert [len(h.result()) for h in handles] == [40] * 4
+        st = eng.stats()
+        assert st["decode_steps"] == 39
+        assert st["decode_overlapped"] / st["decode_steps"] > 0.9
+        assert st["decode_rows_discarded"] == 0
+        assert st["executables"]["decode_step"] == 1
+        assert eng._m_itl.summary()["count"] == st["decode_steps"]
+
+    def test_itl_leaves_out_the_gap_that_held_a_prefill(self, lm):
+        """`generation_itl_ms` is what a live stream waits while no
+        prompt goes in: the one gap with the late request's prefill in
+        it is `generation_prefill_ms`'s."""
+        eng = make_engine(lm, slots=2)
+        eng.submit(gen.GenerationRequest([5, 6, 7], max_new_tokens=12))
+        for _ in range(4):
+            eng.step()
+        eng.submit(gen.GenerationRequest([8, 9], max_new_tokens=4))
+        eng.run_until_idle()
+        st = eng.stats()
+        assert st["decode_overlapped"] == st["decode_steps"] - 1
+        assert eng._m_itl.summary()["count"] == st["decode_steps"] - 1
+        assert eng._m_prefill_ms.summary()["count"] == 2
+
+
 def test_tune_generation_block_and_draft_axes():
     from paddle_tpu.tune.space import generation_config_candidates
 
