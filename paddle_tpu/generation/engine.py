@@ -24,17 +24,17 @@ The execution model, and why the executable set stays enumerable:
   (`sampling.sample_tokens`) is one argmax in a step whose live rows
   are all greedy, a parked slot reading as greedy (`_park`);
   `generation_sampling_step_share` says how many steps were not.
-  The plain step keeps ONE step in flight (`_decode_once`): step t+1
-  is dispatched on the device's own copy of step t's sampled tokens,
-  and only then are step t's fetched (one transfer) and delivered, so
-  the host's work between steps runs while the device computes.
-  Lengths, steps and the cache advance at dispatch.  A request that
-  ends by length is known at dispatch and computes no extra row; one
-  that ends on a stop token, is preempted or fails with a row in
-  flight has that row dropped at delivery
-  (`generation_decode_rows_discarded_total`), matched by the slot's
-  state and not its index.  `generation_decode_overlapped_total` over
-  the decode steps says how often the step before was un-fetched.
+  The loop keeps ONE step in flight (`_decode_once`; a plain step or
+  a block-diffusion pass, below): step t+1 is dispatched on the
+  device's own copy of step t's outputs, and only then are step t's
+  fetched (one transfer) and delivered, so the host's work between
+  steps runs while the device computes.  Lengths, steps and the cache
+  advance at dispatch.  A request that ends by length is known at
+  dispatch and computes no extra row; one that ends on a stop token,
+  is preempted or fails with a row in flight has that row dropped at
+  delivery (`generation_decode_rows_discarded_total`), matched by the
+  slot's state and not its index.  `generation_decode_overlapped_total`
+  over the decode steps says how often the step before was un-fetched.
 * **paged KV** (the PR-17 rebuild) — the store is a block pool, one
   ``[num_blocks, block_size, H*D]`` array per layer for K and for V
   (`kv_cache` says why that shape), plus a host per-slot block table
@@ -96,7 +96,16 @@ The execution model, and why the executable set stays enumerable:
   mask id).  A token is streamed once it and every position before
   it is revealed, with the raw log-probability it had at the pass
   that revealed it; a request ends on the token that meets
-  ``max_new_tokens``, its last block cut and not committed.
+  ``max_new_tokens``, its last block cut and not committed.  The
+  block's tokens and mask are chained on the device from one pass to
+  the next, the rows the host changed (a block opened, a slot freed)
+  written over them by ``generation_block_merge``: whether pass t
+  commits follows from the mask after pass t-1, which the host has
+  when it dispatches pass t+1, so one pass stays in flight like a
+  plain step.  Under ``sequential`` and ``low_confidence_static`` a
+  pass reveals a fixed number of positions and the pass that streams
+  a request's last token is known at dispatch; under the dynamic rule
+  a row may be computed for a request that has just ended.
 
 Exactness: scheduling is invisible in the tokens.  Per-request PRNG
 streams (`sampling.py`) + row-independent slot math make the engine's
@@ -737,8 +746,8 @@ class GenerationEngine:
             labelnames=lbl).labels(self._engine)
         self._m_overlapped = reg.counter(
             "generation_decode_overlapped_total",
-            "Plain decode steps dispatched while the step before was "
-            "un-fetched (over decode steps: the overlap share)",
+            "Decode steps (plain or block) dispatched while the step "
+            "before was un-fetched (over decode steps: the overlap share)",
             labelnames=lbl).labels(self._engine)
         self._m_discarded = reg.counter(
             "generation_decode_rows_discarded_total",
@@ -1044,8 +1053,9 @@ class GenerationEngine:
     def _init_block_diffusion(self, denoising_steps, remasking, threshold,
                               other_paths):
         """Check and keep the block-diffusion knobs, and make the
-        per-slot state of the block in flight (host mirrors, like the
-        rest of a slot's state)."""
+        per-slot state of the block being generated: the device's
+        tokens and mask, chained from pass to pass, and the host's
+        mirrors of them as of the last pass delivered."""
         b, n = self.block_length, self.slots
         steps = int(denoising_steps or b)
         if steps < 1 or b % steps:
@@ -1076,6 +1086,18 @@ class GenerationEngine:
         self._blk_pass = np.zeros(n, np.int32)      # denoise passes done
         self._blk_reveal_pass = np.zeros((n, b), np.int32)
         self._blk_logprobs = np.zeros((n, b), np.float32)
+        # the pass's tokens and mask operands as the device holds them
+        # (a pass's outputs are the next one's), and the slots whose
+        # host rows (tokens, mask and what lies beyond the request) the
+        # next dispatch writes over them: a block opened, a slot freed
+        self._blk_state = (jnp.full((n, b), self._mask_id, jnp.int32),
+                           jnp.zeros((n, b), bool))
+        self._blk_fresh = np.zeros(n, bool)
+        self._merge_block = jax.jit(_named(
+            lambda tokens, revealed, fresh, mine, mine_revealed: (
+                jnp.where(fresh[:, None], mine, tokens),
+                jnp.where(fresh[:, None], mine_revealed, revealed)),
+            "generation_block_merge"))
 
     def _make_block_step_fn(self):
         """ONE pass over every slot's block (see module docstring):
@@ -1191,6 +1213,7 @@ class GenerationEngine:
         tokens = np.zeros((1, width), np.int32)
         tokens[0, :c_real] = request.prompt_ids[cs.pos:cs.pos + c_real]
         last = cs.pos + c_real >= whole
+        self._prefill_since = True
         with _DeviceCall(self, "generation.prefill_chunk",
                          args={"width": width, "slot": int(slot),
                                "pos": cs.pos,
@@ -1233,7 +1256,8 @@ class GenerationEngine:
         """A new block in ``slot``: ``given`` tokens (what a prompt left
         over) revealed and not to be streamed, mask tokens after them;
         those past the request's last token stay mask tokens (its last
-        block is cut: a pass reveals only what will be streamed)."""
+        block is cut: a pass reveals only what will be streamed).  The
+        next dispatch writes it over the device's copy."""
         self._blk_tokens[slot] = self._mask_id
         self._blk_tokens[slot, :len(given)] = given
         self._blk_revealed[slot] = False
@@ -1244,66 +1268,95 @@ class GenerationEngine:
         self._blk_beyond[slot] = (
             self._lengths[slot] + np.arange(self.block_length)
             >= len(request.prompt_ids) + request.max_new_tokens)
+        self._blk_fresh[slot] = True
 
-    def _block_once(self):
-        """One `generation_block_step` over the active slots and what the
-        host does with it: a slot whose block was clean has committed
-        it and opens the next; any other adopts what the pass revealed
-        and streams every token that has none masked before it."""
+    def _dispatch_block(self, overlapped):
+        """Launch one `generation_block_step` over the active slots on
+        the device's own copy of the pass before's tokens and mask, the
+        host's fresh rows (`_blk_fresh`) written over it first.  What
+        the host knows of the pass it learns after the pass before is
+        delivered (`_foresee_block`)."""
         b = self.block_length
-        with _trace.span("generation.grow", cat="generation"):
-            for slot in list(np.nonzero(self._active)[0]):
-                if self._active[slot] and not self._grow_or_preempt(
-                        slot, int(self._lengths[slot]) + b):
-                    self._fail_slot(
-                        slot, "kv pool exhausted: no preemptable slot "
-                        "left to make room")
-            if not self._active.any():
-                return
-        live = np.nonzero(self._active)[0]
-        committing = self._blk_revealed[live].all(axis=1)
+        rows = np.nonzero(self._active)[0]
+        args = self._step_shares(b)
+        args.update(overlapped=int(overlapped), passes=len(rows))
         t0 = time.perf_counter()
-        with _trace.span("generation.block_step", cat="generation",
-                         args={"passes": len(live),
-                               "committing": int(committing.sum())}) as sp:
-            with _DeviceCall(self, "generation.decode_dispatch",
-                             args=self._step_shares(b)):
-                with _TRACE_LOCK:
-                    out = self._decode_step_fn(*self._decode_operands())
-            # the host waits here while the device works
-            with _DeviceCall(self, "generation.decode_fetch"):
-                tokens, revealed, lps, *aux = jax.device_get(
-                    out[self._nc:])
-            revealed = revealed & ~self._blk_beyond
-            newly = int((revealed[live] & ~self._blk_revealed[live]).sum())
-            sp.add_args(revealed=newly)
+        with _DeviceCall(self, "generation.decode_dispatch", args=args):
+            with _TRACE_LOCK:
+                if self._blk_fresh.any():
+                    self._blk_state = self._merge_block(
+                        *self._blk_state, self._blk_fresh.copy(),
+                        self._blk_tokens.copy(),
+                        self._blk_revealed | self._blk_beyond)
+                    self._blk_fresh[:] = False
+                out = self._decode_step_fn(*self._decode_operands())
         self.cache.update(*out[:self._nc])
-        self._decode_steps += 1
-        self._m_itl.observe((time.perf_counter() - t0) * 1e3)
-        self._m_block_passes.inc(len(live))
-        self._m_block_commits.inc(int(committing.sum()))
-        self._m_revealed.inc(newly)
-        self._m_block_rows.inc(int(self._lengths[live].sum()) + b * len(live))
+        self._blk_state = tuple(out[self._nc:self._nc + 2])
+        self._count_dispatch(overlapped, t0)
+        self._m_block_rows.inc(int(self._lengths[rows].sum()) + b * len(rows))
+        # which rows commit is known once the pass before is delivered
+        self._in_flight = (out[self._nc:],
+                           [(slot, self._slot_state[slot]) for slot in rows],
+                           np.zeros(len(rows), bool))
+
+    def _foresee_block(self):
+        """What the host can tell of the pass in flight before fetching
+        it, once the pass before is delivered: the host's mirror is then
+        the pass's input.  A slot whose block was clean commits it in
+        this pass: its length and steps advance and the next block opens
+        (written over the device's copy at the next dispatch).  A slot
+        whose pass reveals its request's last tokens (no more masked
+        than a pass reveals, in the request's last block) is parked:
+        the request ends when the pass is delivered."""
+        _, flight, commits = self._in_flight
+        b, per = self.block_length, self.block_length // self.denoising_steps
+        for i, (slot, st) in enumerate(flight):
+            if self._slot_state[slot] is not st:
+                continue
+            left = int((~(self._blk_revealed[slot]
+                          | self._blk_beyond[slot])).sum())
+            if not left:
+                commits[i] = True
+                self._m_block_commits.inc()
+                self._lengths[slot] += b
+                self._steps[slot] += b
+                self._open_block(slot)
+            elif left <= per and self._lengths[slot] + b >= len(
+                    st.request.prompt_ids) + st.request.max_new_tokens:
+                self._park(slot)
+
+    def _deliver_block(self, outputs, flight, commits):
+        """Fetch a dispatched pass's outputs in one transfer and adopt
+        them: a slot whose pass committed its block has opened the next
+        one already; any other takes what the pass revealed and streams
+        every token that has none masked before it.  A row whose slot
+        no longer holds the `_Slot` it was computed for is dropped."""
+        b = self.block_length
+        tokens, revealed, lps, *aux = self._fetch(outputs)
         if aux:
             self._observe_aux(aux[0])
-        with _trace.span("generation.emit", cat="generation"):
-            for slot, commit in zip(live, committing):
-                if commit:
-                    self._lengths[slot] += b
-                    self._steps[slot] += b
-                    self._open_block(slot)
+        passes = newly = 0
+        with _trace.span("generation.emit", cat="generation") as sp:
+            for (slot, st), commit in zip(flight, commits):
+                if self._slot_state[slot] is not st:
+                    self._m_discarded.inc()
                     continue
-                new = revealed[slot] & ~self._blk_revealed[slot]
+                passes += 1
+                if commit:
+                    continue
+                got = revealed[slot] & ~self._blk_beyond[slot]
+                new = got & ~self._blk_revealed[slot]
+                newly += int(new.sum())
                 # a token keeps the pass and the log-probability of its
                 # reveal until the positions before it let it stream
                 self._blk_reveal_pass[slot, new] = self._blk_pass[slot]
                 self._blk_logprobs[slot, new] = lps[slot, new]
                 self._blk_pass[slot] += 1
                 self._blk_tokens[slot] = tokens[slot]
-                self._blk_revealed[slot] = revealed[slot]
-                st = self._slot_state[slot]
-                while self._active[slot] and self._blk_streamed[slot] < b \
-                        and revealed[slot, self._blk_streamed[slot]]:
+                self._blk_revealed[slot] = got
+                while self._slot_state[slot] is st \
+                        and self._blk_streamed[slot] < b \
+                        and got[self._blk_streamed[slot]]:
                     j = int(self._blk_streamed[slot])
                     self._blk_streamed[slot] += 1
                     st.handle.reveal_passes.append(
@@ -1315,6 +1368,9 @@ class GenerationEngine:
                         self._m_ttft.observe(
                             (time.perf_counter() - st.handle.t_submit)
                             * 1e3)
+            sp.add_args(revealed=newly, commits=int(commits.sum()))
+        self._m_block_passes.inc(passes)
+        self._m_revealed.inc(newly)
 
     # -- block accounting (paged) -----------------------------------------
     def _set_block_gauges(self):
@@ -1367,9 +1423,7 @@ class GenerationEngine:
             cs = self._chunking[slot]
             st = _Slot(cs.request, cs.handle)
             self._chunking[slot] = None
-        self._park(slot)
-        self._release_blocks(slot)
-        self._free.append(slot)
+        self._free_slot(slot)
         st.handle._restart()
         st.handle.t_queued = time.perf_counter()   # a new wait starts
         self._pending.insert(0, (st.request, st.handle))
@@ -1382,8 +1436,21 @@ class GenerationEngine:
     def _grow_or_preempt(self, slot, n_tokens):
         """Grow ``slot`` to ``n_tokens`` rows, preempting the least-
         progressed OTHER slot (deterministic: fewest generated tokens,
-        lowest id) until it fits; False when no victim is left."""
+        lowest id) until it fits; False when no victim is left.  A step
+        in flight that ends a request (its slot parked, the request
+        still there) is delivered first: that returns the request's
+        blocks, as a synchronous loop would have before this grow."""
         while not self._ensure_blocks(slot, n_tokens):
+            if self._in_flight is not None and any(
+                    self._slot_state[s] is st and not self._active[s]
+                    for s, st in self._in_flight[1]):
+                before, self._in_flight = self._in_flight, None
+                (self._deliver_block if self.block_length
+                 else self._deliver)(*before)
+                if self._slot_state[slot] is None \
+                        and self._chunking[slot] is None:
+                    return True        # its own request ended there
+                continue
             victims = [
                 s for s in range(self.slots)
                 if s != slot and (self._slot_state[s] is not None
@@ -1401,11 +1468,24 @@ class GenerationEngine:
     def _fail_slot(self, slot, msg):
         st = self._slot_state[slot]
         self._slot_state[slot] = None
+        self._free_slot(slot)
+        st.handle._fail(msg)
+
+    def _free_slot(self, slot):
+        """``slot`` holds nobody: parked, its blocks back in the pool
+        (a step in flight that names them was dispatched first, so the
+        device writes them before a new owner's call does), on the free
+        list; a block-diffusion slot's rows read as a slot never used
+        from the next dispatch on."""
         self._park(slot)
         if self.paged:
             self._release_blocks(slot)
+        if self.block_length:
+            self._blk_tokens[slot] = self._mask_id
+            self._blk_revealed[slot] = False
+            self._blk_beyond[slot] = False
+            self._blk_fresh[slot] = True
         self._free.append(slot)
-        st.handle._fail(msg)
 
     def _park(self, slot):
         """Nobody decodes in ``slot`` any more, and it reads as greedy:
@@ -1554,7 +1634,7 @@ class GenerationEngine:
     def step(self):
         """One scheduler iteration: advance every mid-flight chunked
         prefill by ONE chunk, refill free slots (prefill), then one
-        decode step over the active batch.  A plain decode step is
+        decode step over the active batch.  A decode step is
         dispatched here and its tokens are delivered by the NEXT
         iteration, after that one's dispatch (`_decode_once`); an
         iteration that only delivers counts as work.  Returns True when
@@ -1829,13 +1909,16 @@ class GenerationEngine:
 
     # -- decode ------------------------------------------------------------
     def _decode_once(self):
-        """One decode step over the active slots.  The plain step keeps
-        one step in flight: step t+1 is dispatched on the device's own
-        copy of step t's tokens, and only then are step t's fetched and
-        delivered, so that the fetch's round trip, the streams'
-        wake-ups and the loop's own Python run while the device
-        computes.  A block step and a verify step decide on the host
-        from what they fetch, and stay synchronous."""
+        """One decode step over the active slots, one step in flight:
+        step t+1 is dispatched on the device's own copy of step t's
+        outputs, and only then are step t's fetched and delivered, so
+        that the fetch's round trip, the streams' wake-ups and the
+        loop's own Python run while the device computes.  A plain step
+        is `_dispatch_decode` and `_deliver`; a block-diffusion pass is
+        `_dispatch_block` and `_deliver_block`, and once pass t is
+        delivered the host knows what pass t+1 does with each block
+        (`_foresee_block`).  A verify step decides on the host from
+        what it fetches, and stays synchronous."""
         if self._active.any():
             if self._step_hook is not None:
                 try:
@@ -1844,29 +1927,33 @@ class GenerationEngine:
                     self._die("injected death at decode step %d"
                               % self._decode_steps)
                     raise
-            if self.block_length:
-                return self._block_once()
             if self.draft_model is not None:
                 with _trace.span("generation.grow", cat="generation"):
                     viable = self._spec_viable()
                 if viable and self._spec_once():
                     return
-            # plain step: make room for ONE new row per active slot
+            # make room for the rows each active slot's step writes
             if self.paged:
+                rows = self.block_length or 1
                 with _trace.span("generation.grow", cat="generation"):
                     for slot in list(np.nonzero(self._active)[0]):
                         if not self._active[slot]:
                             continue   # preempted as an earlier victim
                         if not self._grow_or_preempt(
-                                slot, int(self._lengths[slot]) + 1):
+                                slot, int(self._lengths[slot]) + rows):
                             self._fail_slot(
                                 slot, "kv pool exhausted: no preemptable "
                                 "slot left to make room")
+        dispatch, deliver = (
+            (self._dispatch_block, self._deliver_block) if self.block_length
+            else (self._dispatch_decode, self._deliver))
         before, self._in_flight = self._in_flight, None
         if self._active.any():
-            self._dispatch_decode(overlapped=before is not None)
+            dispatch(overlapped=before is not None)
         if before is not None:
-            self._deliver(*before)
+            deliver(*before)
+        if self.block_length and self._in_flight is not None:
+            self._foresee_block()
         if self.draft_model is not None and self._in_flight is not None:
             # a verify step proposes from the tokens on the host
             flight, self._in_flight = self._in_flight, None
@@ -1898,11 +1985,7 @@ class GenerationEngine:
                 out = self._decode_step_fn(*self._decode_operands())
         self.cache.update(*out[:self._nc])
         self._last_tokens = out[self._nc]
-        self._decode_steps += 1
-        if overlapped:
-            self._m_overlapped.inc()
-        else:                          # nothing to measure its gap from
-            self._t_fetch, self._prefill_since = t0, False
+        self._count_dispatch(overlapped, t0)
         # the step writes every ACTIVE slot's new token at its length
         # (inactive rows compute garbage nobody reads: their writes go
         # to the garbage block)
@@ -1917,6 +2000,28 @@ class GenerationEngine:
                 self._park(slot)       # ends when this row is delivered
         self._in_flight = (out[self._nc:], flight)
 
+    def _count_dispatch(self, overlapped, t0):
+        """A step dispatched at ``t0``, ``overlapped`` when the one
+        before was still un-fetched."""
+        self._decode_steps += 1
+        if overlapped:
+            self._m_overlapped.inc()
+        else:                          # nothing to measure its gap from
+            self._t_fetch, self._prefill_since = t0, False
+
+    def _fetch(self, outputs):
+        """A dispatched step's outputs, in one transfer; the wait since
+        the fetch before is a `generation_itl_ms` observation unless a
+        prompt went in between (that wait is `generation_prefill_ms`'s)."""
+        # the host waits here, while the device works on the next step
+        with _DeviceCall(self, "generation.decode_fetch"):
+            got = jax.device_get(outputs)
+        now = time.perf_counter()
+        if not self._prefill_since:
+            self._m_itl.observe((now - self._t_fetch) * 1e3)
+        self._t_fetch, self._prefill_since = now, False
+        return got
+
     def _deliver(self, outputs, flight):
         """Fetch a dispatched step's tokens (and log-probabilities) in
         one transfer and hand each to its stream.  A row whose slot no
@@ -1924,13 +2029,7 @@ class GenerationEngine:
         stopped on a token, was preempted or failed since the dispatch)
         is dropped: a restarted stream never sees a token of its former
         life."""
-        # the host waits here, while the device works on the next step
-        with _DeviceCall(self, "generation.decode_fetch"):
-            nxt, *lps = jax.device_get(outputs)
-        now = time.perf_counter()
-        if not self._prefill_since:    # that wait is generation_prefill_ms's
-            self._m_itl.observe((now - self._t_fetch) * 1e3)
-        self._t_fetch, self._prefill_since = now, False
+        nxt, *lps = self._fetch(outputs)
         with _trace.span("generation.emit", cat="generation"):
             for slot, st in flight:
                 st.in_flight -= 1
@@ -2048,10 +2147,7 @@ class GenerationEngine:
         st = self._slot_state[slot]
         st.handle._finish(reason)
         self._slot_state[slot] = None
-        self._park(slot)
-        if self.paged:
-            self._release_blocks(slot)
-        self._free.append(slot)
+        self._free_slot(slot)
         _trace.instant("generation.finish", cat="generation",
                        args={"slot": int(slot), "reason": reason,
                              "request_id": st.request.request_id})
@@ -2390,17 +2486,14 @@ class GenerationEngine:
 
     def _decode_operands(self):
         """The decode executable's live operands; the last one says who
-        is live: a paged engine's block tables, a dense one's mask."""
-        if self.block_length:
-            return (self._params, *self.cache.arrays(), self._lengths,
-                    self._blk_tokens,
-                    self._blk_revealed | self._blk_beyond, self._keys,
-                    self._steps, self._temp, self._top_k, self._top_p,
-                    self._decode_tables())
+        is live: a paged engine's block tables, a dense one's mask.  The
+        tokens (and a block step's mask) are the device's own."""
+        tokens = (self._blk_state if self.block_length
+                  else (self._last_tokens,))
         # the host's arrays are copies: the step may still be reading
         # them when a slot's state is next written
         return (self._params, *self.cache.arrays(), self._lengths.copy(),
-                self._last_tokens, self._keys.copy(), self._steps.copy(),
+                *tokens, self._keys.copy(), self._steps.copy(),
                 self._temp.copy(), self._top_k.copy(), self._top_p.copy(),
                 self._decode_tables() if self.paged else self._active.copy())
 
@@ -2429,8 +2522,8 @@ class GenerationEngine:
             "cache": self.cache.describe(),
             "decode_executables": self._decode_cache_size(),
             "preempted": int(self._m_preempt.value),
-            # over ``decode_steps``: the share of plain steps dispatched
-            # while the step before was un-fetched
+            # over ``decode_steps``: the share of steps dispatched while
+            # the step before was un-fetched
             "decode_overlapped": int(self._m_overlapped.value),
             "decode_rows_discarded": int(self._m_discarded.value),
             # mean over the decode/verify steps so far (None before one)

@@ -421,3 +421,145 @@ def test_a_prompt_prefilled_in_chunks_scores_as_the_reference(chunk, lm,
         assert np.max(np.abs(np.asarray(h.logprobs()) - want)) < ATOL
     assert eng.stats()["executables"]["chunk"] == {chunk: 1}
     assert eng.stats()["executables"]["prefill"] == {}
+
+
+def _solo(lm, r, **kw):
+    """``r`` served alone in a fresh engine: its tokens, log-probabilities
+    and the pass that revealed each token."""
+    eng = GenerationEngine(lm, slots=2, max_len=64, logprobs=True, **kw)
+    h = eng.submit(GenerationRequest(
+        r.prompt_ids, max_new_tokens=r.max_new_tokens, sampling=r.sampling,
+        stop_token_ids=r.stop_token_ids))
+    eng.run_until_idle()
+    return h.result(), h.logprobs(), h.reveal_passes
+
+
+def _assert_solo(lm, handles, **kw):
+    for h in handles:
+        tokens, lps, passes = _solo(lm, h.request, **kw)
+        assert h.result() == tokens and h.reveal_passes == passes
+        np.testing.assert_allclose(h.logprobs(), lps, rtol=0, atol=1e-6)
+
+
+@pytest.mark.parametrize("rule", REMASKING_RULES)
+def test_a_pass_in_flight_serves_each_request_as_alone(rule, lm):
+    """Six requests through two slots, one pass in flight: pass t+1 is
+    dispatched on the device's copy of pass t's block before pass t is
+    fetched.  Every stream is what it is alone, token, log-probability
+    and reveal pass; every pass but those dispatched with nothing
+    un-fetched overlaps the one before; under the rules that reveal a
+    fixed number a pass the last one is known at dispatch and no row
+    is computed for a finished request (the dynamic rule's extra
+    reveals end some a pass early: that row is dropped)."""
+    kw = dict(denoising_steps=2, remasking=rule, confidence_threshold=0.0085)
+    eng = GenerationEngine(lm, slots=2, max_len=64, logprobs=True, **kw)
+    handles = []
+    for n_p, n_o in REQUESTS:
+        sp = SamplingParams(temperature=0.7 if n_p in (4, 9) else 0.0,
+                            top_k=5, top_p=0.9, seed=n_p)
+        handles.append(eng.submit(GenerationRequest(
+            prompt_of(n_p), max_new_tokens=n_o, sampling=sp)))
+    alone = 0                       # passes dispatched with none in flight
+    while True:
+        idle, steps = eng._in_flight is None, eng._decode_steps
+        if not eng.step():
+            break
+        alone += idle and eng._decode_steps > steps
+    st = eng.stats()
+    assert st["decode_overlapped"] == st["decode_steps"] - alone
+    assert alone == 1               # the first: the slots never both idle
+    assert eng._in_flight is None and st["executables"]["decode_step"] == 1
+    # the dynamic rule may end a request a pass before the host foresaw
+    assert (st["decode_rows_discarded"] > 0) == (
+        rule == "low_confidence_dynamic")
+    if rule == "sequential":
+        for (n_p, n_o), h in zip(REQUESTS, handles):
+            assert h.reveal_passes == ref.sequential_passes(n_p, n_o, 4, 2)
+    _assert_solo(lm, handles, **kw)
+
+
+def test_a_steady_batch_of_passes_overlaps_every_pass_but_the_first(lm):
+    """Four requests shorter than a block (no chunk), all admitted by
+    the first iteration: every later pass is dispatched while the one
+    before is un-fetched, `generation_itl_ms` has one observation a
+    pass, and the passes follow the closed form."""
+    eng = GenerationEngine(lm, slots=4, max_len=64, denoising_steps=4)
+    handles = [eng.submit(GenerationRequest([7 + i, 8, 9], max_new_tokens=13))
+               for i in range(4)]
+    eng.run_until_idle()
+    assert [len(h.result()) for h in handles] == [13] * 4
+    st = eng.stats()
+    # 1 + 4 + 4 + 4 positions: 1 + 4 + 4 + 4 reveals and three commits
+    assert st["decode_steps"] == 16
+    assert st["decode_overlapped"] == st["decode_steps"] - 1
+    assert st["decode_rows_discarded"] == 0
+    assert eng._m_itl.summary()["count"] == st["decode_steps"]
+    assert st["block_diffusion"]["passes"] == 4 * 16
+    assert st["block_diffusion"]["commits"] == 4 * 3
+    # the host's rows go in through one small program a trace can name
+    assert eng._merge_block.__name__ == "generation_block_merge"
+    assert eng._jit_cache_size(eng._merge_block) == 1
+
+
+def test_a_slot_preempted_with_its_pass_in_flight_restarts_clean(lm):
+    """Preempted while its pass is un-fetched and admitted again into
+    the SAME slot before that pass is delivered: the row is dropped and
+    the restarted stream holds no token of its former life."""
+    req = GenerationRequest(prompt_of(9), max_new_tokens=7,
+                            sampling=SamplingParams(temperature=0.7, top_k=5,
+                                                    top_p=0.9, seed=9))
+    eng = GenerationEngine(lm, slots=1, max_len=64, logprobs=True,
+                           denoising_steps=2)
+    h = eng.submit(req)
+    for _ in range(3):
+        assert eng.step()
+    assert len(h._tokens) > 0
+    (slot, before), = eng._in_flight[1]
+    with eng._lock:
+        eng._preempt_slot(slot, "test")
+    assert eng.step()               # admits it again, same slot
+    assert eng._slot_state[slot] is not before
+    eng.run_until_idle()
+    events = list(h.events(timeout=1))
+    last = max(i for i, e in enumerate(events) if e[0] == "restart")
+    assert [e[1] for e in events[last + 1:-1]] == list(range(7))
+    assert eng.stats()["decode_rows_discarded"] == 1
+    assert eng.stats()["preempted"] == 1
+    _assert_solo(lm, [h], denoising_steps=2)
+
+
+def test_a_stop_with_a_pass_in_flight_costs_one_row_and_frees_clean(lm):
+    """A stop token is known only when its pass is fetched, so its slot
+    rides the pass after; that row is dropped.  The next request takes
+    the freed slot and its pages (its prompt's chunk writes them after
+    the dropped pass did) and is exact, and so is the stream beside."""
+    def sampled(seed):
+        return SamplingParams(temperature=0.7, top_k=5, top_p=0.9, seed=seed)
+
+    probe = _solo(lm, GenerationRequest(prompt_of(6), max_new_tokens=10,
+                                        sampling=sampled(6)))[0]
+    # the first token from the third on that the stream has not held
+    k = next(i for i in range(2, 9) if probe[i] not in probe[:i])
+    long = GenerationRequest(prompt_of(11), max_new_tokens=16,
+                             sampling=sampled(11))
+    stopper = GenerationRequest(prompt_of(6), max_new_tokens=10,
+                                sampling=sampled(6),
+                                stop_token_ids=(probe[k],))
+    after = GenerationRequest(prompt_of(9), max_new_tokens=7,
+                              sampling=sampled(9))
+    eng = GenerationEngine(lm, slots=2, max_len=64, logprobs=True)
+    handles = [eng.submit(r) for r in (long, stopper, after)]
+    held, reused = [], []
+    while eng.step():
+        st = eng._slot_state[1]
+        if st is not None and st.request is stopper:
+            held = list(eng._slot_blocks[1])
+        if st is not None and st.request is after and not reused:
+            reused = list(eng._slot_blocks[1])
+    assert handles[1].result() == probe[:k + 1]
+    assert handles[1].finish_reason == "stop_token"
+    assert set(held) & set(reused)
+    assert [e[0] for e in handles[2].events(timeout=1)].count("restart") == 0
+    assert eng.stats()["decode_rows_discarded"] == 1
+    assert eng.cache.pool.used_blocks == 0
+    _assert_solo(lm, handles)
