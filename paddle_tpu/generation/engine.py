@@ -134,6 +134,7 @@ from ..fluid import framework
 from ..observability import locks as _locks
 from ..observability import trace as _trace
 from ..observability.metrics import default_registry, unique_instance_label
+from ..observability.system import CollectionPauses
 from .kv_cache import (
     KVCache,
     PagedKVCache,
@@ -185,26 +186,54 @@ def _named(fn, name):
     return fn
 
 
-class _DeviceCall:
-    """A span around a device call (its dispatch, or the fetch that
-    waits for it), whose duration also counts as the step's device
-    share: `generation_sched_host_ms` is what a step took beyond these."""
+class _Timed:
+    """A span that times itself whether or not a trace records it: its
+    duration is observed into ``hist`` (a histogram series, in ms), so
+    the histogram and the span measure one stretch."""
 
-    __slots__ = ("_engine", "_span", "_t0")
+    __slots__ = ("_span", "_hist", "_t0")
 
-    def __init__(self, engine, name, args=None, trace_id=None):
-        self._engine = engine
+    def __init__(self, name, hist=None, args=None, trace_id=None):
         self._span = _trace.span(name, cat="generation", args=args,
                                  trace_id=trace_id)
+        self._hist = hist
 
     def __enter__(self):
         self._span.__enter__()
         self._t0 = time.perf_counter()
         return self
 
+    def _took(self, seconds):
+        if self._hist is not None:
+            self._hist.observe(seconds * 1e3)
+
     def __exit__(self, *exc):
-        self._engine._device_s += time.perf_counter() - self._t0
+        self._took(time.perf_counter() - self._t0)
         return self._span.__exit__(*exc)
+
+
+class _DeviceCall(_Timed):
+    """A span around a device call (its dispatch, or the fetch that
+    waits for it), whose duration also counts as the step's device
+    share: `generation_sched_host_ms` is what a step took beyond these."""
+
+    __slots__ = ("_engine",)
+
+    def __init__(self, engine, name, args=None, trace_id=None, hist=None):
+        super().__init__(name, hist, args, trace_id)
+        self._engine = engine
+
+    def _took(self, seconds):
+        self._engine._device_s += seconds
+        if self._hist is not None:
+            self._hist.observe(seconds * 1e3)
+
+
+# the interpreter's collections, reported by every running engine's loop
+_COLLECTIONS = CollectionPauses(
+    "generation.gc", "generation_gc_pause_ms",
+    "One collection of the interpreter, whichever thread ran it (ms)",
+    cat="generation")
 
 
 def _shed_error(reason, retry_after_s, detail):
@@ -732,6 +761,24 @@ class GenerationEngine:
         self._m_sched_host = reg.histogram(
             "generation_sched_host_ms",
             "A step() that decoded, less its time in device calls (ms)",
+            labelnames=lbl).labels(self._engine)
+        self._m_dispatch = reg.histogram(
+            "generation_dispatch_ms",
+            "One decode dispatch: token merge, operands, the step's call "
+            "and the bookkeeping under the span (ms)",
+            labelnames=lbl).labels(self._engine)
+        self._m_dispatch_call = reg.histogram(
+            "generation_dispatch_call_ms",
+            "The decode step's jitted call inside a dispatch, until it "
+            "returns: arguments flattened, host operands put, enqueued (ms)",
+            labelnames=lbl).labels(self._engine)
+        self._m_lock_wait = reg.histogram(
+            "generation_lock_wait_ms",
+            "The scheduler's wait for the engine lock, one a step() (ms)",
+            labelnames=lbl).labels(self._engine)
+        self._m_decode_steps = reg.counter(
+            "generation_decode_steps_total",
+            "Decode steps dispatched (plain, block or verify)",
             labelnames=lbl).labels(self._engine)
         self._device_s = 0.0           # this step's time in device calls
         self._m_occupancy = reg.gauge(
@@ -1281,15 +1328,18 @@ class GenerationEngine:
         args = self._step_shares(b)
         args.update(overlapped=int(overlapped), passes=len(rows))
         t0 = time.perf_counter()
-        with _DeviceCall(self, "generation.decode_dispatch", args=args):
+        with _DeviceCall(self, "generation.decode_dispatch", args=args,
+                         hist=self._m_dispatch):
             with _TRACE_LOCK:
                 if self._blk_fresh.any():
-                    self._blk_state = self._merge_block(
-                        *self._blk_state, self._blk_fresh.copy(),
-                        self._blk_tokens.copy(),
-                        self._blk_revealed | self._blk_beyond)
+                    with _trace.span("generation.dispatch_merge",
+                                     cat="generation"):
+                        self._blk_state = self._merge_block(
+                            *self._blk_state, self._blk_fresh.copy(),
+                            self._blk_tokens.copy(),
+                            self._blk_revealed | self._blk_beyond)
                     self._blk_fresh[:] = False
-                out = self._decode_step_fn(*self._decode_operands())
+                out = self._call_decode_step()
         self.cache.update(*out[:self._nc])
         self._blk_state = tuple(out[self._nc:self._nc + 2])
         self._count_dispatch(overlapped, t0)
@@ -1642,7 +1692,7 @@ class GenerationEngine:
         un-fetched."""
         t_step = time.perf_counter()
         with _trace.span("generation.step", cat="generation"):
-            with _trace.span("generation.lock_wait", cat="generation"):
+            with _Timed("generation.lock_wait", self._m_lock_wait):
                 self._lock.acquire()
             try:
                 return self._step_locked(t_step)
@@ -1976,13 +2026,17 @@ class GenerationEngine:
         args = self._step_shares(1)
         args["overlapped"] = int(overlapped)
         t0 = time.perf_counter()
-        with _DeviceCall(self, "generation.decode_dispatch", args=args):
+        with _DeviceCall(self, "generation.decode_dispatch", args=args,
+                         hist=self._m_dispatch):
             with _TRACE_LOCK:
                 if self._fresh.any():
-                    self._last_tokens = self._merge_tokens(
-                        self._last_tokens, self._fresh, self._tok_host.copy())
+                    with _trace.span("generation.dispatch_merge",
+                                     cat="generation"):
+                        self._last_tokens = self._merge_tokens(
+                            self._last_tokens, self._fresh,
+                            self._tok_host.copy())
                     self._fresh = np.zeros(self.slots, bool)
-                out = self._decode_step_fn(*self._decode_operands())
+                out = self._call_decode_step()
         self.cache.update(*out[:self._nc])
         self._last_tokens = out[self._nc]
         self._count_dispatch(overlapped, t0)
@@ -2000,10 +2054,20 @@ class GenerationEngine:
                 self._park(slot)       # ends when this row is delivered
         self._in_flight = (out[self._nc:], flight)
 
+    def _call_decode_step(self):
+        """The decode (or block) step's call inside a dispatch, its
+        operands built first: the two parts of the dispatch that are
+        neither the merge nor the bookkeeping after the call."""
+        with _trace.span("generation.dispatch_operands", cat="generation"):
+            operands = self._decode_operands()
+        with _Timed("generation.dispatch_call", self._m_dispatch_call):
+            return self._decode_step_fn(*operands)
+
     def _count_dispatch(self, overlapped, t0):
         """A step dispatched at ``t0``, ``overlapped`` when the one
         before was still un-fetched."""
         self._decode_steps += 1
+        self._m_decode_steps.inc()
         if overlapped:
             self._m_overlapped.inc()
         else:                          # nothing to measure its gap from
@@ -2072,7 +2136,8 @@ class GenerationEngine:
         cur = self._tok_host.copy()
         t0 = time.perf_counter()
         for i in range(k):
-            with _DeviceCall(self, "generation.decode_dispatch"):
+            with _DeviceCall(self, "generation.decode_dispatch",
+                             hist=self._m_dispatch):
                 with _TRACE_LOCK:
                     *darrays, nxt = self._draft_decode_fn(
                         self._draft_params, *self._draft_cache.arrays(),
@@ -2085,7 +2150,8 @@ class GenerationEngine:
             [self._tok_host[:, None], drafts], axis=1).astype(np.int32)
         tables = self._decode_tables()
         with _DeviceCall(self, "generation.decode_dispatch",
-                         args=self._step_shares(k + 1)):
+                         args=self._step_shares(k + 1),
+                         hist=self._m_dispatch):
             with _TRACE_LOCK:
                 out = self._verify_fn(
                     self._params, *self.cache.arrays(), self._lengths,
@@ -2097,6 +2163,7 @@ class GenerationEngine:
                    else None)
         self.cache.update(*out[:self._nc])
         self._decode_steps += 1
+        self._m_decode_steps.inc()
         self._m_itl.observe((time.perf_counter() - t0) * 1e3)
         with _trace.span("generation.emit", cat="generation"):
             for slot in np.nonzero(self._active)[0]:
@@ -2210,6 +2277,7 @@ class GenerationEngine:
             return self
         self._t0 = time.perf_counter()
         self._stop = False
+        _COLLECTIONS.acquire(self.metrics_registry)
         self._thread = threading.Thread(
             target=self._loop, name="genloop-%s" % self._engine,
             daemon=True)
@@ -2218,6 +2286,7 @@ class GenerationEngine:
 
     def _loop(self):
         while True:
+            _COLLECTIONS.drain()
             with self._lock:
                 if self._stop or self._dead:
                     return
@@ -2247,6 +2316,7 @@ class GenerationEngine:
         if self._thread is not None:
             self._thread.join(timeout=10)
             self._thread = None
+            _COLLECTIONS.release(self.metrics_registry)
 
     # -- disaggregated prefill/decode (paddle_tpu.tp_serving.disagg) ------
     def prefill_extract(self, request, trace=None):

@@ -1,5 +1,6 @@
 """System gauges: device memory, live arrays, host RSS — sampled on a
-background thread into the shared registry.
+background thread into the shared registry; and the interpreter's
+collections, timed by a `gc.callbacks` hook (`CollectionPauses`).
 
 Capability parity: the reference used StatRegistry counters for GPU
 memory high-water marks (`platform/monitor.h`, STAT_ADD in the CUDA
@@ -14,12 +15,19 @@ raises.
 
 from __future__ import annotations
 
+import gc
 import os
 import threading
+import time
+from collections import deque
 
+from jax.profiler import TraceAnnotation as _Annotation
+
+from . import locks
+from . import trace as _trace
 from .metrics import default_registry
 
-__all__ = ["SystemMetricsSampler"]
+__all__ = ["SystemMetricsSampler", "CollectionPauses"]
 
 
 class SystemMetricsSampler:
@@ -126,6 +134,96 @@ class SystemMetricsSampler:
 
     def __exit__(self, *exc):
         self.stop()
+
+
+class CollectionPauses:
+    """The interpreter's collections, each a span ``span_name`` (args
+    ``generation`` and ``collected``) and an observation of the
+    histogram ``family`` (ms) in the registry of every holder: one
+    `gc.callbacks` hook a process, installed while anyone holds it
+    (`acquire` and `release` count the holders by registry).
+
+    The hook runs at an allocation on any thread, which may hold any
+    lock at that moment (a tracer's, a histogram's), so it takes none:
+    it opens only the profiler's own annotation, which puts the
+    collection on the device trace's clock as it happens, and keeps the
+    finished collection in a bounded queue.  `drain` (a holder's loop
+    calls it) turns the queue into ring spans and observations.  A
+    collection holds the GIL, so it stalls every thread of the process;
+    its span lies on the thread that collected."""
+
+    def __init__(self, span_name, family, help="", cat=""):
+        self.span_name = span_name
+        self.family = family
+        self.help = help
+        self.cat = cat
+        self._lock = locks.named_lock("observability.gc_pauses")
+        self._holders = {}      # id(registry) -> [registry, hist, holds]
+        self._hists = ()        # what `drain` observes into: swapped whole
+        self._open = None       # the collection under way: one at a time
+        self._done = deque(maxlen=1024)
+
+    def _swap_hists(self):
+        self._hists = tuple(hist for _, hist, _ in self._holders.values())
+
+    def acquire(self, registry=None):
+        registry = registry or default_registry()
+        with self._lock:
+            if not self._holders:
+                self._open = None
+                gc.callbacks.append(self._callback)
+            held = self._holders.get(id(registry))
+            if held is None:
+                held = self._holders[id(registry)] = [
+                    registry, registry.histogram(self.family, self.help), 0]
+                self._swap_hists()
+            held[2] += 1
+
+    def release(self, registry=None):
+        registry = registry or default_registry()
+        self.drain()
+        with self._lock:
+            held = self._holders[id(registry)]
+            held[2] -= 1
+            if not held[2]:
+                del self._holders[id(registry)]
+                self._swap_hists()
+                if not self._holders:
+                    gc.callbacks.remove(self._callback)
+
+    def _callback(self, phase, info):
+        if phase == "start":
+            ann = None
+            if _Annotation.is_enabled():
+                ann = _Annotation(self.span_name,
+                                  generation=info["generation"])
+                ann.__enter__()
+            self._open = (time.perf_counter(), info["generation"], ann)
+        elif self._open is not None:
+            t0, generation, ann = self._open
+            self._open = None
+            t1 = time.perf_counter()
+            if ann is not None:
+                ann.set_metadata(collected=info["collected"])
+                ann.__exit__(None, None, None)
+            self._done.append((t0, t1, generation, info["collected"],
+                               threading.get_ident()))
+
+    def drain(self):
+        """Report the collections finished since the last call: a ring
+        span each (on the thread that collected) and an observation in
+        every holder's histogram."""
+        while self._done:
+            try:
+                t0, t1, generation, collected, tid = self._done.popleft()
+            except IndexError:      # another holder took the last one
+                return
+            for hist in self._hists:
+                hist.observe((t1 - t0) * 1e3)
+            _trace.default_tracer().complete(
+                self.span_name, t0, t1, cat=self.cat,
+                args={"generation": generation, "collected": collected},
+                tid=tid)
 
 
 def _host_rss_bytes():

@@ -53,8 +53,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import dispatch
-
 NEG_INF = -1e30
 
 __all__ = [
@@ -578,14 +576,6 @@ def cached_attention(q, k_new, v_new, cache, scale=None, granule=1):
     arrays = kv_write(arrays, i0.ravel(), i1.ravel(),
                       k_new.reshape(b * c, g, d),
                       v_new.reshape(b * c, g, d))
-    if c == 1:
-        # nothing is chosen here: the count is what
-        # `decode_attn_kernel_share.serve` reads (0% with it, null
-        # without), and goes with that metric (ROADMAP D10)
-        dispatch.record(
-            "decode_attention", "walk",
-            "no decode kernel exists: the live part of the merged cache "
-            "is walked as it lies")
     if c * h > _BLOCK_DIAGONAL_ROWS:
         k_view, v_view = arrays[:2]
         if not dense:

@@ -9,6 +9,7 @@ the chip.
 
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -38,7 +39,7 @@ def test_one_chip_phases_pass_at_tiny_size(capsys):
     assert all("answered=5" in ln
                and "greedy_equal_to_plain_forward=3" in ln for ln in serve)
     # the dispatch choice is printed for every step function's trace
-    assert "decode_attention -> walk" in out
+    assert re.search(r"\battention -> (pallas|naive) \(", out)
 
 
 def test_four_chip_phases_pass_on_virtual_devices(capsys):

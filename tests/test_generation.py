@@ -1604,3 +1604,229 @@ def test_a_greedy_stream_does_not_depend_on_its_neighbours_policy(lm):
         assert out[0] == alone
         assert eng.stats()["sampling_step_share"] == float(
             neighbours[0] is not None)
+
+
+# ---------------------------------------------------------------------------
+# the dispatch split into its parts, and the instruments that time them
+# ---------------------------------------------------------------------------
+
+DISPATCH_PARTS = ["generation.dispatch_call", "generation.dispatch_operands"]
+
+
+@pytest.fixture
+def ring():
+    from paddle_tpu.observability import trace as T
+
+    tr = T.enable_tracing()
+    tr.clear()
+    yield tr
+    T.disable_tracing()
+    tr.clear()
+
+
+def recording_fresh(monkeypatch, eng, method, fresh):
+    """Wrap ``eng.<method>`` (a dispatch) to note, before each call,
+    whether the host had changed rows since the dispatch before."""
+    seen, real = [], getattr(eng, method)
+
+    def dispatch(*a, **kw):
+        seen.append(bool(fresh().any()))
+        return real(*a, **kw)
+
+    monkeypatch.setattr(eng, method, dispatch)
+    return seen
+
+
+def dispatch_children(events):
+    """For each `generation.decode_dispatch` span of the ring, in order,
+    the names of the `generation.dispatch_*` spans nested in it on its
+    thread."""
+    xs = [e for e in events if e.get("ph") == "X"]
+    out = []
+    for d in sorted((e for e in xs
+                     if e["name"] == "generation.decode_dispatch"),
+                    key=lambda e: e["ts"]):
+        out.append(sorted(
+            e["name"] for e in xs
+            if e["name"].startswith("generation.dispatch_")
+            and e["tid"] == d["tid"] and d["ts"] <= e["ts"]
+            and e["ts"] + e["dur"] <= d["ts"] + d["dur"]))
+    return out
+
+
+def assert_dispatch_split(eng, reg, events, fresh):
+    """Every dispatch holds its operands and its call, and a merge
+    exactly where the host had changed rows; the two dispatch
+    histograms count one observation a dispatch, and the step counter
+    is `decode_steps`."""
+    children = dispatch_children(events)
+    assert len(children) == len(fresh) == eng.stats()["decode_steps"]
+    assert children == [sorted(DISPATCH_PARTS + ["generation.dispatch_merge"]
+                               if f else DISPATCH_PARTS) for f in fresh]
+    assert any(fresh) and not all(fresh)
+    for family in ("generation_dispatch_ms", "generation_dispatch_call_ms"):
+        assert reg.get(family).labels(eng._engine).summary()["count"] == \
+            len(fresh)
+    steps = reg.get("generation_decode_steps_total").labels(eng._engine)
+    assert steps.value == eng.stats()["decode_steps"]
+
+
+def test_every_dispatch_holds_its_parts_and_a_merge_where_rows_changed(
+        lm, ring, monkeypatch):
+    from paddle_tpu.observability.metrics import MetricsRegistry
+
+    reg = MetricsRegistry()
+    eng = make_engine(lm, metrics_registry=reg)
+    fresh = recording_fresh(monkeypatch, eng, "_dispatch_decode",
+                            lambda: eng._fresh)
+    handles = [eng.submit(r) for r in mixed_requests(7)]
+    eng.run_until_idle()
+    assert all(h.done for h in handles)
+    assert_dispatch_split(eng, reg, ring.events(), fresh)
+
+
+class _TickClock:
+    """``time`` whose `perf_counter` advances one tick a read, so that a
+    duration is the number of reads it spans."""
+
+    def __init__(self):
+        self.n = 0
+
+    def perf_counter(self):
+        self.n += 1
+        return float(self.n)
+
+    def __getattr__(self, name):
+        import time
+
+        return getattr(time, name)
+
+
+def _sched_host_in_ticks(lm, monkeypatch, parts):
+    from paddle_tpu.generation import engine as engine_mod
+    from paddle_tpu.observability.metrics import MetricsRegistry
+
+    monkeypatch.setattr(engine_mod, "time", _TickClock())
+    reg = MetricsRegistry()
+    eng = make_engine(lm, metrics_registry=reg)
+    if not parts:       # the call as it was before the dispatch's parts
+        eng._call_decode_step = lambda: eng._decode_step_fn(
+            *eng._decode_operands())
+    try:
+        for r in mixed_requests(5):
+            eng.submit(r)
+        eng.run_until_idle()
+    finally:
+        monkeypatch.undo()
+    s = reg.get("generation_sched_host_ms").labels(eng._engine).summary()
+    return s["count"], s["sum"]
+
+
+def test_the_dispatch_parts_leave_sched_host_as_it_was(lm, monkeypatch):
+    """`generation_sched_host_ms` is a step less its device calls: the
+    dispatch's parts lie inside `generation.decode_dispatch` and add
+    nothing to its device share.  On a clock that ticks once a read, the
+    engine with the parts and the same engine calling its step as
+    before observe the same."""
+    with_parts = _sched_host_in_ticks(lm, monkeypatch, True)
+    without = _sched_host_in_ticks(lm, monkeypatch, False)
+    assert with_parts == without
+    assert with_parts[0] > 5 and with_parts[1] > 0
+
+
+def test_a_collection_is_one_span_and_one_observation(lm, ring):
+    """A running engine installs one `gc.callbacks` hook for the process:
+    a forced full collection is one `generation.gc` span (generation 2,
+    with what it collected, on the thread that collected) and one
+    `generation_gc_pause_ms` observation in the engine's own registry,
+    and the hook is gone once the engine stops."""
+    import gc
+    import threading
+
+    from paddle_tpu.generation import engine as engine_mod
+    from paddle_tpu.observability.metrics import MetricsRegistry
+
+    hooks = len(gc.callbacks)
+    reg = MetricsRegistry()
+    eng = make_engine(lm, metrics_registry=reg).start()
+    gc.disable()        # no other collection until the count is read
+    try:
+        engine_mod._COLLECTIONS.drain()
+        hist = reg.get("generation_gc_pause_ms")
+        before = hist.summary()["count"]
+        ring.clear()
+        gc.collect()
+        eng.stop()      # reports what the loop has not yet
+        spans = [e for e in ring.events()
+                 if e.get("name") == "generation.gc"]
+        after = hist.summary()
+    finally:
+        gc.enable()
+        eng.stop()
+    assert len(spans) == 1
+    assert spans[0]["args"]["generation"] == 2
+    assert spans[0]["args"]["collected"] >= 0
+    assert spans[0]["tid"] == threading.get_ident()
+    assert after["count"] == before + 1
+    assert len(gc.callbacks) == hooks
+
+
+class _CollectingList(list):
+    """A list whose copy runs a full collection first: a collection at
+    an allocation made under the lock that guards the list."""
+
+    def __iter__(self):
+        import gc
+
+        gc.collect()
+        return super().__iter__()
+
+
+def test_a_collection_under_the_tracers_lock_does_not_block_its_thread(
+        lm, ring):
+    """`Tracer.events()` copies its thread names under the tracer's own
+    lock; a collection there, on a thread the tracer has not seen (an
+    HTTP handler serving `/trace`), runs the hook on that thread.  The
+    hook takes no lock, so the thread goes on, and its collection is
+    reported all the same."""
+    import threading
+
+    names = ring._meta_events
+    eng = make_engine(lm).start()
+    try:
+        ring._meta_events = _CollectingList(names)
+        done = []
+        reader = threading.Thread(
+            target=lambda: done.append(len(ring.events())), daemon=True)
+        reader.start()
+        reader.join(timeout=30)
+        assert not reader.is_alive() and done
+        h = eng.submit(gen.GenerationRequest([1, 2, 3], max_new_tokens=2))
+        assert len(h.result(timeout=60)) == 2
+    finally:
+        collecting, ring._meta_events = ring._meta_events, names
+        names.extend(collecting[len(names):])   # threads named meanwhile
+        eng.stop()
+    assert any(e.get("name") == "generation.gc" and e["tid"] == reader.ident
+               for e in ring.events())
+
+
+def test_the_collection_hook_is_shared_and_counted(lm):
+    """Two engines running share the one hook; it stays until the last
+    one stops, and an engine stopped twice releases it once."""
+    import gc
+
+    from paddle_tpu.generation import engine as engine_mod
+
+    hook = engine_mod._COLLECTIONS._callback
+    hooks = len(gc.callbacks)
+    a, b = make_engine(lm).start(), make_engine(lm).start()
+    try:
+        assert gc.callbacks.count(hook) == 1
+        a.stop()
+        a.stop()
+        assert gc.callbacks.count(hook) == 1
+    finally:
+        a.stop()
+        b.stop()
+    assert len(gc.callbacks) == hooks

@@ -563,3 +563,34 @@ def test_a_stop_with_a_pass_in_flight_costs_one_row_and_frees_clean(lm):
     assert eng.stats()["decode_rows_discarded"] == 1
     assert eng.cache.pool.used_blocks == 0
     _assert_solo(lm, handles)
+
+
+def test_every_pass_dispatch_holds_its_parts_and_a_merge_where_rows_changed(
+        lm, monkeypatch):
+    """The block loop's `generation.decode_dispatch` splits as the plain
+    one's does: operands and call in every pass's dispatch, the
+    `generation_block_merge` call's span exactly where the host changed
+    rows (a block opened, a slot freed), the dispatch histograms one
+    observation a pass and `generation_decode_steps_total` the passes."""
+    from test_generation import assert_dispatch_split, recording_fresh
+
+    from paddle_tpu.observability import trace as T
+    from paddle_tpu.observability.metrics import MetricsRegistry
+
+    reg = MetricsRegistry()
+    eng = GenerationEngine(lm, slots=2, max_len=64, denoising_steps=4,
+                           metrics_registry=reg)
+    fresh = recording_fresh(monkeypatch, eng, "_dispatch_block",
+                            lambda: eng._blk_fresh)
+    tr = T.enable_tracing()
+    tr.clear()
+    try:
+        handles = [eng.submit(GenerationRequest(
+            prompt_of(n_p), max_new_tokens=n_o)) for n_p, n_o in REQUESTS]
+        eng.run_until_idle()
+        events = tr.events()
+    finally:
+        T.disable_tracing()
+        tr.clear()
+    assert [len(h.result()) for h in handles] == [n for _, n in REQUESTS]
+    assert_dispatch_split(eng, reg, events, fresh)

@@ -7,6 +7,7 @@ with one ``trace_id`` per request across the handler and loop threads, and
 every new histogram observed as often as the script says.  Nothing here
 asserts a time."""
 
+import gc
 import glob
 import http.client
 import json
@@ -126,6 +127,7 @@ def scripted(tmp_path_factory):
                     assert status == 200 and records[-1].get("done")
                     served.append(sum("token" in r for r in records))
                 time.sleep(0.12)                # the loop idles: idle_wait
+                gc.collect()                    # a collection: generation.gc
                 chunked.generate([list(range(1, 10))], max_new_tokens=2)
                 feed = iter(io.DevicePrefetcher(iter(batches * 2), depth=2))
                 for _ in range(TRAIN_STEPS):
@@ -154,6 +156,8 @@ SPAN_NAMES = [
     "http.generate", "http.admit", "generation.step", "generation.lock_wait",
     "generation.admit", "generation.prefill", "generation.prefill_chunk",
     "generation.grow", "generation.decode_dispatch",
+    "generation.dispatch_merge", "generation.dispatch_operands",
+    "generation.dispatch_call", "generation.gc",
     "generation.decode_fetch", "generation.emit", "generation.idle_wait",
     "train.step_dispatch", "train.batch_put", "io.next_batch"]
 
@@ -173,6 +177,9 @@ NESTING = [
     ("generation.prefill_chunk", "generation.step"),
     ("generation.grow", "generation.step"),
     ("generation.decode_dispatch", "generation.step"),
+    ("generation.dispatch_merge", "generation.decode_dispatch"),
+    ("generation.dispatch_operands", "generation.decode_dispatch"),
+    ("generation.dispatch_call", "generation.decode_dispatch"),
     ("generation.decode_fetch", "generation.step"),
     ("generation.emit", "generation.step"),
     ("train.batch_put", "train.step_dispatch"),
@@ -228,6 +235,8 @@ HISTOGRAMS = [      # family, registry, observations the script makes
     ("generation_sampling_step_share", "served",
      lambda r: r["decode_steps"]),
     ("generation_prefill_ms", "served", lambda r: REQUESTS),
+    ("generation_dispatch_ms", "served", lambda r: r["decode_steps"]),
+    ("generation_dispatch_call_ms", "served", lambda r: r["decode_steps"]),
     ("train_step_dispatch_ms", "train", lambda r: TRAIN_STEPS),
     ("io_step_wait_ms", "train", lambda r: TRAIN_STEPS),
 ]

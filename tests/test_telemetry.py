@@ -509,6 +509,43 @@ def test_system_metrics_sampler_cpu_graceful():
     assert "host_rss_bytes" in reg.prometheus_text()
 
 
+def test_collection_pauses_report_to_every_holder_and_leave_no_hook():
+    """One hook however many hold it; each collection is one observation
+    in every holder's registry and one ring span on the thread that
+    collected, once drained; the hook goes with the last holder."""
+    import gc
+    import threading
+
+    from paddle_tpu.observability.system import CollectionPauses
+
+    pauses = CollectionPauses("test.gc", "test_gc_pause_ms", cat="test")
+    regs = [MetricsRegistry(), MetricsRegistry()]
+    hooks = len(gc.callbacks)
+    tr = obs.enable_tracing()
+    tr.clear()
+    gc.disable()
+    try:
+        for reg in regs + regs[:1]:
+            pauses.acquire(reg)
+        assert len(gc.callbacks) == hooks + 1
+        gc.collect()
+        pauses.drain()
+        spans = [e for e in tr.events() if e.get("name") == "test.gc"]
+        for reg in regs:
+            assert reg.get("test_gc_pause_ms").summary()["count"] == 1
+        pauses.release(regs[0])
+        pauses.release(regs[1])
+        assert len(gc.callbacks) == hooks + 1   # regs[0] still holds it
+        pauses.release(regs[0])
+    finally:
+        gc.enable()
+        obs.disable_tracing()
+        tr.clear()
+    assert len(gc.callbacks) == hooks
+    assert [(s["args"]["generation"], s["tid"], s["cat"]) for s in spans] \
+        == [(2, threading.get_ident(), "test")]
+
+
 def test_checkpoint_save_durations_wired(tmp_path):
     from paddle_tpu.fluid import layers
     from paddle_tpu.incubate.checkpoint import train_epoch_range

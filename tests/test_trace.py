@@ -601,7 +601,8 @@ def test_disabled_tracing_is_shared_noop_and_within_budget(monkeypatch):
             per_step[paged] = [name for name, _ in opened]
         assert per_step[True] == [
             "generation.step", "generation.lock_wait", "generation.grow",
-            "generation.decode_dispatch", "generation.decode_fetch",
+            "generation.decode_dispatch", "generation.dispatch_operands",
+            "generation.dispatch_call", "generation.decode_fetch",
             "generation.emit"]
         assert per_step[False] == [n for n in per_step[True]
                                    if n != "generation.grow"]
