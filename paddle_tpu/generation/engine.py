@@ -614,22 +614,16 @@ class GenerationEngine:
             raise ValueError("draft_model needs draft_len >= 1")
         if self.draft_model is not None and not self.paged:
             raise ValueError("speculative decoding requires paged=True")
-        # host mirrors of per-slot state.  Device state is the cache and
-        # the plain decode step's token operand: step t+1 reads step t's
-        # sampled tokens where they are, so ``_last_tokens`` is a device
-        # array from the start (one jit signature).  ``_tok_host`` holds
-        # what the host knows of it, and ``_fresh`` marks the slots whose
-        # token the host set since the last dispatch (`_set_token`: an
-        # admission's first token, a verify step's last), which
-        # `_dispatch_decode` writes over the device's copy
+        # host mirrors of per-slot state.  ``_tok_host`` holds what the
+        # host knows of the plain step's token operand, and ``_fresh``
+        # marks the slots whose token (a block step's tokens and mask)
+        # the host set since the last dispatch (`_set_token`: an
+        # admission's first token, a verify step's last; `_open_block`,
+        # `_free_slot`)
         self._lengths = np.zeros(n, np.int32)
         self._steps = np.zeros(n, np.int32)
-        self._last_tokens = jnp.zeros(n, jnp.int32)
         self._tok_host = np.zeros(n, np.int32)
         self._fresh = np.zeros(n, bool)
-        self._merge_tokens = jax.jit(_named(
-            lambda tokens, fresh, mine: jnp.where(fresh, mine, tokens),
-            "generation_token_merge"))
         # the plain decode step dispatched and not yet fetched:
         # ``(its outputs after the cache's, [(slot, _Slot), ...])``
         self._in_flight = None
@@ -640,6 +634,7 @@ class GenerationEngine:
         self._top_k = np.zeros(n, np.int32)
         self._top_p = np.ones(n, np.float32)
         self._active = np.zeros(n, bool)
+        self._init_device_state()
         self._slot_state = [None] * n          # _Slot | None
         self._chunking = [None] * n            # _ChunkState | None
         self._free = list(range(n))
@@ -670,13 +665,16 @@ class GenerationEngine:
         self._donate_kv = donate_kv
         # a block-diffusion engine's step is a program of its own, and
         # its prompts take the chunk path alone; every other engine
-        # builds the programs it always has
-        make_step, step_name = (
-            (self._make_block_step_fn, "generation_block_step")
+        # builds the programs it always has.  The plain step hands back
+        # its lengths and steps advanced (`_advance_step`); a pass's
+        # advance is a commit, which the host decides
+        step, step_name = (
+            (self._make_block_step_fn(), "generation_block_step")
             if self.block_length else
-            (self._make_decode_fn, "generation_decode"))
+            (self._advance_step(self._make_decode_fn()),
+             "generation_decode"))
         self._decode_step_fn = jax.jit(
-            _named(make_step(), step_name), donate_argnums=donate_kv)
+            _named(step, step_name), donate_argnums=donate_kv)
         self._prefill_fns = {} if self.block_length else {
             b: jax.jit(_named(self._make_prefill_fn(b),
                               "generation_prefill_%d" % b),
@@ -764,13 +762,13 @@ class GenerationEngine:
             labelnames=lbl).labels(self._engine)
         self._m_dispatch = reg.histogram(
             "generation_dispatch_ms",
-            "One decode dispatch: token merge, operands, the step's call "
+            "One decode dispatch: state merge, operands, the step's call "
             "and the bookkeeping under the span (ms)",
             labelnames=lbl).labels(self._engine)
         self._m_dispatch_call = reg.histogram(
             "generation_dispatch_call_ms",
             "The decode step's jitted call inside a dispatch, until it "
-            "returns: arguments flattened, host operands put, enqueued (ms)",
+            "returns: arguments flattened and enqueued (ms)",
             labelnames=lbl).labels(self._engine)
         self._m_lock_wait = reg.histogram(
             "generation_lock_wait_ms",
@@ -779,6 +777,12 @@ class GenerationEngine:
         self._m_decode_steps = reg.counter(
             "generation_decode_steps_total",
             "Decode steps dispatched (plain, block or verify)",
+            labelnames=lbl).labels(self._engine)
+        self._m_merges = reg.counter(
+            "generation_state_merges_total",
+            "Calls of generation_state_merge: plain or block dispatches "
+            "that wrote rows the host changed over the device's per-slot "
+            "state (over decode steps: the merge share)",
             labelnames=lbl).labels(self._engine)
         self._device_s = 0.0           # this step's time in device calls
         self._m_occupancy = reg.gauge(
@@ -965,6 +969,21 @@ class GenerationEngine:
 
         return self._wrap_step(decode)
 
+    def _advance_step(self, step):
+        """The plain decode step as the dispatch calls it: ``step``'s
+        outputs, then its lengths and steps operands advanced by one in
+        every live row, the rows it writes (a dense cache's active mask,
+        or a table row that is not all zeros, as the walk reads it), so
+        that step t+1 reads them where step t left them."""
+        nc = self._nc
+
+        def advance(params, *args):
+            lengths, steps, where = args[nc], args[nc + 3], args[-1]
+            live = where if where.ndim == 1 else jnp.any(where != 0, axis=1)
+            return (*step(params, *args), lengths + live, steps + live)
+
+        return advance
+
     def _prefill_rows(self, bucket, where):
         """Cache indices ``(i0, i1)`` of a prompt's ``bucket`` rows (the
         arguments of `kv_write`).  ``where`` is the slot's block-table
@@ -1099,10 +1118,10 @@ class GenerationEngine:
     # -- block diffusion ---------------------------------------------------
     def _init_block_diffusion(self, denoising_steps, remasking, threshold,
                               other_paths):
-        """Check and keep the block-diffusion knobs, and make the
-        per-slot state of the block being generated: the device's
-        tokens and mask, chained from pass to pass, and the host's
-        mirrors of them as of the last pass delivered."""
+        """Check and keep the block-diffusion knobs, and make the host's
+        mirrors of the block being generated in each slot, as of the
+        last pass delivered (the device's tokens and mask are chained
+        from pass to pass: `_init_device_state`)."""
         b, n = self.block_length, self.slots
         steps = int(denoising_steps or b)
         if steps < 1 or b % steps:
@@ -1133,18 +1152,6 @@ class GenerationEngine:
         self._blk_pass = np.zeros(n, np.int32)      # denoise passes done
         self._blk_reveal_pass = np.zeros((n, b), np.int32)
         self._blk_logprobs = np.zeros((n, b), np.float32)
-        # the pass's tokens and mask operands as the device holds them
-        # (a pass's outputs are the next one's), and the slots whose
-        # host rows (tokens, mask and what lies beyond the request) the
-        # next dispatch writes over them: a block opened, a slot freed
-        self._blk_state = (jnp.full((n, b), self._mask_id, jnp.int32),
-                           jnp.zeros((n, b), bool))
-        self._blk_fresh = np.zeros(n, bool)
-        self._merge_block = jax.jit(_named(
-            lambda tokens, revealed, fresh, mine, mine_revealed: (
-                jnp.where(fresh[:, None], mine, tokens),
-                jnp.where(fresh[:, None], mine_revealed, revealed)),
-            "generation_block_merge"))
 
     def _make_block_step_fn(self):
         """ONE pass over every slot's block (see module docstring):
@@ -1315,14 +1322,16 @@ class GenerationEngine:
         self._blk_beyond[slot] = (
             self._lengths[slot] + np.arange(self.block_length)
             >= len(request.prompt_ids) + request.max_new_tokens)
-        self._blk_fresh[slot] = True
+        self._fresh[slot] = True
 
     def _dispatch_block(self, overlapped):
         """Launch one `generation_block_step` over the active slots on
-        the device's own copy of the pass before's tokens and mask, the
-        host's fresh rows (`_blk_fresh`) written over it first.  What
-        the host knows of the pass it learns after the pass before is
-        delivered (`_foresee_block`)."""
+        the device's own copy of the pass before's tokens and mask and
+        of the per-slot operands, the rows the host changed written over
+        them first (`_merge_state`: a block opened, a commit's lengths
+        and steps, a slot parked or freed).  What the host knows of the
+        pass it learns after the pass before is delivered
+        (`_foresee_block`)."""
         b = self.block_length
         rows = np.nonzero(self._active)[0]
         args = self._step_shares(b)
@@ -1331,17 +1340,11 @@ class GenerationEngine:
         with _DeviceCall(self, "generation.decode_dispatch", args=args,
                          hist=self._m_dispatch):
             with _TRACE_LOCK:
-                if self._blk_fresh.any():
-                    with _trace.span("generation.dispatch_merge",
-                                     cat="generation"):
-                        self._blk_state = self._merge_block(
-                            *self._blk_state, self._blk_fresh.copy(),
-                            self._blk_tokens.copy(),
-                            self._blk_revealed | self._blk_beyond)
-                    self._blk_fresh[:] = False
+                self._merge_state()
                 out = self._call_decode_step()
         self.cache.update(*out[:self._nc])
-        self._blk_state = tuple(out[self._nc:self._nc + 2])
+        lengths, _, _, *rest = self._dev
+        self._dev = (lengths, *out[self._nc:self._nc + 2], *rest)
         self._count_dispatch(overlapped, t0)
         self._m_block_rows.inc(int(self._lengths[rows].sum()) + b * len(rows))
         # which rows commit is known once the pass before is delivered
@@ -1534,7 +1537,7 @@ class GenerationEngine:
             self._blk_tokens[slot] = self._mask_id
             self._blk_revealed[slot] = False
             self._blk_beyond[slot] = False
-            self._blk_fresh[slot] = True
+            self._fresh[slot] = True
         self._free.append(slot)
 
     def _park(self, slot):
@@ -2012,16 +2015,19 @@ class GenerationEngine:
     def _set_token(self, slot, token):
         """The host decides ``slot``'s next input token (its prefill's
         sample, a verify step's last): the next plain dispatch writes
-        it over the device's copy."""
+        it over the device's copy (`_merge_state`)."""
         self._tok_host[slot] = token
         self._fresh[slot] = True
 
     def _dispatch_decode(self, overlapped):
-        """Launch one plain decode step over the active slots and count
-        it as done on the host: lengths, steps and the cache advance
-        here, not at delivery, so the next step can be built before
-        this one's tokens are known.  A slot whose row in flight is its
-        last by length is not in the next step."""
+        """Launch one plain decode step over the active slots on the
+        device's own per-slot operands, the rows the host changed
+        written over them first (`_merge_state`), and count it as done
+        on the host: lengths, steps and the cache advance here, not at
+        delivery, so the next step can be built before this one's
+        tokens are known.  The step hands back its tokens, lengths and
+        steps, which the next one reads where they are.  A slot whose
+        row in flight is its last by length is not in the next step."""
         rows = np.nonzero(self._active)[0]
         args = self._step_shares(1)
         args["overlapped"] = int(overlapped)
@@ -2029,22 +2035,22 @@ class GenerationEngine:
         with _DeviceCall(self, "generation.decode_dispatch", args=args,
                          hist=self._m_dispatch):
             with _TRACE_LOCK:
-                if self._fresh.any():
-                    with _trace.span("generation.dispatch_merge",
-                                     cat="generation"):
-                        self._last_tokens = self._merge_tokens(
-                            self._last_tokens, self._fresh,
-                            self._tok_host.copy())
-                    self._fresh = np.zeros(self.slots, bool)
+                self._merge_state()
                 out = self._call_decode_step()
         self.cache.update(*out[:self._nc])
-        self._last_tokens = out[self._nc]
+        *outputs, lengths, steps = out[self._nc:]
+        _, _, keys, _, *rest = self._dev
+        self._dev = (lengths, outputs[0], keys, steps, *rest)
         self._count_dispatch(overlapped, t0)
         # the step writes every ACTIVE slot's new token at its length
         # (inactive rows compute garbage nobody reads: their writes go
-        # to the garbage block)
+        # to the garbage block); what the device was last given advances
+        # as the device advanced it, in the rows its operand said live
         self._lengths[rows] += 1
         self._steps[rows] += 1
+        live = self._sent[:, self._cols[-1]].any(axis=1)
+        for col in (self._cols[0], self._cols[3]):     # lengths, steps
+            self._sent[live, col] += 1
         flight = []
         for slot in rows:
             st = self._slot_state[slot]
@@ -2052,11 +2058,11 @@ class GenerationEngine:
             flight.append((slot, st))
             if self._length_reason(st, st.generated + st.in_flight):
                 self._park(slot)       # ends when this row is delivered
-        self._in_flight = (out[self._nc:], flight)
+        self._in_flight = (outputs, flight)
 
     def _call_decode_step(self):
         """The decode (or block) step's call inside a dispatch, its
-        operands built first: the two parts of the dispatch that are
+        operands gathered first: the two parts of the dispatch that are
         neither the merge nor the bookkeeping after the call."""
         with _trace.span("generation.dispatch_operands", cat="generation"):
             operands = self._decode_operands()
@@ -2555,17 +2561,110 @@ class GenerationEngine:
         return lowered.compile().as_text()
 
     def _decode_operands(self):
-        """The decode executable's live operands; the last one says who
-        is live: a paged engine's block tables, a dense one's mask.  The
-        tokens (and a block step's mask) are the device's own."""
-        tokens = (self._blk_state if self.block_length
-                  else (self._last_tokens,))
-        # the host's arrays are copies: the step may still be reading
-        # them when a slot's state is next written
-        return (self._params, *self.cache.arrays(), self._lengths.copy(),
-                *tokens, self._keys.copy(), self._steps.copy(),
-                self._temp.copy(), self._top_k.copy(), self._top_p.copy(),
-                self._decode_tables() if self.paged else self._active.copy())
+        """The decode executable's operands: the weights, the cache and
+        the per-slot operands as the device holds them (`_dev`)."""
+        return (self._params, *self.cache.arrays(), *self._dev)
+
+    # -- per-slot state on the device --------------------------------------
+    def _init_device_state(self):
+        """The step's per-slot operands on the device, in its order
+        (`_dev`), what the device was last given of them (`_sent`, packed
+        by `_pack`) and the program that writes the host's changed rows
+        over them (`_merge_state`).  The tokens (a block step's tokens
+        and mask) are chained: the host's copy lags the device's, so a
+        row of them is written only where the host set it (`_fresh`);
+        the host keeps every other field exact, so a row of them is
+        written wherever it differs from what was sent."""
+        host = self._host_operands()
+        widths = [a[0].size for a in host]
+        ends = np.cumsum(widths).tolist()
+        self._cols = [slice(e - w, e) for w, e in zip(widths, ends)]
+        chained = range(1, 3 if self.block_length else 2)
+        self._exact = np.ones(ends[-1], bool)
+        for i in chained:
+            self._exact[self._cols[i]] = False
+        # fresh host copies: a device array may share a host buffer
+        self._dev = tuple(jnp.asarray(np.array(a)) for a in host)
+        self._sent = self._pack(host)
+        self._merge_state_fn = jax.jit(_named(
+            self._make_merge_fn(chained), "generation_state_merge"))
+
+    def _host_operands(self):
+        """The step's per-slot operands as the host knows them, in the
+        step's order: lengths, the chained tokens (a block step's
+        tokens and mask, revealed or beyond the request), keys, steps,
+        temperature, top-k, top-p, and what says who is live: the block
+        tables with the rows of inactive slots zeroed (`_decode_tables`)
+        or a dense cache's active mask."""
+        chained = ((self._blk_tokens, self._blk_revealed | self._blk_beyond)
+                   if self.block_length else (self._tok_host,))
+        return (self._lengths, *chained, self._keys, self._steps,
+                self._temp, self._top_k, self._top_p,
+                self._decode_tables() if self.paged else self._active)
+
+    @staticmethod
+    def _pack(arrays):
+        """Per-slot arrays as one int32 array, a row a slot: each
+        array's row flattened, its bits kept (a bool as 0 or 1)."""
+        return np.concatenate([
+            a.reshape(len(a), -1).astype(np.int32) if a.dtype == bool
+            else a.reshape(len(a), -1).view(np.int32) for a in arrays],
+            axis=1)
+
+    def _make_merge_fn(self, chained):
+        cols = self._cols
+
+        def merge(state, packed):
+            """Rows ``packed[:, 0]`` of every per-slot operand, and rows
+            ``packed[:, 1]`` of the ``chained`` ones, from the fields
+            packed after them; an index past the last slot writes
+            nothing (the padding)."""
+            out = []
+            for i, (a, col) in enumerate(zip(state, cols)):
+                v = packed[:, 2 + col.start:2 + col.stop]
+                v = (v != 0 if a.dtype == jnp.bool_
+                     else jax.lax.bitcast_convert_type(v, a.dtype))
+                out.append(a.at[packed[:, 1 if i in chained else 0]].set(
+                    v.reshape((-1,) + a.shape[1:]), mode="drop"))
+            return tuple(out)
+
+        return merge
+
+    def _merge_state(self):
+        """Write the rows the host changed since the last dispatch over
+        the device's per-slot operands, in ONE `generation_state_merge`
+        call whose one host operand packs them: their indices and
+        fields, padded to ``slots`` rows so that it compiles once.  A
+        row changed where the host set its chained token (`_fresh`) or
+        where a field the host keeps exact differs from what the device
+        was last given: an admission, a grow, a park, a free, a commit.
+        A dispatch where no row changed puts nothing on the device."""
+        host, changed = self._changed_rows()
+        self._sent = host
+        if not changed.any():
+            return
+        with _trace.span("generation.dispatch_merge", cat="generation"):
+            rows = np.nonzero(changed)[0]
+            n = self.slots
+            packed = np.full((n, 2 + host.shape[1]), n, np.int32)
+            packed[:len(rows), 0] = rows
+            packed[:len(rows), 1] = np.where(self._fresh[rows], rows, n)
+            packed[:len(rows), 2:] = host[rows]
+            self._dev = self._merge_state_fn(self._dev, packed)
+        self._fresh[:] = False
+        self._m_merges.inc()
+
+    def _changed_rows(self):
+        """The host's per-slot operands, packed, and the rows of them
+        the next dispatch writes over the device's (`_merge_state`)."""
+        host = self._pack(self._host_operands())
+        return host, self._fresh | ((host != self._sent)
+                                    & self._exact).any(axis=1)
+
+    @property
+    def _last_tokens(self):
+        """The plain step's token operand as the device holds it."""
+        return self._dev[1]
 
     def _decode_cache_size(self):
         """Jit-cache entries of the decode step — the compile-once pin."""

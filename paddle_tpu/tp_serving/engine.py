@@ -90,11 +90,11 @@ class TPGenerationEngine(GenerationEngine):
         self.cache.update(*(
             jax.device_put(a, NamedSharding(self._mesh, s))
             for a, s in zip(self.cache.arrays(), self._cache_specs())))
-        # and the chained token operand to the form a step's sampled
-        # tokens come back in (replicated over the mesh), for the same
-        # reason
-        self._last_tokens = jax.device_put(
-            self._last_tokens, NamedSharding(self._mesh, P()))
+        # and the per-slot operands the device chains from step to step
+        # to the form a step's outputs come back in (replicated over the
+        # mesh), for the same reason
+        self._dev = tuple(jax.device_put(a, NamedSharding(self._mesh, P()))
+                          for a in self._dev)
 
     # -- sharding plumbing -------------------------------------------------
     def _cache_specs(self):

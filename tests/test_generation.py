@@ -1678,7 +1678,7 @@ def test_every_dispatch_holds_its_parts_and_a_merge_where_rows_changed(
     reg = MetricsRegistry()
     eng = make_engine(lm, metrics_registry=reg)
     fresh = recording_fresh(monkeypatch, eng, "_dispatch_decode",
-                            lambda: eng._fresh)
+                            lambda: eng._changed_rows()[1])
     handles = [eng.submit(r) for r in mixed_requests(7)]
     eng.run_until_idle()
     assert all(h.done for h in handles)
@@ -1830,3 +1830,236 @@ def test_the_collection_hook_is_shared_and_counted(lm):
         a.stop()
         b.stop()
     assert len(gc.callbacks) == hooks
+
+
+# ---------------------------------------------------------------------------
+# the step's per-slot state on the device, and the merge of the host's rows
+# ---------------------------------------------------------------------------
+
+def _scenario_requests():
+    """Seven requests over three slots: greedy ones and sampled ones
+    under different temperatures, top-k and top-p, two of them sharing
+    an eight-token prefix (a prefix-cache hit at a block of four)."""
+    rng = np.random.RandomState(7)
+    shared = [int(t) for t in rng.randint(0, 97, 8)]
+    policies = [None, _sampled(11), _sampled(12, temperature=1.1, top_k=5,
+                                             top_p=0.8),
+                None, _sampled(14, temperature=0.6, top_k=0, top_p=0.9),
+                _sampled(15, temperature=0.9, top_k=20, top_p=1.0), None]
+    lengths = [(5, 12), (13, 9), (3, 14), (shared, 7),
+               (shared + [3, 4, 5], 10), (9, 6), (2, 11)]
+    reqs = []
+    for i, ((prompt, new), sp) in enumerate(zip(lengths, policies)):
+        if not isinstance(prompt, list):
+            prompt = [int(t) for t in rng.randint(0, 97, prompt)]
+        reqs.append(gen.GenerationRequest(prompt, max_new_tokens=new,
+                                          sampling=sp, request_id="s%d" % i))
+    return reqs
+
+
+# request i is submitted at scheduler iteration ARRIVALS[i]; at iteration
+# 7 the slot of request 1 is preempted (it is admitted again later)
+ARRIVALS = [0, 0, 2, 3, 9, 12, 14]
+PREEMPT = (7, 1)
+
+
+def _serve_scenario(eng, reqs):
+    handles, it = {}, 0
+    while True:
+        for i, at in enumerate(ARRIVALS):
+            if at == it:
+                handles[i] = eng.submit(reqs[i])
+        if it == PREEMPT[0]:
+            slot = next(s for s, st in enumerate(eng._slot_state)
+                        if st is not None and st.request is reqs[PREEMPT[1]])
+            with eng._lock:
+                eng._preempt_slot(slot, "test")
+        busy = eng.step()
+        it += 1
+        if not busy and len(handles) == len(reqs):
+            return [handles[i] for i in range(len(reqs))]
+
+
+PARENT_STREAMS = {
+    "autoregressive": (
+        [   # tokens
+            [42, 42, 10, 10, 10, 10, 10, 10, 10, 10, 10, 10],
+            [120, 89, 85, 43, 7, 16, 6, 28, 112],
+            [49, 68, 44, 64, 10, 10, 104, 67, 14, 35, 69, 69, 110, 67],
+            [57, 57, 57, 57, 57, 57, 57],
+            [44, 4, 93, 75, 123, 26, 62, 62, 26, 125],
+            [86, 86, 80, 10, 12, 12],
+            [38, 38, 38, 38, 38, 10, 10, 10, 10, 10, 10],
+        ], [   # log-probabilities
+            [-4.5276718, -4.4971628, -4.4944148, -4.3783927, -4.4178009,
+             -4.5074482, -4.3610692, -4.4249015, -4.3846679, -4.3638353,
+             -4.5532761, -4.4478283],
+            [-4.7343082, -4.8111601, -4.7839551, -4.8062482, -4.7032127,
+             -4.6577692, -4.7562304, -4.7583184, -4.5633693],
+            [-4.6416502, -4.6102209, -4.5268469, -4.5646496, -4.6007233,
+             -4.3853588, -4.5826159, -4.6276913, -4.5807705, -4.6054130,
+             -4.6624274, -4.4650664, -4.6557264, -4.6334324],
+            [-4.3225608, -4.2726202, -4.3933945, -4.3364573, -4.4375873,
+             -4.4028692, -4.4032292],
+            [-4.7372479, -4.8661561, -4.9344234, -4.7807388, -4.9347587,
+             -4.7228637, -4.6326270, -4.4710913, -4.8167915, -4.7980475],
+            [-4.6623163, -4.4313688, -4.6170034, -4.7042589, -4.5299759,
+             -4.3409967],
+            [-4.3986249, -4.3981905, -4.4232688, -4.4583941, -4.4956684,
+             -4.5640116, -4.4076843, -4.4592032, -4.5384283, -4.3965750,
+             -4.4483256],
+        ]),
+    "block": (
+        [   # tokens
+            [17, 17, 24, 17, 17, 111, 24, 24, 24, 24, 111, 24],
+            [34, 89, 22, 42, 10, 34, 29, 114, 101],
+            [24, 90, 97, 24, 42, 17, 17, 24, 17, 42, 24, 42, 111, 17],
+            [17, 17, 17, 17, 17, 17, 17],
+            [87, 4, 93, 75, 123, 112, 31, 62, 26, 125],
+            [86, 86, 80, 10, 90, 82],
+            [99, 99, 97, 97, 56, 56, 5, 5, 34, 34, 56],
+        ], [   # log-probabilities
+            [-4.4705906, -4.5009909, -4.3736844, -4.4239860, -4.4478192,
+             -4.4019060, -4.3864088, -4.4369192, -4.4263997, -4.4150949,
+             -4.4156699, -4.4258318],
+            [-4.5245271, -4.7547975, -4.7453680, -4.6775632, -4.6831474,
+             -4.4694047, -4.7694430, -4.7240887, -4.7052760],
+            [-4.5093379, -4.4585547, -4.4971766, -4.5014830, -4.4253063,
+             -4.4959230, -4.5202208, -4.4988289, -4.4585114, -4.5341663,
+             -4.5050211, -4.5418019, -4.5156856, -4.4537549],
+            [-4.3335061, -4.3275766, -4.2758737, -4.3151569, -4.3024654,
+             -4.3116727, -4.3044739],
+            [-4.7681823, -4.9593668, -4.6908779, -4.6174421, -5.0099521,
+             -4.8067503, -4.9715466, -4.9865417, -4.8987513, -4.8453140],
+            [-4.4846835, -4.4230199, -4.6406989, -4.6029921, -4.4701753,
+             -4.6904001],
+            [-4.4143195, -4.4333215, -4.4820342, -4.4791117, -4.4707422,
+             -4.4700317, -4.4672055, -4.4733205, -4.4819894, -4.4548373,
+             -4.5490141],
+        ]),
+}
+
+
+def _scenario_engine(kind):
+    from paddle_tpu.models.moe_decoder import MoEDecoderConfig, MoEDecoderLM
+
+    reqs = _scenario_requests()
+    if kind == "block":
+        lm = MoEDecoderLM.seeded(MoEDecoderConfig.tiny(), 3)
+        return gen.GenerationEngine(
+            lm, slots=3, max_len=64, logprobs=True, denoising_steps=2,
+            prefill_chunk=8), [gen.GenerationRequest(
+                [t % 120 for t in r.prompt_ids],
+                max_new_tokens=r.max_new_tokens, sampling=r.sampling,
+                request_id=r.request_id) for r in reqs]
+    with dygraph.guard():
+        np.random.seed(0)
+        lm = models.TransformerLM(CFG)
+    kw = ({"paged": False} if kind == "dense" else
+          {"block_size": 4, "prefix_cache": True, "prefill_chunk": 4})
+    return make_engine(lm, logprobs=True, **kw), reqs
+
+
+@pytest.mark.parametrize("kind", ["paged", "dense", "block"])
+def test_streams_equal_the_parents_through_every_change_of_a_row(kind,
+                                                                 monkeypatch):
+    """Admissions while others decode, prompts going in by chunks beside
+    live decoders (paged: four tokens a chunk; block diffusion: every
+    prompt), grows (blocks of four), a prefix-cache hit, parks and frees
+    by length, a preemption and its re-admission, blocks opened and
+    committed, under mixed policies: every row the host changes reaches
+    the device's per-slot state, so each stream is the one the engine
+    served before that state stayed on the device (`PARENT_STREAMS`,
+    taken on the parent tree; paged and dense serve the same tokens).
+    `generation_state_merges_total` counts one a dispatch that had a
+    changed row and none for one that had not."""
+    eng, reqs = _scenario_engine(kind)
+    changed = recording_fresh(
+        monkeypatch, eng,
+        "_dispatch_block" if kind == "block" else "_dispatch_decode",
+        lambda: eng._changed_rows()[1])
+    handles = _serve_scenario(eng, reqs)
+    tokens, logprobs = PARENT_STREAMS[
+        "block" if kind == "block" else "autoregressive"]
+    assert [h.result() for h in handles] == tokens
+    for h, want in zip(handles, logprobs):
+        np.testing.assert_allclose(h.logprobs(), want, rtol=0, atol=1e-6)
+    st = eng.stats()
+    assert st["preempted"] == 1
+    if kind == "paged":
+        assert st["prefix_cache"]["hits"] == 2
+    assert len(changed) == st["decode_steps"]
+    assert eng._m_merges.value == sum(changed) < len(changed)
+    assert eng._jit_cache_size(eng._merge_state_fn) == 1
+    assert st["executables"]["decode_step"] == 1
+
+
+@pytest.mark.parametrize("kw", [{}, {"paged": False}],
+                         ids=["paged", "dense"])
+def test_a_step_where_no_row_changed_puts_nothing_on_the_device(lm, kw):
+    """Three live decoders: once they are admitted, a scheduler
+    iteration whose rows the host has not changed (no admission, park
+    or free since the dispatch before, and no grow before its own
+    dispatch) dispatches and delivers with every host-to-device
+    transfer refused."""
+    import jax
+
+    eng = make_engine(lm, **kw)
+    for i in range(3):
+        eng.submit(gen.GenerationRequest(
+            [3 + i, 4, 5], max_new_tokens=40,
+            sampling=None if i % 2 else _sampled(30 + i)))
+    eng.step()
+
+    def grows():
+        return eng.paged and any(
+            eng.cache.blocks_for(int(eng._lengths[s]) + 1)
+            > len(eng._slot_blocks[s]) for s in np.nonzero(eng._active)[0])
+
+    guarded = 0
+    while eng._active.any():
+        if eng._changed_rows()[1].any() or grows():
+            eng.step()
+            continue
+        with jax.transfer_guard_host_to_device("disallow"):
+            eng.step()
+        guarded += 1
+    eng.run_until_idle()
+    # 39 steps a request, a merge at an admission, a grow (blocks of 16)
+    # or a park
+    assert guarded >= 30
+    assert eng._m_merges.value == eng.stats()["decode_steps"] - guarded
+
+
+@pytest.mark.parametrize("kw", [{}, {"paged": False},
+                                {"kv_dtype": "int8"}],
+                         ids=["paged", "dense", "int8"])
+def test_the_dispatched_step_adds_only_the_advance_of_lengths_and_steps(
+        lm, kw):
+    """`generation_decode` as the dispatch calls it is its body (the
+    program whose lowered text `test_moe_decoder` pins) with the
+    operands' lengths and steps after its outputs, each advanced by one
+    in the rows the step writes and nowhere else."""
+    import jax
+
+    from paddle_tpu.generation.engine import _named
+
+    eng = make_engine(lm, slots=4, **kw)
+    for i in range(2):
+        eng.submit(gen.GenerationRequest([3 + i, 4, 5, 6][:2 + i],
+                                         max_new_tokens=9))
+    eng.step()
+    eng.step()
+    with eng._lock:
+        eng._merge_state()
+        ops = eng._decode_operands()
+        body = jax.jit(_named(eng._make_decode_fn(), "generation_decode"))
+        want = body(*ops)
+        got = eng._decode_step_fn(*ops)
+    assert len(got) == len(want) + 2
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    live = eng._active.copy()
+    assert live.sum() == 2
+    np.testing.assert_array_equal(np.asarray(got[-2]), eng._lengths + live)
+    np.testing.assert_array_equal(np.asarray(got[-1]), eng._steps + live)

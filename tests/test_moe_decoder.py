@@ -318,20 +318,24 @@ PARENT_DECODE_SHA256 = {
 @pytest.mark.parametrize("kind,kw", [("paged", {}), ("dense", {"paged": False}),
                                      ("int8", {"kv_dtype": "int8"})])
 def test_an_autoregressive_models_decode_step_is_the_parents_program(kind, kw):
-    """`generation_decode` of `TransformerLM.tiny()` lowers to the text
-    it had before grouped heads and the mask granule came to the shared
-    walk (sha256 of ``as_text(debug_info=False)`` on the parent commit,
-    3a4365c, under `tests/conftest.py`, jax 0.9.0: the one installation this
-    repo supports)."""
+    """`generation_decode`'s body, the step the dispatch's program wraps
+    (`_advance_step` adds the advance of lengths and steps beside it),
+    of `TransformerLM.tiny()` lowers to the text it had before grouped
+    heads and the mask granule came to the shared walk (sha256 of
+    ``as_text(debug_info=False)`` on the parent commit, 3a4365c, under
+    `tests/conftest.py`, jax 0.9.0: the one installation this repo
+    supports)."""
     from paddle_tpu.fluid import dygraph
+    from paddle_tpu.generation.engine import _named
 
     with dygraph.guard():
         np.random.seed(0)
         tlm = models.TransformerLM(models.TransformerLMConfig.tiny())
     eng = GenerationEngine(tlm, slots=4, max_len=64, **kw)
+    body = jax.jit(_named(eng._make_decode_fn(), "generation_decode"),
+                   donate_argnums=eng._donate_kv)
     with eng._lock, _TRACE_LOCK:
-        text = eng._decode_step_fn.lower(
-            *eng._decode_operands()).as_text(debug_info=False)
+        text = body.lower(*eng._decode_operands()).as_text(debug_info=False)
     assert hashlib.sha256(text.encode()).hexdigest() == \
         PARENT_DECODE_SHA256[kind]
 
@@ -497,8 +501,8 @@ def test_a_steady_batch_of_passes_overlaps_every_pass_but_the_first(lm):
     assert st["block_diffusion"]["passes"] == 4 * 16
     assert st["block_diffusion"]["commits"] == 4 * 3
     # the host's rows go in through one small program a trace can name
-    assert eng._merge_block.__name__ == "generation_block_merge"
-    assert eng._jit_cache_size(eng._merge_block) == 1
+    assert eng._merge_state_fn.__name__ == "generation_state_merge"
+    assert eng._jit_cache_size(eng._merge_state_fn) == 1
 
 
 def test_a_slot_preempted_with_its_pass_in_flight_restarts_clean(lm):
@@ -581,7 +585,7 @@ def test_every_pass_dispatch_holds_its_parts_and_a_merge_where_rows_changed(
     eng = GenerationEngine(lm, slots=2, max_len=64, denoising_steps=4,
                            metrics_registry=reg)
     fresh = recording_fresh(monkeypatch, eng, "_dispatch_block",
-                            lambda: eng._blk_fresh)
+                            lambda: eng._changed_rows()[1])
     tr = T.enable_tracing()
     tr.clear()
     try:
@@ -594,3 +598,33 @@ def test_every_pass_dispatch_holds_its_parts_and_a_merge_where_rows_changed(
         tr.clear()
     assert [len(h.result()) for h in handles] == [n for _, n in REQUESTS]
     assert_dispatch_split(eng, reg, events, fresh)
+
+
+def test_a_pass_that_opens_and_commits_no_block_puts_nothing(lm):
+    """Two live streams past their prompts: a scheduler iteration whose
+    pass neither follows an opened block, a commit, a park or a free nor
+    grows a slot dispatches and delivers with every host-to-device
+    transfer refused; every other pass merged once."""
+    eng = GenerationEngine(lm, slots=2, max_len=64, denoising_steps=4)
+    for n_p in (3, 2):
+        eng.submit(GenerationRequest(prompt_of(n_p), max_new_tokens=21))
+    eng.step()
+
+    def grows():
+        return any(eng.cache.blocks_for(int(eng._lengths[s]) + 4)
+                   > len(eng._slot_blocks[s])
+                   for s in np.nonzero(eng._active)[0])
+
+    guarded = 0
+    while eng._active.any():
+        if eng._changed_rows()[1].any() or grows():
+            eng.step()
+            continue
+        with jax.transfer_guard_host_to_device("disallow"):
+            eng.step()
+        guarded += 1
+    eng.run_until_idle()
+    # a block is four reveals and a commit, and the two streams commit
+    # on different passes: about three of five passes change no row
+    assert guarded >= 12
+    assert eng._m_merges.value == eng.stats()["decode_steps"] - guarded

@@ -325,3 +325,27 @@ def test_decode_step_compiles_for_the_other_cache_forms(
     hlo = compiled.as_text()
     assert hlo.count(" while(") == 2 * CACHE_LAYERS + 2
     assert CUSTOM_CALL not in hlo
+
+
+@pytest.mark.parametrize("paged", [True, False], ids=["paged", "dense"])
+def test_the_state_merge_compiles_for_the_chip(
+        cache_width_lm, one_chip, no_persistent_cache, paged):
+    """`generation_state_merge` at the GPT-2 cell's slots and table
+    width, compiled for the described chip: one scatter of the packed
+    rows into each per-slot operand, no loop and no kernel."""
+    import numpy as np
+
+    from paddle_tpu import generation
+
+    engine = generation.GenerationEngine(
+        cache_width_lm, slots=16, max_len=1024, block_size=16,
+        paged=paged, donate=True, logprobs=True)
+    width = engine._sent.shape[1]
+    assert width == 8 + (64 if paged else 1)
+    specs = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                       sharding=one_chip),
+        (engine._dev, np.zeros((16, 2 + width), np.int32)))
+    hlo = engine._merge_state_fn.lower(*specs).compile().as_text()
+    assert hlo.count(" scatter(") >= 1
+    assert " while(" not in hlo and CUSTOM_CALL not in hlo
